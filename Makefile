@@ -50,8 +50,7 @@ uninstall:
 
 # Cost tranches (VERDICT r3 #10): `test-fast` is the unit core (~3 min);
 # `test-all` adds the e2e (live servers / envtest apiserver) and slow
-# (compile- and subprocess-heavy) tranches — the full suite exceeds a
-# 10-minute wall in remote-compile environments.
+# (compile- and subprocess-heavy) tranches.
 test: test-fast
 
 test-fast:

@@ -14,6 +14,7 @@ forest, bert, llama, resnet, pyfunc, ...) and asks this registry to build a
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -36,6 +37,17 @@ class Predictor:
     # batcher can merge them and XLA compiles log-many shapes.  Only for
     # models whose padding is exact (masked attention, pooled outputs).
     seq_pad: dict | None = None
+    # Weights as a jit ARGUMENT.  ``apply(params, *inputs)`` is
+    # ``predict(*inputs)`` with the weight tree passed explicitly; the
+    # engine jits THAT.  A jitted closure bakes every captured array
+    # into the program as a constant: at Llama-2-7B width each batch
+    # bucket then carries gigabytes of weights through the compiler (the
+    # first chip run died at the host's 40 GiB), the executable cannot be
+    # shared between two versions of one architecture, and the persistent
+    # cache stores the weights once per bucket.  Flavors whose captured
+    # state is small (tabular) leave both None.
+    params: Any = None
+    apply: Callable[..., Any] | None = None
 
 
 _BUILDERS: dict[str, Callable[..., Predictor]] = {}
@@ -180,7 +192,7 @@ def _build_bert(
 
     cfg = cfg or bert.BertConfig.base()
 
-    def predict(input_ids, attention_mask=None, token_type_ids=None):
+    def apply(params, input_ids, attention_mask=None, token_type_ids=None):
         import jax.numpy as jnp
 
         return bert.classify(
@@ -192,6 +204,8 @@ def _build_bert(
             dtype=jnp.bfloat16,
         )
 
+    predict = functools.partial(apply, params)
+
     def example(b):
         return {
             "input_ids": np.ones((b, seq_len), np.int32),
@@ -201,6 +215,8 @@ def _build_bert(
     return Predictor(
         name="bert-classifier",
         predict=predict,
+        params=params,
+        apply=apply,
         jittable=True,
         example_input=example,
         metadata={
@@ -238,12 +254,14 @@ def _build_resnet(params: Any, cfg: Any = None, image_size: int = 224, **_kw) ->
 
     cfg = cfg or resnet.ResNetConfig.resnet50()
 
-    def predict(images):
+    def apply(params, images):
         return resnet.forward(params, images, cfg)
 
     return Predictor(
         name="resnet-classifier",
-        predict=predict,
+        predict=functools.partial(apply, params),
+        params=params,
+        apply=apply,
         jittable=True,
         example_input=lambda b: np.zeros((b, image_size, image_size, 3), np.float32),
         metadata={"image_size": image_size, "num_classes": cfg.num_classes},
@@ -265,12 +283,14 @@ def _build_llama(
     example_len = min(16, cfg.max_seq // 4)
     max_new_tokens = min(max_new_tokens, cfg.max_seq - example_len)
 
-    def predict(prompt_ids):
+    def apply(params, prompt_ids):
         return llama.generate_greedy(params, prompt_ids, max_new_tokens, cfg)
 
     return Predictor(
         name="llama-generate",
-        predict=predict,
+        predict=functools.partial(apply, params),
+        params=params,
+        apply=apply,
         jittable=True,
         example_input=lambda b: np.ones((b, example_len), np.int32),
         metadata={"max_new_tokens": max_new_tokens, "max_seq": cfg.max_seq},

@@ -57,8 +57,7 @@ from ..utils.journey_trace import (
 PLAN_FORMAT_VERSION = 1
 
 # Fixed per-dispatch host overhead (enqueue + callback glue) the fused
-# multi-step path amortizes by K.  Order-of-magnitude constant, same
-# spirit as DevicePeaks' "assumed" rooflines.
+# multi-step path amortizes by K.  Order-of-magnitude planning constant.
 HOST_DISPATCH_S = 300e-6
 
 # Credit speculative decode an assumed draft-acceptance rate: the trace
@@ -68,7 +67,9 @@ HOST_DISPATCH_S = 300e-6
 SPEC_ASSUMED_ACCEPTANCE = 0.3
 SPEC_DRAFT_TOKENS = 4
 
-# v5e rooflines (per chip), matching device_telemetry's assumed table.
+# v5e rooflines (per chip): the planner's own documented planning
+# constants — it plans FOR a device, it does not measure one, so it does
+# not ask jax what is attached (device_telemetry.DEVICE_PEAKS does).
 _DEFAULT_PEAKS = DevicePeaks(
     kind="tpu-v5e(assumed)",
     flops_per_s=197e12,

@@ -90,12 +90,15 @@ def _decode_attn_kernel_mxu(q_ref, k_ref, ks_ref, v_ref, vs_ref,
         o_ref[t, 0] = (ctx + p_self * v_self) / denom
 
 
-def _slot_block(b: int) -> int:
-    """Largest power-of-two slot block (<=8) dividing ``b``: 8 bounds the
-    f32-converted K/V VMEM footprint (~4 MiB at W=512, D=128) and the
-    unroll size; smaller b falls back so any slot count lowers."""
+def _slot_block(b: int, w: int) -> int:
+    """Largest power-of-two slot block (<=8) dividing ``b`` whose window
+    rows fit VMEM: the per-position scale planes [bb, 1, W, 1] pad to a
+    full lane each, so the footprint grows with bb x W — the v5e compiler
+    accepts 8 slots up to W=512 and refuses them at W=1024 (20 MiB
+    against the 16 MiB scoped limit; tests/test_tpu_compile.py), hence
+    bb x W <= 4096.  Smaller b falls back so any slot count lowers."""
     for bb in (8, 4, 2):
-        if b % bb == 0:
+        if b % bb == 0 and bb * w <= 4096:
             return bb
     return 1
 
@@ -106,10 +109,6 @@ def _mxu_decode_call(q, k8, ks, v8, vs, k_self, v_self, mask,
     b, nkv, g, d = q.shape
     w = k8.shape[2]
     scale = 1.0 / (d ** 0.5)
-    if not interpret and jax.devices()[0].platform == "cpu":
-        # No Mosaic lowering on CPU: interpret transparently so the
-        # integrated pallas path stays testable off-chip.
-        interpret = True
     kernel = functools.partial(_decode_attn_kernel_mxu, scale=scale, bb=bb)
     return pl.pallas_call(
         kernel,
@@ -164,7 +163,7 @@ def decode_attention_batched(
     program (same contract and kernel body as :func:`decode_attention`)."""
     return _mxu_decode_call(
         q, k8, ks, v8, vs, k_self, v_self, mask,
-        bb=_slot_block(q.shape[0]), interpret=interpret)
+        bb=_slot_block(q.shape[0], k8.shape[2]), interpret=interpret)
 
 
 _LANE = 128  # VPU lane width: W is retiled as [W // _LANE, _LANE]
@@ -246,9 +245,7 @@ def decode_attention_vpu(
             f"decode_attention_vpu requires W % {_LANE} == 0, got {w}")
     wg = w // _LANE
     scale = 1.0 / (d ** 0.5)
-    bb = _slot_block(b)
-    if not interpret and jax.devices()[0].platform == "cpu":
-        interpret = True
+    bb = _slot_block(b, w)
     # Retile the per-position vectors [.., W, 1] -> [.., Wg, 128] (and
     # the mask [B, 1, W] -> [B, Wg, 128]) on the XLA side: pure reshapes
     # of tiny arrays, giving the kernel lane-dense softmax layouts.

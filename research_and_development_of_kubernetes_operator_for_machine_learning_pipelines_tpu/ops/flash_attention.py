@@ -9,17 +9,19 @@ Layout: [batch, heads, seq, head_dim]; grid is (batch*heads, q_tiles).
 Tiles default to 128x128 (the MXU native tile).  Causal masking and a
 static ``kv_len`` (for padded keys) fold into the tile mask via iota.
 
-Dispatch policy (measured on TPU v5e, 2026-07): standalone, this kernel
-beats XLA attention at BERT-base shapes (16.9 us vs 29.9 us per op at
-B32/H12/S128/D64).  *Inside* a full encoder forward, however, the XLA
-path wins at every shape tried (S=128: 6.1 vs 6.3 ms; B8/S512: 12.4 vs
-17.0 ms; B2/S2048: 20.8 vs 35.6 ms per forward) because XLA fuses the
-QKV projections, softmax, and context matmul without the layout
-transposes the [B,H,S,D] kernel interface forces.  The model zoo
-therefore keeps XLA attention; this kernel is the building block for
-``ring_attention`` (sequence parallelism), where blockwise
-online-softmax structure is required to overlap compute with the ICI
-ring permute and XLA has no equivalent fusion.
+Size limit: each program keeps the WHOLE padded K and V of its
+(batch, head) resident in VMEM (the BlockSpecs below), double-buffered
+by the pipeline, so key length is bounded by fast memory, not HBM.  The
+v5e compiler (compile-only, tests/test_tpu_compile.py) accepts 14 Ki
+bf16 keys at head_dim 128 (14 MiB resident) and refuses 16 Ki (16 MiB):
+:func:`flash_attention` raises :class:`FlashAttentionVmemError` past
+:data:`KV_VMEM_BUDGET_BYTES` instead of reaching the compiler.  Longer
+sequences need K/V tiled over a grid axis (ROADMAP D4 decides whether
+this kernel earns that).
+
+Not on the serving path: the model zoo keeps XLA attention (the
+[B,H,S,D] interface forces layout transposes XLA's fused QKV chain
+avoids); no timing of this kernel exists on today's code.
 """
 
 from __future__ import annotations
@@ -32,6 +34,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
+
+# K + V, double-buffered, resident per program (see module docstring):
+# the largest footprint the v5e compiler was seen to accept.
+KV_VMEM_BUDGET_BYTES = 14 * 2**20
+_LANE = 128
+
+
+class FlashAttentionVmemError(ValueError):
+    """Key length whose resident K/V exceed :data:`KV_VMEM_BUDGET_BYTES`."""
 
 
 def attention_reference(
@@ -121,6 +132,15 @@ def flash_attention(
     block_k = min(block_k, _round_up(t_k, 8))
     s_pad = _round_up(s_q, block_q)
     t_pad = _round_up(t_k, block_k)
+    # 2 arrays (K, V) x 2 pipeline buffers, minor dim padded to a lane.
+    resident = 4 * t_pad * _round_up(d, _LANE) * k.dtype.itemsize
+    if not interpret and resident > KV_VMEM_BUDGET_BYTES:
+        raise FlashAttentionVmemError(
+            f"flash_attention keeps all {t_pad} padded keys of a head "
+            f"resident in VMEM: {resident / 2**20:.1f} MiB of K/V "
+            f"({k.dtype}, head_dim {d}) exceeds the "
+            f"{KV_VMEM_BUDGET_BYTES / 2**20:.0f} MiB the TPU compiler accepts"
+        )
     qp = _pad_seq(q, s_pad)
     kp = _pad_seq(k, t_pad)
     vp = _pad_seq(v, t_pad)

@@ -33,9 +33,7 @@ def ring_attention(
     scale: float | None = None,
 ) -> jax.Array:
     """Call INSIDE shard_map: q/k/v are local shards [B, H, S/n, D]."""
-    from ..parallel.collectives import axis_size_compat
-
-    n = axis_size_compat(axis_name)
+    n = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     b, h, chunk, d = q.shape
     scale = scale if scale is not None else 1.0 / (d**0.5)
@@ -93,12 +91,11 @@ def ring_attention_sharded(
 ) -> jax.Array:
     """Convenience wrapper: global [B,H,S,D] arrays, seq sharded over ``sp``."""
     spec = PartitionSpec(None, None, axis_name, None)
-    from ..parallel.collectives import shard_map_compat
-
-    f = shard_map_compat(
+    f = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return f(q, k, v)

@@ -34,7 +34,7 @@ from .sharding import (
     match_partition_rules,
     shard_pytree,
 )
-from .collectives import ring_shift, shard_map_compat
+from .collectives import ring_shift
 from .distributed import maybe_initialize_distributed
 
 __all__ = [
@@ -61,6 +61,5 @@ __all__ = [
     "PartitionRuleError",
     "shard_pytree",
     "ring_shift",
-    "shard_map_compat",
     "maybe_initialize_distributed",
 ]

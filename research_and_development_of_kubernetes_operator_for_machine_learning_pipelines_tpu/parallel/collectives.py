@@ -23,33 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.5 exposes ``jax.shard_map`` with ``check_vma``; 0.4.x only
-    has ``jax.experimental.shard_map.shard_map`` with the kwarg spelled
-    ``check_rep``.  One call site, both APIs."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
-    )
-
-
-def axis_size_compat(axis_name: str) -> int:
-    """``lax.axis_size`` across jax versions (0.4.x lacks it; the bound
-    axis env makes ``psum(1, name)`` a compile-time constant there)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_shift(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
     """Shift ``x`` around the mesh-axis ring by ``shift`` hops.
 
@@ -57,7 +30,7 @@ def ring_shift(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
     torus this is nearest-neighbor ICI traffic — the primitive under ring
     attention and pipelined all-gathers.
     """
-    n = axis_size_compat(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

@@ -29,8 +29,8 @@ def configure_cpu_rehearsal(num_local_devices: int = 1) -> None:
     ``psum``/``all_gather`` genuinely cross process boundaries — the same
     code path a v5e multi-host slice takes over DCN, minus the TPU
     transport.  Must run before the group forms; it drops any
-    already-created backends because environments that pre-import JAX
-    (or pytest's conftest) may have initialized a different platform.
+    already-created backends because the caller (pytest's conftest,
+    say) may have initialized a different platform or device count.
 
     Proven by ``tests/test_distributed_group.py``: two processes, one
     coordinator, a cross-process ``psum`` with bitwise-checked results on
@@ -39,9 +39,9 @@ def configure_cpu_rehearsal(num_local_devices: int = 1) -> None:
     import jax
     from jax.extend import backend
 
-    # Clear BEFORE the device-count update: with a backend already live
-    # (pre-imported JAX), jax_num_cpu_devices raises "config should be
-    # updated before backends are initialized".
+    # Clear BEFORE the device-count update: with a backend already
+    # live, jax_num_cpu_devices raises "config should be updated before
+    # backends are initialized".
     jax.config.update("jax_platforms", "cpu")
     backend.clear_backends()
     jax.config.update("jax_num_cpu_devices", num_local_devices)

@@ -2024,6 +2024,7 @@ def build_server(
     warmup: bool = True,
     transport=None,
     wake_start_wall: float | None = None,
+    peaks=None,
 ) -> TpuInferenceServer:
     """Build the leader-side server.
 
@@ -2038,6 +2039,10 @@ def build_server(
     on demand.  ``wake_start_wall`` (unix seconds) is the instant the
     controller decided to wake this replica — it anchors the
     ``tpumlops_cold_start_seconds`` ladder's ``wake`` stage.
+
+    ``peaks`` (a ``device_telemetry.DevicePeaks``) is what the device
+    telemetry layer divides by; None asks the attached device, which
+    must then be one the peaks table knows.
     """
     boot_wall = time.time()
     mesh_shape = dict(config.tpu.mesh_shape)
@@ -2050,7 +2055,7 @@ def build_server(
 
         # Before load_predictor so even the loader-phase compiles (the
         # streamed quantizer) land in the observatory's journal.
-        telemetry = DeviceTelemetry()
+        telemetry = DeviceTelemetry(peaks=peaks)
     metrics = ServerMetrics(
         deployment_name=config.deployment_name or config.model_name,
         predictor_name=config.predictor_name,
@@ -2444,9 +2449,11 @@ def main(argv: list[str] | None = None) -> None:
     )
     ap.add_argument(
         "--compile-cache-dir",
-        default=os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_compile_cache"),
+        default=None,
         help="persistent XLA compile cache (SURVEY §7 hard part 3); "
-        "empty string disables",
+        "JAX_COMPILATION_CACHE_DIR, when set, places it and wins; unset "
+        "and no flag = the fixed in-checkout default "
+        "(utils/compile_cache.py); empty string disables",
     )
     ap.add_argument(
         "--trace-ring",
@@ -2523,12 +2530,17 @@ def main(argv: list[str] | None = None) -> None:
     configure_logging(json_format=args.log_format == "json")
 
     from ..parallel.distributed import maybe_initialize_distributed
-    from ..utils.compile_cache import enable_persistent_compile_cache
+    from ..utils.compile_cache import (
+        enable_persistent_compile_cache,
+        resolve_compile_cache_dir,
+    )
 
     maybe_initialize_distributed()
     # Before any jit trace (warmup included), so even the first-ever
     # compile of each batch bucket is persisted for the next pod.
-    enable_persistent_compile_cache(args.compile_cache_dir)
+    enable_persistent_compile_cache(
+        resolve_compile_cache_dir(args.compile_cache_dir)
+    )
 
     config = ServerConfig(
         model_name=args.model_name,

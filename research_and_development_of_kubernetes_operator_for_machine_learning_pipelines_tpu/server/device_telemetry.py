@@ -78,12 +78,13 @@ class DevicePeaks:
     cover the whole device set holding it — a tp=8 mesh divides by 8x
     the per-chip roofline, or every ratio reads 8x high and clamps."""
 
-    kind: str  # jax device_kind (or the assumed stand-in)
-    flops_per_s: float  # dense peak for the serving dtype family
+    kind: str  # the DEVICE_PEAKS row's name
+    flops_per_s: float  # dense bf16 peak
     hbm_bytes_per_s: float
     hbm_bytes: int  # HBM capacity
-    source: str  # "detected" | "assumed"
+    source: str  # "detected" (table row) | whatever a caller's own peaks say
     chips: int = 1
+    int8_ops_per_s: float = 0.0  # dense s8 peak (0 = not stated)
     # Per-chip ICI bandwidth the collective-wall estimates divide by
     # (rough order-of-magnitude constants, marked per ``source`` like
     # the rooflines; stays PER-CHIP under scaled() — a ring all-reduce's
@@ -97,6 +98,7 @@ class DevicePeaks:
         return dataclasses.replace(
             self,
             flops_per_s=self.flops_per_s * n,
+            int8_ops_per_s=self.int8_ops_per_s * n,
             hbm_bytes_per_s=self.hbm_bytes_per_s * n,
             hbm_bytes=self.hbm_bytes * n,
             chips=n,
@@ -115,30 +117,50 @@ def param_device_count(params) -> int:
         return 1
 
 
-# v5e: 197 bf16 TFLOP/s, 819 GB/s, 16 GiB HBM (bench.py's constants of
-# record).  Matching is by device_kind substring; unknown kinds (the CPU
-# dev environment) fall back to the v5e row marked "assumed" so ratios
-# stay computable — tiny on CPU, honest on the target part.
-_KNOWN_DEVICES = {
-    "v5 lite": ("tpu-v5e", 197e12, 819e9, 16 * 2**30),
-    "v5e": ("tpu-v5e", 197e12, 819e9, 16 * 2**30),
-    "v4": ("tpu-v4", 275e12, 1228e9, 32 * 2**30),
+class UnknownDeviceKind(LookupError):
+    """``device_kind`` has no row in :data:`DEVICE_PEAKS`.  A utilization
+    ratio against another part's roofline is not a measurement, so an
+    unlisted device is an error, never a default; callers that want
+    ratios on such a device (CPU tests) pass ``peaks=`` themselves."""
+
+    def __init__(self, kind: str):
+        self.kind = kind
+        super().__init__(
+            f"no peak rates known for device kind {kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); add a row with its source "
+            "to DEVICE_PEAKS or pass peaks= explicitly"
+        )
+
+
+# THE peaks table (bench.py reads it too), per chip, keyed by the exact
+# ``jax.Device.device_kind`` (a v5e reports "TPU v5 lite").  Source:
+# Google Cloud TPU documentation, "TPU v5e" / "TPU v4" system
+# architecture pages.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        "tpu-v5e", 197e12, 819e9, 16 * 2**30, "detected",
+        int8_ops_per_s=394e12,
+    ),
+    "TPU v4": DevicePeaks(
+        "tpu-v4", 275e12, 1228e9, 32 * 2**30, "detected",
+        int8_ops_per_s=275e12,
+    ),
 }
-_ASSUMED = ("tpu-v5e (assumed)", 197e12, 819e9, 16 * 2**30)
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDeviceKind(device_kind) from None
 
 
 def detect_peaks() -> DevicePeaks:
-    try:
-        import jax
+    """Peaks of the first visible device; :class:`UnknownDeviceKind`
+    when it is not in the table."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        kind = "unknown"
-    for marker, (name, fl, bw, hbm) in _KNOWN_DEVICES.items():
-        if marker in kind:
-            return DevicePeaks(name, fl, bw, hbm, "detected")
-    name, fl, bw, hbm = _ASSUMED
-    return DevicePeaks(name, fl, bw, hbm, "assumed")
+    return peaks_for(jax.devices()[0].device_kind)
 
 
 def measured_memory() -> dict | None:
@@ -146,29 +168,25 @@ def measured_memory() -> dict | None:
     (TPU/GPU runtimes report it; CPU returns None).  ``devices`` counts
     how many reported — on a multi-host unit each process sees only its
     local chips, so the ledger cross-check scales by the addressable
-    fraction (see :meth:`HbmLedger.snapshot`)."""
-    try:
-        import jax
+    fraction (see :meth:`HbmLedger.snapshot`).  ``per_device_bytes_in_use``
+    keeps the addends of the ``bytes_in_use`` sum, in device order: the
+    sum alone cannot show a sharded model that landed on one chip."""
+    import jax
 
-        devs = jax.local_devices()
-    except Exception:
-        return None
-    totals: dict[str, int] = {}
-    reporting = 0
-    for dev in devs:
-        try:
-            stats = dev.memory_stats()
-        except Exception:
-            stats = None
+    totals: dict = {}
+    per_device: list[int] = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
         if not stats:
             continue
-        reporting += 1
+        per_device.append(int(stats.get("bytes_in_use", 0)))
         for k, v in stats.items():
             if isinstance(v, (int, float)):
                 totals[k] = totals.get(k, 0) + int(v)
-    if reporting == 0:
+    if not per_device:
         return None
-    totals["devices"] = reporting
+    totals["devices"] = len(per_device)
+    totals["per_device_bytes_in_use"] = per_device
     return totals
 
 
@@ -328,21 +346,36 @@ def build_hbm_ledger(
     return ledger
 
 
-def capacity_log_line(params, cfg, kv_quant: bool) -> str:
+def capacity_log_line(params, cfg, kv_quant: bool,
+                      peaks: DevicePeaks | None = None) -> str:
     """The model-capacity startup line ``server/loader.py`` stamps (even
     with telemetry off): weights by dtype, KV bytes/row, max cache rows.
-    HBM covers the device set the params are sharded over."""
+    HBM covers the device set the params are sharded over.  On a device
+    kind with no :data:`DEVICE_PEAKS` row the line says so instead of
+    pricing rows against another part's HBM."""
     n_chips = param_device_count(params)
-    peaks = detect_peaks().scaled(n_chips)
     by_dtype = weights_bytes_by_dtype(params)
     total = sum(by_dtype.values())
     per_row = kv_cache_bytes_per_row(cfg, kv_quant)
-    spare = peaks.hbm_bytes - total
-    rows = max(0, spare // per_row) if per_row else 0
+    try:
+        peaks = (peaks or detect_peaks()).scaled(n_chips)
+    except UnknownDeviceKind as e:
+        capacity = (
+            f"max cache rows not computed (device kind {e.kind!r} has no "
+            "peaks row)"
+        )
+    else:
+        spare = peaks.hbm_bytes - total
+        rows = max(0, spare // per_row) if per_row else 0
+        chips = f" x{peaks.chips}" if peaks.chips > 1 else ""
+        capacity = (
+            f"max cache rows {rows} "
+            f"(hbm {peaks.hbm_bytes / 2**30:.1f} GiB "
+            f"{peaks.source} {peaks.kind}{chips})"
+        )
     dtypes = ", ".join(
         f"{k}={v / 2**20:.1f}MiB" for k, v in sorted(by_dtype.items())
     )
-    chips = f" x{peaks.chips}" if peaks.chips > 1 else ""
     per_chip = ""
     if n_chips > 1:
         # The tp view: what ONE chip actually holds (weights exact via
@@ -358,9 +391,7 @@ def capacity_log_line(params, cfg, kv_quant: bool) -> str:
         f"model capacity: weights {total / 2**20:.1f} MiB ({dtypes}), "
         f"kv {per_row} B/row (max_seq {cfg.max_seq}"
         f"{', int8kv' if kv_quant else ''}), "
-        f"max cache rows {rows} "
-        f"(hbm {peaks.hbm_bytes / 2**30:.1f} GiB "
-        f"{peaks.source} {peaks.kind}{chips}){per_chip}"
+        f"{capacity}{per_chip}"
     )
 
 
@@ -720,11 +751,12 @@ class DeviceTelemetry:
     nothing and every existing payload stays byte-for-byte."""
 
     def __init__(self, metrics=None,
-                 readiness_budget_s: float = READINESS_BUDGET_S):
+                 readiness_budget_s: float = READINESS_BUDGET_S,
+                 peaks: DevicePeaks | None = None):
         # Per-chip until attach_model scales to the param-holding device
         # set; _chip_peaks keeps the pristine base so a rebind/re-attach
         # can never compound the scaling.
-        self._chip_peaks = detect_peaks()
+        self._chip_peaks = peaks or detect_peaks()
         self.peaks = self._chip_peaks
         self.observatory = CompileObservatory(readiness_budget_s)
         self.observatory.install()

@@ -17,6 +17,7 @@ same interface, same metrics, different tier.
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -64,18 +65,35 @@ class InferenceEngine:
         if predictor.jittable:
             import jax
 
-            self._jitted = jax.jit(self._call_predict)
+            if predictor.apply is not None:
+                # Weights ride as a jit ARGUMENT (see Predictor.apply):
+                # never captured into the program as constants.
+                apply_jit = jax.jit(
+                    lambda params, inputs: self._call(
+                        functools.partial(self.predictor.apply, params),
+                        inputs,
+                    )
+                )
+                self._jitted = lambda inputs: apply_jit(
+                    self.predictor.params, inputs
+                )
+            else:
+                self._jitted = jax.jit(self._call_predict)
         else:
             self._jitted = None
 
     # -- calling conventions -------------------------------------------------
 
-    def _call_predict(self, inputs: Mapping[str, Any]):
+    @staticmethod
+    def _call(fn, inputs: Mapping[str, Any]):
         """Single input -> positional call; several -> keyword call."""
         if len(inputs) == 1:
             (value,) = inputs.values()
-            return self.predictor.predict(value)
-        return self.predictor.predict(**inputs)
+            return fn(value)
+        return fn(**inputs)
+
+    def _call_predict(self, inputs: Mapping[str, Any]):
+        return self._call(self.predictor.predict, inputs)
 
     @staticmethod
     def _signature(inputs: Mapping[str, np.ndarray]) -> tuple:

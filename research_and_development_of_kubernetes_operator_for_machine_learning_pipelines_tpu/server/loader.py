@@ -671,9 +671,9 @@ def release_predictor(predictor: Any) -> None:
 
     An in-place version swap (warm reload, /admin/attach replace, bench
     warm-load) used to stream the new tree into an HBM still holding the
-    old one plus every executable cache pinning its buffers — the 7B
-    warm reload died RESOURCE_EXHAUSTED exactly that way
-    (BENCH_7B_FULL.json warm_load_error).  Deleting the device buffers
+    old one plus every executable cache pinning its buffers — a 7B
+    warm reload once died RESOURCE_EXHAUSTED exactly that way (not
+    measured on today's code).  Deleting the device buffers
     explicitly (not just dropping the Python refs) and clearing the jit
     caches returns the HBM before the replacement's first byte
     transfers."""
@@ -697,8 +697,7 @@ def release_predictor(predictor: Any) -> None:
                 except Exception:  # already deleted / donated
                     pass
     # Executable caches pin device buffers even after the params are
-    # garbage (measured: a "warm" reload into a near-full HBM ran 1204 s
-    # of allocator pathology vs 154 s fresh — BENCH_7B_FULL.json).
+    # garbage (cost of skipping this: not measured on today's code).
     jax.clear_caches()
     gc.collect()
 

@@ -1,9 +1,9 @@
 """Pre-baked weight snapshots: the device-resident tree on disk, restorable
 with zero transform work.
 
-Why: the measured 7B cold path (BENCH_7B_FULL.json) spends 102 s to
-first-servable — 92 s of it reading 12.55 GiB of bf16 from disk only to
-quantize it down to 6.4 GiB of int8 on device.  Both λScale and "Breaking
+Why: a 7B cold load reads 12.55 GiB of bf16 from disk only to quantize
+it down to 6.4 GiB of int8 on device (seconds: not measured on today's
+code).  Both λScale and "Breaking
 the Ice" (PAPERS.md) locate the scale-to-zero win in the same place:
 stop re-deriving the device state on every boot.  A snapshot is the
 *exact post-shard, post-quantize* param tree — q8/scale planes included —

@@ -31,6 +31,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+from pathlib import Path
 
 _log = logging.getLogger("tpumlops.compile_cache")
 # One structured line per compilation (see install_compile_listeners).
@@ -48,8 +49,36 @@ COUNTERS = {
 }
 _counters_lock = threading.Lock()
 _listeners_installed = False
-_reset_failure_logged = False
 _observatory = None  # server.device_telemetry.CompileObservatory | None
+
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# Where the cache lives when nobody placed it from outside: one fixed
+# path inside the checkout (the path is part of jax's cache key, so a
+# directory that moves — a tempdir, a pid, a timestamp — never hits).
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_compile_cache")
+
+
+def resolve_compile_cache_dir(requested: str | None = None) -> str:
+    """THE answer to "where does this process keep its compile cache",
+    for every entry point (server CLI, bench.py, chip_smoke.py, scripts).
+
+    ``JAX_COMPILATION_CACHE_DIR`` set → that directory and no other:
+    whoever runs the program placed the cache, and a flag or a default
+    of ours does not move it.  Unset → ``requested`` when given, else
+    :data:`DEFAULT_CACHE_DIR`.  An explicit empty ``requested`` means
+    "no persistent cache" and stays empty either way (nothing is written
+    anywhere)."""
+    if requested == "":
+        return ""
+    placed = os.environ.get(CACHE_DIR_ENV)
+    if placed:
+        if requested and requested != placed:
+            _log.warning(
+                "%s=%s places the compile cache; ignoring requested %s",
+                CACHE_DIR_ENV, placed, requested,
+            )
+        return placed
+    return requested or DEFAULT_CACHE_DIR
 
 
 def install_compile_listeners(observatory=None) -> None:
@@ -66,13 +95,8 @@ def install_compile_listeners(observatory=None) -> None:
         _observatory = observatory
     if _listeners_installed:
         return
-    try:
-        from jax._src import monitoring
-    except Exception as exc:  # private API moved: counters stay at 0
-        _log.warning("jax monitoring unavailable (%s); compile/cache "
-                     "counters disabled", exc)
-        _listeners_installed = True
-        return
+    from jax import monitoring
+
     monitoring.register_event_listener(_on_jax_event)
     monitoring.register_event_duration_secs_listener(_on_jax_duration)
     _listeners_installed = True
@@ -190,37 +214,21 @@ def enable_persistent_compile_cache(
         "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs
     )
     jax.config.update("jax_compilation_cache_max_size", max_size_bytes)
-    _reset_jax_cache_singleton(jax)
+    _reset_jax_cache_singleton()
     _log.info("persistent compile cache at %s", cache_dir)
     return True
 
 
-def _reset_jax_cache_singleton(jax) -> None:
+def _reset_jax_cache_singleton() -> None:
     """Drop jax's latched cache object so the new dir takes effect.
 
     jax initializes its persistent-cache singleton on the FIRST compile
     and never re-reads ``jax_compilation_cache_dir`` afterwards — if any
     jit ran before this helper (or the helper runs twice with different
     dirs), the config update is silently ignored without this reset."""
-    global _reset_failure_logged
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax.experimental.compilation_cache import compilation_cache
 
-        _cc.reset_cache()
-    except Exception as exc:  # private API: absence degrades to the old
-        # behavior — but say so ONCE, with the directory that will be
-        # silently ignored if a jit already ran; the old bare ``pass``
-        # made an in-process cache re-point look successful while every
-        # compile kept writing to the previous dir.
-        if not _reset_failure_logged:
-            _reset_failure_logged = True
-            _log.warning(
-                "could not reset jax's persistent-cache singleton "
-                "(%s: %s); if any jit ran before this point, the cache "
-                "dir change to %r is silently ignored",
-                type(exc).__name__, exc,
-                jax.config.jax_compilation_cache_dir,
-            )
+    compilation_cache.reset_cache()
 
 
 def cache_entry_count(cache_dir: str) -> int:

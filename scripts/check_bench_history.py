@@ -44,17 +44,6 @@ HISTORY = _ROOT / "BENCH_HISTORY.jsonl"
 # module docstring; tolerance is relative for higher/lower, absolute
 # points for max_delta, ignored for exact.
 WATCHED: dict[str, tuple[str, dict[str, tuple[str, float]]]] = {
-    "BENCH_SUPERSTEP.json": (
-        "ragged_superstep",
-        {
-            "tok_per_s_unified": ("higher", 0.30),
-            "compile_collapse_ratio": ("higher", 0.10),
-            "unified_compiles": ("lower", 0.0),
-            "unified_dispatches_per_token": ("lower", 0.10),
-            "itl_p99_ms_unified": ("lower", 0.50),
-            "token_agreement": ("exact", 0.0),
-        },
-    ),
     "BENCH_FLEET_TRACE.json": (
         "fleet_trace",
         {

@@ -50,7 +50,7 @@ def _sweep(unified: bool, mesh_shape: dict | None = None,
     import jax.numpy as jnp
 
     from tpumlops.models import llama, partition
-    from tpumlops.server.device_telemetry import DeviceTelemetry
+    from tpumlops.server.device_telemetry import DeviceTelemetry, peaks_for
     from tpumlops.server.generation import GenerationEngine
     from tpumlops.server.speculative import SpeculativeConfig
 
@@ -59,7 +59,10 @@ def _sweep(unified: bool, mesh_shape: dict | None = None,
     if mesh_shape:
         mesh = partition.build_serving_mesh(mesh_shape)
         params = partition.shard_llama_params(params, mesh)
-    telemetry = DeviceTelemetry()
+    # This gate runs on the CPU and reads only the observatory's compile
+    # COUNTS; the utilization ratios are never looked at, so it names the
+    # row of the part the budget is for instead of asking the device.
+    telemetry = DeviceTelemetry(peaks=peaks_for("TPU v5 lite"))
     engine = GenerationEngine(
         params, cfg, max_slots=4, dtype=jnp.float32, decode_steps=4,
         speculative=SpeculativeConfig(

@@ -33,9 +33,12 @@ params = {
 print(f"generated in {time.time()-t0:.0f}s")
 from tpumlops.server.loader import save_native_model
 t0 = time.time()
-save_native_model("/root/ckpt7b", "llama-generate", params, config={
+CKPT = os.environ.get("BENCH_7B_CKPT") or os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".ckpt7b"
+)
+save_native_model(CKPT, "llama-generate", params, config={
     "vocab_size": VOCAB, "hidden_size": H, "num_layers": L, "num_heads": NH,
     "num_kv_heads": NKV, "intermediate_size": INTER, "max_seq": 1024})
 print(f"saved in {time.time()-t0:.0f}s")
 import subprocess
-print(subprocess.run(["du","-sh","/root/ckpt7b"], capture_output=True, text=True).stdout)
+print(subprocess.run(["du","-sh",CKPT], capture_output=True, text=True).stdout)

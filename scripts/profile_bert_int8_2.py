@@ -8,10 +8,14 @@ import time
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_compile_cache")
-
 from tpumlops.models import bert
 from tpumlops.models.quantization import quantize_bert
+from tpumlops.utils.compile_cache import (
+    enable_persistent_compile_cache,
+    resolve_compile_cache_dir,
+)
+
+enable_persistent_compile_cache(resolve_compile_cache_dir())
 
 BATCH, SEQ = 32, 128
 RUNS, INNER = 6, 64

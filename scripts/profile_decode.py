@@ -50,7 +50,9 @@ def main() -> None:
 
         from tpumlops.server.loader import load_predictor
 
-        ckpt = os.environ.get("BENCH_7B_CKPT", "/root/ckpt7b")
+        ckpt = os.environ.get("BENCH_7B_CKPT") or str(
+            Path(__file__).resolve().parent.parent / ".ckpt7b"
+        )
         pred = load_predictor(ckpt, quantize="int8")
         params, cfg = pred.causal_lm["params"], pred.causal_lm["cfg"]
         import dataclasses

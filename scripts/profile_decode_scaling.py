@@ -26,11 +26,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_compile_cache")
-
+import os
 import sys
-sys.path.insert(0, "/root/repo")
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench import _scan_delta_timed
+from tpumlops.utils.compile_cache import (
+    enable_persistent_compile_cache,
+    resolve_compile_cache_dir,
+)
+
+enable_persistent_compile_cache(resolve_compile_cache_dir())
 
 H, NKV, NH, HD, I = 2048, 16, 16, 128, 5632
 CAP, WINDOW, POS = 768, 512, 256

@@ -2,7 +2,7 @@
 stream the 13.5 GB synthetic Llama-2-7B checkpoint through
 server/loader.py with quantize: int8, record wall time + HBM footprint,
 then prove the loaded model decodes."""
-import json, time
+import json, os, time
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +11,11 @@ dev = jax.devices()[0]
 print("device:", dev)
 t0 = time.time()
 from tpumlops.server.loader import load_predictor
-pred = load_predictor("/root/ckpt7b", quantize="int8")
+# Where scripts/gen_7b_checkpoint.py writes it (inside the checkout).
+CKPT = os.environ.get("BENCH_7B_CKPT") or os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".ckpt7b"
+)
+pred = load_predictor(CKPT, quantize="int8")
 load_s = time.time() - t0
 stats = dev.memory_stats() or {}
 in_use = stats.get("bytes_in_use", 0)

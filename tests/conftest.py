@@ -1,32 +1,31 @@
-"""Test environment: force JAX onto a virtual 8-device CPU mesh.
+"""Test environment: JAX on a virtual 8-device CPU mesh.
 
 Tests exercise the same ``pjit``/sharding paths as a v5e-8 slice
-(SURVEY.md §4) but on CPU.  Env vars alone are not enough here: the host
-environment may pre-import and initialize JAX on a TPU backend before pytest
-starts, so we switch platforms through ``jax.config`` and drop any
-already-created backends.
+(SURVEY.md §4) but on CPU: the platform comes from the environment and
+the device count from ``jax_num_cpu_devices``, both fixed before the
+first backend exists.
 """
 
 import os
 
-# For clean environments where jax is not yet imported.
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=8"
-    ).strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # Older jax (< 0.5) has no jax_num_cpu_devices option; the
-    # XLA_FLAGS fallback above provides the 8 virtual devices.
-    pass
-from jax.extend import backend as _jeb  # noqa: E402
-
-_jeb.clear_backends()
+jax.config.update("jax_num_cpu_devices", 8)
 assert len(jax.devices()) == 8, jax.devices()
+
+
+@pytest.fixture(scope="session")
+def cpu_peaks():
+    """Peak rates for tests that want utilization ratios on the CPU: the
+    device telemetry layer refuses a device kind it has no row for, so a
+    test states what it divides by (arbitrary round numbers — the ratios
+    are exercised, not believed)."""
+    from tpumlops.server.device_telemetry import DevicePeaks
+
+    return DevicePeaks(
+        kind="test-cpu", flops_per_s=1e12, hbm_bytes_per_s=1e11,
+        hbm_bytes=16 * 2**30, source="test",
+    )

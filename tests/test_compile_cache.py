@@ -6,8 +6,8 @@ Pins three behaviors that previously had no test of their own:
   unusable one disables it (and clears the env-var-injected default)
   WITHOUT failing startup;
 - in-process re-point: jax latches its cache singleton on first compile,
-  so changing the dir must go through ``reset_cache()`` (the PR 1 fix —
-  pinned nowhere until now) for later compiles to land in the new dir;
+  so changing the dir must go through jax's public ``reset_cache()``
+  for later compiles to land in the new dir;
 - counters + structured log: the jax monitoring hooks count compiles /
   persistent-cache hits / misses / persists and emit one
   ``tpumlops.compile`` line per compilation.
@@ -28,7 +28,7 @@ def _restore_cache_config():
     prior = jax.config.jax_compilation_cache_dir
     yield
     jax.config.update("jax_compilation_cache_dir", prior)
-    cc._reset_jax_cache_singleton(jax)
+    cc._reset_jax_cache_singleton()
 
 
 def _unique_fn(tag: float):
@@ -77,28 +77,28 @@ def test_in_process_repoint_takes_effect(tmp_path):
     assert cc.cache_entry_count(str(d1)) == n1  # old dir no longer written
 
 
-def test_reset_failure_logs_once_with_directory(tmp_path, monkeypatch, caplog):
-    """The old silent ``except Exception: pass`` hid a real failure mode;
-    now the first failure names the dir that will be ignored, and
-    repeats stay quiet (no per-call log spam)."""
-    monkeypatch.setattr(cc, "_reset_failure_logged", False)
+def test_repoint_resets_through_the_public_api(tmp_path, monkeypatch):
+    """Every (re-)point goes through jax's PUBLIC ``reset_cache`` — one
+    installation, no private-module probing — and a failure there is
+    raised, not swallowed: a re-point that did not take would keep
+    writing the previous dir while looking successful."""
+    from jax.experimental.compilation_cache import compilation_cache as pub
 
-    class _Boom:
-        def reset_cache(self):
-            raise RuntimeError("private API moved")
+    calls = []
+    real = pub.reset_cache
+    monkeypatch.setattr(
+        pub, "reset_cache", lambda: (calls.append(1), real())[1]
+    )
+    assert cc.enable_persistent_compile_cache(str(tmp_path / "a")) is True
+    assert cc.enable_persistent_compile_cache(str(tmp_path / "b")) is True
+    assert len(calls) == 2
 
-    import jax._src as jax_src
+    def boom():
+        raise RuntimeError("reset refused")
 
-    monkeypatch.setattr(jax_src, "compilation_cache", _Boom(), raising=False)
-    with caplog.at_level(logging.WARNING, logger="tpumlops.compile_cache"):
-        assert cc.enable_persistent_compile_cache(str(tmp_path / "a")) is True
-        assert cc.enable_persistent_compile_cache(str(tmp_path / "b")) is True
-    warnings = [
-        r for r in caplog.records
-        if "persistent-cache singleton" in r.getMessage()
-    ]
-    assert len(warnings) == 1
-    assert str(tmp_path / "a") in warnings[0].getMessage()
+    monkeypatch.setattr(pub, "reset_cache", boom)
+    with pytest.raises(RuntimeError, match="reset refused"):
+        cc.enable_persistent_compile_cache(str(tmp_path / "c"))
 
 
 def test_counters_and_one_structured_line_per_compile(tmp_path, caplog):

@@ -64,7 +64,7 @@ def test_kv_bytes_per_row_bf16_and_int8kv(tiny):
     )
 
 
-def test_ledger_components_and_rows(tiny):
+def test_ledger_components_and_rows(tiny, cpu_peaks):
     params, cfg = tiny
     ledger = build_hbm_ledger(
         params, cfg, max_slots=4, prefix_cache_budget_bytes=7 * 2**20
@@ -79,18 +79,26 @@ def test_ledger_components_and_rows(tiny):
     # Capacity planning: rows scale with spare HBM, never negative.
     assert ledger.max_cache_rows(2**34) > 4
     assert ledger.max_cache_rows(0) == 0
-    snap = json.loads(json.dumps(ledger.snapshot()))
+    snap = json.loads(json.dumps(ledger.snapshot(cpu_peaks)))
     assert snap["device_total_bytes"] == ledger.device_total()
     assert snap["max_cache_rows"] >= 0
 
 
-def test_capacity_log_line_has_the_planning_facts(tiny):
+def test_capacity_log_line_has_the_planning_facts(tiny, cpu_peaks):
     params, cfg = tiny
-    line = capacity_log_line(params, cfg, kv_quant=False)
+    line = capacity_log_line(params, cfg, kv_quant=False, peaks=cpu_peaks)
     assert line.startswith("model capacity: weights ")
     assert "B/row" in line and "max cache rows" in line
     assert f"max_seq {cfg.max_seq}" in line
-    assert "int8kv" in capacity_log_line(params, cfg, kv_quant=True)
+    assert "test test-cpu" in line  # the peaks it priced rows against
+    assert "int8kv" in capacity_log_line(
+        params, cfg, kv_quant=True, peaks=cpu_peaks
+    )
+    # No peaks row for this device kind: the line keeps the
+    # device-independent facts and says what it did not compute.
+    bare = capacity_log_line(params, cfg, kv_quant=False)
+    assert bare.startswith("model capacity: weights ")
+    assert "max cache rows not computed (device kind 'cpu'" in bare
 
 
 # ---------------------------------------------------------------------------
@@ -179,8 +187,8 @@ def test_observatory_warns_past_readiness_budget(caplog):
     assert any("readiness budget" in r.getMessage() for r in caplog.records)
 
 
-def test_tick_util_clamps_to_unit_interval():
-    tel = DeviceTelemetry()
+def test_tick_util_clamps_to_unit_interval(cpu_peaks):
+    tel = DeviceTelemetry(peaks=cpu_peaks)
     hot = tel.tick_util("decode", 1e-9, 1e30, 1e30)
     assert hot == {"mfu": 1.0, "hbm_bw_util": 1.0}
     cold = tel.tick_util("decode", 10.0, 1.0, 1.0)
@@ -193,11 +201,25 @@ def test_tick_util_clamps_to_unit_interval():
     assert snap["peaks"]["flops_per_s"] > 0
 
 
-def test_detect_peaks_always_computable():
-    peaks = detect_peaks()
-    assert peaks.flops_per_s > 0 and peaks.hbm_bytes_per_s > 0
-    assert peaks.hbm_bytes > 0
-    assert peaks.source in ("detected", "assumed")
+def test_known_kind_detected_unknown_kind_raises():
+    from tpumlops.server.device_telemetry import (
+        UnknownDeviceKind,
+        peaks_for,
+    )
+
+    # What jax reports for a v5e chip.
+    peaks = peaks_for("TPU v5 lite")
+    assert peaks.source == "detected" and peaks.kind == "tpu-v5e"
+    assert peaks.flops_per_s == 197e12 and peaks.hbm_bytes_per_s == 819e9
+    assert peaks.int8_ops_per_s == 394e12 and peaks.hbm_bytes == 16 * 2**30
+    # No default row: this process's device (cpu) is not a known part.
+    with pytest.raises(UnknownDeviceKind, match="'cpu'") as exc:
+        detect_peaks()
+    assert exc.value.kind == "cpu"
+    with pytest.raises(UnknownDeviceKind, match="TPU v9"):
+        peaks_for("TPU v9")
+    with pytest.raises(UnknownDeviceKind):
+        DeviceTelemetry()
 
 
 # ---------------------------------------------------------------------------
@@ -205,11 +227,11 @@ def test_detect_peaks_always_computable():
 # ---------------------------------------------------------------------------
 
 
-def test_engine_ticks_carry_utilization_with_telemetry(tiny):
+def test_engine_ticks_carry_utilization_with_telemetry(tiny, cpu_peaks):
     from tpumlops.server.generation import GenerationEngine
 
     params, cfg = tiny
-    telemetry = DeviceTelemetry()
+    telemetry = DeviceTelemetry(peaks=cpu_peaks)
     recorder = FlightRecorder(256)
     engine = GenerationEngine(
         params, cfg, max_slots=2, telemetry=telemetry, recorder=recorder,
@@ -424,21 +446,21 @@ def test_status_capacity_appears_and_clears_with_spec_toggle():
     assert kube.get(cr_ref())["status"].get("capacity") is None
 
 
-def test_peaks_scale_to_param_device_set(tiny):
+def test_peaks_scale_to_param_device_set(tiny, cpu_peaks):
     """The cost model and ledger count the WHOLE sharded model, so the
     peaks must cover the device set holding it — and re-attaching must
     never compound the scaling."""
     from tpumlops.server.device_telemetry import param_device_count
 
     params, cfg = tiny
-    base = detect_peaks()
+    base = cpu_peaks
     s = base.scaled(8)
     assert s.chips == 8
     assert s.flops_per_s == base.flops_per_s * 8
     assert s.hbm_bytes == base.hbm_bytes * 8
     assert param_device_count(params) == 1  # unsharded tree
 
-    tel = DeviceTelemetry()
+    tel = DeviceTelemetry(peaks=cpu_peaks)
     tel.attach_model(params, cfg, 2)
     assert tel.peaks.chips == 1
     tel.attach_model(params, cfg, 2)  # idempotent, never compounds

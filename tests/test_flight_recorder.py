@@ -306,7 +306,7 @@ def test_snapshot_is_json_serializable_and_isolated():
 
 
 @pytest.fixture(scope="module")
-def traced_llm_server(tmp_path_factory):
+def traced_llm_server(tmp_path_factory, cpu_peaks):
     import jax
 
     from tpumlops.models import llama
@@ -352,7 +352,7 @@ def traced_llm_server(tmp_path_factory):
             }
         ),
     )
-    server = build_server(config)
+    server = build_server(config, peaks=cpu_peaks)
     handle = serve(server)
     yield handle
     handle.stop()

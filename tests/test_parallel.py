@@ -1,12 +1,12 @@
 """Mesh/sharding/collectives on the virtual 8-device CPU mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec
-
-from tpumlops.parallel import shard_map_compat as shard_map
 
 from tpumlops.parallel import (
     AXIS_DATA,
@@ -19,6 +19,8 @@ from tpumlops.parallel import (
     ring_shift,
     shard_pytree,
 )
+
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def test_eight_virtual_devices_present():

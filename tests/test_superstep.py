@@ -314,7 +314,7 @@ def test_tensor_parallel_parity(x64, tp):
 
 
 @pytest.mark.slow
-def test_warmup_variant_count_collapses_3x(tiny):
+def test_warmup_variant_count_collapses_3x(tiny, cpu_peaks):
     """The acceptance bar: at decodeSteps=4 + speculative + packed
     prefill the unified warmup sweep compiles >= 3x fewer jit variants
     than the legacy sweep (one per window-bucket x sampling-mode, all
@@ -325,7 +325,7 @@ def test_warmup_variant_count_collapses_3x(tiny):
     params, cfg = tiny
 
     def boot(unified):
-        tel = DeviceTelemetry()
+        tel = DeviceTelemetry(peaks=cpu_peaks)
         engine = _engine(
             params, cfg, unified=unified, max_slots=4,
             prefill_chunk=8, prefill_batch=4,

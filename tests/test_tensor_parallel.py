@@ -418,7 +418,7 @@ def test_multihost_replay_state_equality_tp2(tiny):
 
 
 @pytest.mark.slow
-def test_per_chip_ledger_and_collectives_under_tp(tiny):
+def test_per_chip_ledger_and_collectives_under_tp(tiny, cpu_peaks):
     """Device telemetry learns the tp axis: per-chip HBM components
     (exact shard bytes for the weights, heads/tp for the KV rows) and
     analytic collective walls appear at tp=2 — and the tp=1 snapshot of
@@ -432,7 +432,7 @@ def test_per_chip_ledger_and_collectives_under_tp(tiny):
     mesh = partition.build_serving_mesh({"dp": 1, "tp": 2})
     sharded = partition.shard_llama_params(params, mesh)
 
-    tel = DeviceTelemetry()
+    tel = DeviceTelemetry(peaks=cpu_peaks)
     tel.attach_model(sharded, cfg, max_slots=2)
     ledger = tel.ledger
     assert ledger.per_chip, "per-chip view missing at tp=2"
@@ -451,7 +451,7 @@ def test_per_chip_ledger_and_collectives_under_tp(tiny):
     coll = tel.cost.collective_bytes(2)
     assert coll["all_reduce"] > 0 and coll["all_gather"] > 0
 
-    tel1 = DeviceTelemetry()
+    tel1 = DeviceTelemetry(peaks=cpu_peaks)
     tel1.attach_model(params, cfg, max_slots=2)
     assert not tel1.ledger.per_chip
     assert tel1.cost.collective_bytes(2) == {}

@@ -1,0 +1,319 @@
+"""Compile-only tests against a DESCRIBED TPU v5e (no chip attached).
+
+The chip's compiler is installed beside jax; it compiles for a topology
+that is described, not present.  That shows what interpret mode and the
+CPU backend cannot: Mosaic lowering of the Pallas kernels (tiling, VMEM),
+and HBM fit of the full-width serving programs.  Nothing runs, so these
+say nothing about results or times — a pass here is not a chip run.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture that skips when it cannot be —
+never at import; every compile happens in the test's own process; the
+persistent compile cache is off around them (an entry compiled for a
+described device cannot be read back); all such tests live in THIS file,
+so exactly one xdist worker loads the TPU library.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpumlops.models import llama
+from tpumlops.models.quantization import quantize_llama
+
+# Llama-2-7B: the widths chip_smoke.py serves.
+H, L, NH, NKV, INTER, VOCAB = 4096, 32, 32, 32, 11008, 32000
+HD = H // NH
+HBM = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prior)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    """Shape tree -> the same shapes placed on the described device."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _cfg(max_seq=1024):
+    return llama.LlamaConfig(
+        vocab_size=VOCAB, hidden_size=H, num_layers=L, num_heads=NH,
+        num_kv_heads=NKV, intermediate_size=INTER, max_seq=max_seq,
+    )
+
+
+def _int8_params(cfg):
+    return jax.eval_shape(
+        lambda: quantize_llama(
+            llama.init(jax.random.key(0), cfg, dtype=jnp.bfloat16)
+        )
+    )
+
+
+def _resident_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes + m.temp_size_in_bytes
+        - m.alias_size_in_bytes
+    )
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels at 7B geometry
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "kernel", ["decode_attention", "decode_attention_batched",
+               "decode_attention_vpu"],
+)
+def test_decode_attention_kernels_lower_at_7b_geometry(one_chip, kernel):
+    from tpumlops.ops import decode_attention as da
+
+    slots, w = 16, 1024
+    s = functools.partial(_sds, one_chip)
+    args = (
+        s((slots, NKV, 1, HD), jnp.bfloat16),   # q
+        s((slots, NKV, w, HD), jnp.int8),       # k8
+        s((slots, NKV, w, 1), jnp.float32),     # k scale
+        s((slots, NKV, w, HD), jnp.int8),       # v8
+        s((slots, NKV, w, 1), jnp.float32),     # v scale
+        s((slots, NKV, 1, HD), jnp.bfloat16),   # k_self
+        s((slots, NKV, 1, HD), jnp.bfloat16),   # v_self
+        s((slots, 1, w), jnp.float32),          # mask bias
+    )
+    compiled = jax.jit(getattr(da, kernel)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_rmsnorm_lowers_at_hidden_4096(one_chip):
+    from tpumlops.ops import rmsnorm
+
+    compiled = jax.jit(rmsnorm).lower(
+        _sds(one_chip, (16, 1024, H), jnp.bfloat16),
+        _sds(one_chip, (H,), jnp.bfloat16),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("seq", [2048, 8192])
+def test_flash_attention_lowers_at_long_prefill(one_chip, seq):
+    from tpumlops.ops import flash_attention
+
+    x = _sds(one_chip, (1, NH, seq, HD), jnp.bfloat16)
+    compiled = jax.jit(
+        functools.partial(flash_attention, causal=True)
+    ).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_32k_raises_the_typed_vmem_error(one_chip):
+    """The compiler refuses this size (RESOURCE_EXHAUSTED in memory space
+    vmem: each program keeps the whole padded K and V resident); the
+    kernel says so itself, naming the limit, before reaching it."""
+    from tpumlops.ops.flash_attention import (
+        KV_VMEM_BUDGET_BYTES,
+        FlashAttentionVmemError,
+        flash_attention,
+    )
+
+    x = _sds(one_chip, (1, NH, 32768, HD), jnp.bfloat16)
+    with pytest.raises(FlashAttentionVmemError, match="32.0 MiB") as exc:
+        jax.jit(functools.partial(flash_attention, causal=True)).lower(x, x, x)
+    assert f"{KV_VMEM_BUDGET_BYTES / 2**20:.0f} MiB" in str(exc.value)
+
+
+# ---------------------------------------------------------------------------
+# Full-width serving programs: do they fit one chip's HBM?
+# ---------------------------------------------------------------------------
+
+
+def test_int8_decode_step_fits_one_chip_with_cache_donated(one_chip):
+    """THE flagship step: Llama-2-7B int8 weights + int8 KV, 16 slots x
+    1024, cache donated.  The chip's compiler credits the donation
+    (alias > 0), and what stays resident is under 16 GiB."""
+    cfg = _cfg()
+    slots = 16
+    params = _on(one_chip, _int8_params(cfg))
+    cache = _on(
+        one_chip,
+        jax.eval_shape(lambda: llama.QuantRaggedKVCache.create(cfg, slots)),
+    )
+
+    def step(params, toks, cache, active):
+        logits, cache = llama.decode_ragged(
+            params, toks, cache, cfg, active=active, window=cfg.max_seq
+        )
+        return jnp.argmax(logits[:, -1], axis=-1), cache
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params,
+        _sds(one_chip, (slots, 1), jnp.int32),
+        cache,
+        _sds(one_chip, (slots,), jnp.bool_),
+    ).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes > 2**30, "cache donation not credited"
+    assert _resident_bytes(compiled) < HBM
+
+
+def _bf16_cache(cfg, slots, one_chip):
+    return _on(
+        one_chip,
+        jax.eval_shape(lambda: llama.RaggedKVCache.create(cfg, slots)),
+    )
+
+
+@pytest.mark.parametrize(
+    "program", ["multistep", "verify", "packed_prefill", "superstep"]
+)
+def test_engine_programs_fit_one_chip_at_smoke_geometry(one_chip, program):
+    """The engine's other step programs at what chip_smoke.py's server
+    runs: int8 weights, bf16 KV, 8 slots x 1024, chunk 128."""
+    cfg = _cfg()
+    slots, chunk, steps = 8, 128, 4
+    params = _on(one_chip, _int8_params(cfg))
+    cache = _bf16_cache(cfg, slots, one_chip)
+    s = functools.partial(_sds, one_chip)
+    i32, b = jnp.int32, jnp.bool_
+
+    def greedy(logits, carry):
+        return carry, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    if program == "multistep":
+        def fn(params, toks, cache, active, remaining, eos):
+            return llama.decode_multistep(
+                params, toks, cache, cfg, active, remaining, eos, steps,
+                greedy, window=cfg.max_seq,
+            )
+        args = (s((slots, 1), i32), cache, s((slots,), b),
+                s((slots,), i32), s((slots,), i32))
+    elif program == "verify":
+        def fn(params, toks, cache, active):
+            return llama.verify_ragged(
+                params, toks, cache, cfg, window=cfg.max_seq, active=active
+            )
+        args = (s((slots, 5), i32), cache, s((slots,), b))
+    elif program == "packed_prefill":
+        def fn(params, toks, cache, rows, offsets):
+            return llama.prefill_chunks_ragged(
+                params, toks, cache, rows, offsets, cfg
+            )
+        args = (s((4, chunk), i32), cache, s((4,), i32), s((4,), i32))
+    else:
+        def fn(params, block, cache, roles, offsets, counts, draft, active,
+               remaining, eos):
+            return llama.super_step_ragged(
+                params, block, cache, cfg, roles=roles, offsets=offsets,
+                counts=counts, draft_len=draft, active=active,
+                remaining=remaining, eos_ids=eos, steps=steps,
+                sample_fn=greedy, window=cfg.max_seq,
+            )
+        vec = s((slots,), i32)
+        args = (s((slots, chunk), i32), cache, vec, vec, vec, vec,
+                s((slots,), b), vec, vec)
+
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(params, *args).compile()
+    assert _resident_bytes(compiled) < HBM
+
+
+def test_batch_generate_program_does_not_reserve_a_full_capacity_cache(one_chip):
+    """The ``/infer`` path of an LLM (``generate_greedy``, warmed per
+    batch bucket beside the serving engine).  Its loop carries a KV
+    cache; sized to ``max_seq`` that was 9 GiB of temporaries at batch 8
+    and the full-depth server could not load it on the chip (5.3 GiB
+    were free).  Sized to the 80 positions the call can reach, the
+    program's temporaries are under 2 GiB."""
+    cfg = _cfg()
+    params = _on(one_chip, _int8_params(cfg))
+    compiled = jax.jit(
+        lambda p, ids: llama.generate_greedy(p, ids, 64, cfg)
+    ).lower(params, _sds(one_chip, (8, 16), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+
+
+def test_tp4_decode_step_partitions_over_the_four_chip_host(topo):
+    """What ``chip_smoke.py --chips 4`` serves: the int8 decode step on a
+    {tp: 4} mesh over the host's four chips.  The compiler must be able
+    to partition it (collectives inserted), and what ONE device holds is
+    about a quarter of the one-chip program — not the whole model."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from tpumlops.models import partition
+
+    cfg = _cfg()
+    slots = 8
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("dp", "tp"))
+    rep, kvsh, _seq = partition.engine_state_shardings(mesh, kv_quant=False)
+    shapes = _int8_params(cfg)
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, partition.llama_param_shardings(shapes, mesh),
+    )
+    cache_shape = jax.eval_shape(
+        lambda: llama.RaggedKVCache.create(cfg, slots)
+    )
+    cache = llama.RaggedKVCache(
+        jax.ShapeDtypeStruct(cache_shape.k.shape, cache_shape.k.dtype,
+                             sharding=kvsh),
+        jax.ShapeDtypeStruct(cache_shape.v.shape, cache_shape.v.dtype,
+                             sharding=kvsh),
+        jax.ShapeDtypeStruct(cache_shape.lengths.shape, jnp.int32,
+                             sharding=rep),
+    )
+
+    def step(params, toks, cache, active):
+        logits, cache = llama.decode_ragged(
+            params, toks, cache, cfg, active=active, window=cfg.max_seq
+        )
+        return jnp.argmax(logits[:, -1], axis=-1), cache
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params,
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=rep),
+        cache,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=rep),
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    whole = sum(
+        int(np.prod(s.shape)) * s.dtype.itemsize
+        for s in jax.tree.leaves((shapes, cache_shape))
+    )
+    # A quarter of weights + cache, plus the replicated norms/scales.
+    assert whole / 4 <= per_device < whole / 3, (per_device, whole)
