@@ -1,0 +1,6 @@
+"""Host time of one decode tick of the scheduler."""
+
+
+def compute(ctx):
+    v = ctx.hist_mean("tpumlops_tick_seconds", kind="decode")
+    return None if v is None else 1e3 * v
