@@ -1,0 +1,11 @@
+"""The engine loop's period per decode step less the time it is blocked
+on the device (engine.decode_readback, engine.prefill_sync): what the
+host itself costs a step, from the server's span counters.  In a
+saturated cell a pass is a prefill chunk and a decode step, and this
+moves `tokens_per_s`."""
+from harness import spans
+
+
+def compute(ctx):
+    d = spans.read(ctx)
+    return None if d is None else spans.loop_host_ms(d)

@@ -366,6 +366,51 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _attn_qkv(x, lp, cos, sin, cfg):
+    """A layer's attention prologue: norm, the three projections, RoPE.
+    ``x`` [B,S,H] -> q [B,S,NH,D], k and v [B,S,NKV,D]."""
+    b, s, _h = x.shape
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    with jax.named_scope("layer.attn_qkv"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q = _qmatmul(xn, lp["q"])
+        k = _qmatmul(xn, lp["k"])
+        v = _qmatmul(xn, lp["v"])
+        q = q.astype(x.dtype).reshape(b, s, nh, hd)
+        k = k.astype(x.dtype).reshape(b, s, nkv, hd)
+        v = v.astype(x.dtype).reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    return q, k, v
+
+
+def _attn_out_mlp(x, ctx, lp, cfg):
+    """A layer's epilogue: the attention output projection and the
+    SwiGLU MLP, each with its residual.  ``ctx`` is [B,S,NH*D]."""
+    with jax.named_scope("layer.attn_out"):
+        attn_out = _qmatmul(ctx, lp["o"]).astype(x.dtype)
+        x = x + attn_out
+    with jax.named_scope("layer.mlp"):
+        xn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        gate = _qmatmul(xn, lp["gate"])
+        up = _qmatmul(xn, lp["up"])
+        act = jax.nn.silu(gate) * up
+        down = _qmatmul(act.astype(x.dtype), lp["down"]).astype(x.dtype)
+        return x + down
+
+
+def _embed(params, token_ids, dtype):
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"], token_ids, axis=0).astype(dtype)
+
+
+def _head(params, x, cfg):
+    """Final norm and lm_head: logits in float32."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return _qmatmul(x, params["lm_head"])
+
+
 def _block(
     x: jax.Array,
     lp: dict,
@@ -396,16 +441,7 @@ def _block(
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = _qmatmul(xn, lp["q"])
-    k = _qmatmul(xn, lp["k"])
-    v = _qmatmul(xn, lp["v"])
-    q = q.astype(x.dtype).reshape(b, s, nh, hd)
-    k = k.astype(x.dtype).reshape(b, s, nkv, hd)
-    v = v.astype(x.dtype).reshape(b, s, nkv, hd)
-
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _attn_qkv(x, lp, cos, sin, cfg)
 
     # Write this chunk's K/V into the cache at [start : start+s].
     # A quantized cache layer arrives as pairs (values int8, scales): the
@@ -427,72 +463,66 @@ def _block(
             )
         return out
 
-    if quant_cache:
-        k8, ks = cache_k
-        v8, vs = cache_v
-        kq, kqs = _quant_kv(k)
-        vq, vqs = _quant_kv(v)
-        k8, ks, v8, vs = _write_all([(k8, kq), (ks, kqs), (v8, vq), (vs, vqs)])
-        cache_k = (k8, ks)
-        cache_v = (v8, vs)
-    else:
-        cache_k, cache_v = _write_all([(cache_k, k), (cache_v, v)])
+    with jax.named_scope("kv_commit"):
+        if quant_cache:
+            k8, ks = cache_k
+            v8, vs = cache_v
+            kq, kqs = _quant_kv(k)
+            vq, vqs = _quant_kv(v)
+            k8, ks, v8, vs = _write_all([(k8, kq), (ks, kqs), (v8, vq), (vs, vqs)])
+            cache_k = (k8, ks)
+            cache_v = (v8, vs)
+        else:
+            cache_k, cache_v = _write_all([(cache_k, k), (cache_v, v)])
 
-    # GQA via grouped einsum: q reshaped to [B,S,NKV,G,D] contracts directly
-    # against the [B,T,NKV,D] cache — no materialized repeat of K/V to all
-    # query heads (that broadcast would dominate HBM traffic at decode).
-    group = nh // nkv
-    qg = q.reshape(b, s, nkv, group, hd)
-    if quant_cache:
-        # The per-(position, head) scales are CONSTANT over the contracted
-        # head_dim axis, so they factor OUT of both einsums: contract the
-        # raw int8 cache (the int8->bf16 convert fuses into the operand
-        # read like the weight path) and fold K's scale into the scores,
-        # V's into the probabilities.  A naive dequantize-then-einsum
-        # materializes a full bf16 copy of the cache window per step —
-        # measured SLOWER than the bf16 cache it was meant to beat.
-        k8, ks = cache_k
-        v8, vs = cache_v
-        if window is not None:
-            k8, ks = k8[:, :window], ks[:, :window]
-            v8, vs = v8[:, :window], vs[:, :window]
-        scores = jnp.einsum(
-            "bqngd,bknd->bngqk",
-            qg,
-            k8.astype(x.dtype),
-            preferred_element_type=jnp.float32,
-        ) / jnp.sqrt(jnp.float32(hd))
-        # ks: [B, W, NKV, 1] -> [B, NKV, 1, 1, W] broadcast over (G, S)
-        kscale = jnp.moveaxis(ks[..., 0], 1, 2)[:, :, None, None, :]
-        scores = scores * kscale
-        scores = scores + mask_bias[:, None]
-        probs = jax.nn.softmax(scores, axis=-1)
-        vscale = jnp.moveaxis(vs[..., 0], 1, 2)[:, :, None, None, :]
-        probs = (probs * vscale).astype(x.dtype)
-        ctx = jnp.einsum(
-            "bngqk,bknd->bqngd", probs, v8.astype(x.dtype)
-        ).reshape(b, s, nh * hd)
-    else:
-        kk = cache_k if window is None else cache_k[:, :window]
-        vv = cache_v if window is None else cache_v[:, :window]
-        kk = kk.astype(x.dtype)
-        vv = vv.astype(x.dtype)
+    with jax.named_scope("layer.attn_core"):
+        # GQA via grouped einsum: q reshaped to [B,S,NKV,G,D] contracts directly
+        # against the [B,T,NKV,D] cache — no materialized repeat of K/V to all
+        # query heads (that broadcast would dominate HBM traffic at decode).
+        group = nh // nkv
+        qg = q.reshape(b, s, nkv, group, hd)
+        if quant_cache:
+            # The per-(position, head) scales are CONSTANT over the contracted
+            # head_dim axis, so they factor OUT of both einsums: contract the
+            # raw int8 cache (the int8->bf16 convert fuses into the operand
+            # read like the weight path) and fold K's scale into the scores,
+            # V's into the probabilities.  A naive dequantize-then-einsum
+            # materializes a full bf16 copy of the cache window per step —
+            # measured SLOWER than the bf16 cache it was meant to beat.
+            k8, ks = cache_k
+            v8, vs = cache_v
+            if window is not None:
+                k8, ks = k8[:, :window], ks[:, :window]
+                v8, vs = v8[:, :window], vs[:, :window]
+            scores = jnp.einsum(
+                "bqngd,bknd->bngqk",
+                qg,
+                k8.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(jnp.float32(hd))
+            # ks: [B, W, NKV, 1] -> [B, NKV, 1, 1, W] broadcast over (G, S)
+            kscale = jnp.moveaxis(ks[..., 0], 1, 2)[:, :, None, None, :]
+            scores = scores * kscale
+            scores = scores + mask_bias[:, None]
+            probs = jax.nn.softmax(scores, axis=-1)
+            vscale = jnp.moveaxis(vs[..., 0], 1, 2)[:, :, None, None, :]
+            probs = (probs * vscale).astype(x.dtype)
+            ctx = jnp.einsum(
+                "bngqk,bknd->bqngd", probs, v8.astype(x.dtype)
+            ).reshape(b, s, nh * hd)
+        else:
+            kk = cache_k if window is None else cache_k[:, :window]
+            vv = cache_v if window is None else cache_v[:, :window]
+            kk = kk.astype(x.dtype)
+            vv = vv.astype(x.dtype)
 
-        scores = jnp.einsum(
-            "bqngd,bknd->bngqk", qg, kk, preferred_element_type=jnp.float32
-        ) / jnp.sqrt(jnp.float32(hd))
-        scores = scores + mask_bias[:, None]  # [B or 1, 1, 1, S, T]
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum("bngqk,bknd->bqngd", probs, vv).reshape(b, s, nh * hd)
-    attn_out = _qmatmul(ctx, lp["o"]).astype(x.dtype)
-    x = x + attn_out
-
-    xn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    gate = _qmatmul(xn, lp["gate"])
-    up = _qmatmul(xn, lp["up"])
-    act = jax.nn.silu(gate) * up
-    down = _qmatmul(act.astype(x.dtype), lp["down"]).astype(x.dtype)
-    return x + down, cache_k, cache_v
+            scores = jnp.einsum(
+                "bqngd,bknd->bngqk", qg, kk, preferred_element_type=jnp.float32
+            ) / jnp.sqrt(jnp.float32(hd))
+            scores = scores + mask_bias[:, None]  # [B or 1, 1, 1, S, T]
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bknd->bqngd", probs, vv).reshape(b, s, nh * hd)
+    return _attn_out_mlp(x, ctx, lp, cfg), cache_k, cache_v
 
 
 def _block_decode_deferred(
@@ -527,15 +557,7 @@ def _block_decode_deferred(
     b, s, h = x.shape  # s == 1 by contract
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = _qmatmul(xn, lp["q"])
-    k = _qmatmul(xn, lp["k"])
-    v = _qmatmul(xn, lp["v"])
-    q = q.astype(x.dtype).reshape(b, s, nh, hd)
-    k = k.astype(x.dtype).reshape(b, s, nkv, hd)
-    v = v.astype(x.dtype).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _attn_qkv(x, lp, cos, sin, cfg)
 
     group = nh // nkv
     qg = q.reshape(b, s, nkv, group, hd)
@@ -551,93 +573,81 @@ def _block_decode_deferred(
             f"(got G={group}, window={window})"
         )
     if quant_cache and impl.startswith("pallas"):
-        # Fused Pallas path: program(s) over (slot-block, kv-head) do both
-        # MXU dots over the VMEM-resident int8 window with scales folded
-        # into score/prob rows and the self-term joined in-softmax —
-        # replacing the ~15-op XLA chain below (ops/decode_attention.py;
-        # dispatch measured by scripts/ab_attention.py).  "pallas" is the
-        # slot-batched kernel (grid divided by the slot block — the
-        # per-program overhead was a ~1 ms/slot linear term at 1.35B);
-        # "pallas_single" keeps one program per (slot, head) for A/B.
-        from ..ops.decode_attention import (
-            decode_attention, decode_attention_batched, decode_attention_vpu)
+        with jax.named_scope("layer.attn_core"):
+            # Fused Pallas path: program(s) over (slot-block, kv-head) do both
+            # MXU dots over the VMEM-resident int8 window with scales folded
+            # into score/prob rows and the self-term joined in-softmax —
+            # replacing the ~15-op XLA chain below (ops/decode_attention.py;
+            # dispatch measured by scripts/ab_attention.py).  "pallas" is the
+            # slot-batched kernel (grid divided by the slot block — the
+            # per-program overhead was a ~1 ms/slot linear term at 1.35B);
+            # "pallas_single" keeps one program per (slot, head) for A/B.
+            from ..ops.decode_attention import (
+                decode_attention, decode_attention_batched, decode_attention_vpu)
 
-        attn_fn = {
-            "pallas_single": decode_attention,
-            "pallas_vpu": decode_attention_vpu,
-        }.get(impl, decode_attention_batched)
-        k8, ks = cache_k
-        v8, vs = cache_v
-        ctx4 = attn_fn(
-            qg[:, 0],                                   # [B, NKV, G, D]
-            k8[:, :, :window],
-            ks[:, :, :window],                          # [B, NKV, W, 1]
-            v8[:, :, :window],
-            vs[:, :, :window],
-            k[:, 0][:, :, None, :],                     # [B, NKV, 1, D]
-            v[:, 0][:, :, None, :],
-            mask_bias[:, 0],                            # [B, 1, W]
+            attn_fn = {
+                "pallas_single": decode_attention,
+                "pallas_vpu": decode_attention_vpu,
+            }.get(impl, decode_attention_batched)
+            k8, ks = cache_k
+            v8, vs = cache_v
+            ctx4 = attn_fn(
+                qg[:, 0],                                   # [B, NKV, G, D]
+                k8[:, :, :window],
+                ks[:, :, :window],                          # [B, NKV, W, 1]
+                v8[:, :, :window],
+                vs[:, :, :window],
+                k[:, 0][:, :, None, :],                     # [B, NKV, 1, D]
+                v[:, 0][:, :, None, :],
+                mask_bias[:, 0],                            # [B, 1, W]
+            )
+            ctx = ctx4[:, None].astype(x.dtype).reshape(b, s, nh * hd)
+        return _attn_out_mlp(x, ctx, lp, cfg), k, v
+    with jax.named_scope("layer.attn_core"):
+        if quant_cache:
+            k8, ks = cache_k
+            v8, vs = cache_v
+            k8, ks = k8[:, :, :window], ks[:, :, :window]
+            v8, vs = v8[:, :, :window], vs[:, :, :window]
+            scores = jnp.einsum(
+                "bqngd,bnkd->bngqk",
+                qg,
+                k8.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(jnp.float32(hd))
+            # ks: [B, NKV, W, 1] -> [B, NKV, 1, 1, W] — head-major layout
+            # means NO transposed copy, just a reshape of the window slice.
+            kscale = ks[..., 0][:, :, None, None, :]
+            scores = scores * kscale
+        else:
+            kk = cache_k[:, :, :window].astype(x.dtype)
+            scores = jnp.einsum(
+                "bqngd,bnkd->bngqk", qg, kk, preferred_element_type=jnp.float32
+            ) / jnp.sqrt(jnp.float32(hd))
+        scores = scores + mask_bias[:, None]
+
+        # Exact self-term for the current (not-yet-written) position.
+        score_self = (
+            jnp.einsum("bqngd,bqnd->bngq", qg, k, preferred_element_type=jnp.float32)
+            / jnp.sqrt(jnp.float32(hd))
+        )[..., None]
+        full = jnp.concatenate([scores, score_self], axis=-1)
+        probs = jax.nn.softmax(full, axis=-1)
+        probs_cache, prob_self = probs[..., :-1], probs[..., -1:]
+
+        if quant_cache:
+            vscale = vs[..., 0][:, :, None, None, :]
+            probs_cache = (probs_cache * vscale).astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache, v8.astype(x.dtype))
+        else:
+            vv = cache_v[:, :, :window].astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache.astype(x.dtype), vv)
+        ctx = ctx + jnp.einsum(
+            "bngqk,bknd->bqngd", prob_self.astype(x.dtype), v
         )
-        ctx = ctx4[:, None].astype(x.dtype).reshape(b, s, nh * hd)
-        attn_out = _qmatmul(ctx, lp["o"]).astype(x.dtype)
-        x = x + attn_out
-        xn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        gate = _qmatmul(xn, lp["gate"])
-        up = _qmatmul(xn, lp["up"])
-        act = jax.nn.silu(gate) * up
-        down = _qmatmul(act.astype(x.dtype), lp["down"]).astype(x.dtype)
-        return x + down, k, v
-    if quant_cache:
-        k8, ks = cache_k
-        v8, vs = cache_v
-        k8, ks = k8[:, :, :window], ks[:, :, :window]
-        v8, vs = v8[:, :, :window], vs[:, :, :window]
-        scores = jnp.einsum(
-            "bqngd,bnkd->bngqk",
-            qg,
-            k8.astype(x.dtype),
-            preferred_element_type=jnp.float32,
-        ) / jnp.sqrt(jnp.float32(hd))
-        # ks: [B, NKV, W, 1] -> [B, NKV, 1, 1, W] — head-major layout
-        # means NO transposed copy, just a reshape of the window slice.
-        kscale = ks[..., 0][:, :, None, None, :]
-        scores = scores * kscale
-    else:
-        kk = cache_k[:, :, :window].astype(x.dtype)
-        scores = jnp.einsum(
-            "bqngd,bnkd->bngqk", qg, kk, preferred_element_type=jnp.float32
-        ) / jnp.sqrt(jnp.float32(hd))
-    scores = scores + mask_bias[:, None]
+        ctx = ctx.reshape(b, s, nh * hd)
 
-    # Exact self-term for the current (not-yet-written) position.
-    score_self = (
-        jnp.einsum("bqngd,bqnd->bngq", qg, k, preferred_element_type=jnp.float32)
-        / jnp.sqrt(jnp.float32(hd))
-    )[..., None]
-    full = jnp.concatenate([scores, score_self], axis=-1)
-    probs = jax.nn.softmax(full, axis=-1)
-    probs_cache, prob_self = probs[..., :-1], probs[..., -1:]
-
-    if quant_cache:
-        vscale = vs[..., 0][:, :, None, None, :]
-        probs_cache = (probs_cache * vscale).astype(x.dtype)
-        ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache, v8.astype(x.dtype))
-    else:
-        vv = cache_v[:, :, :window].astype(x.dtype)
-        ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache.astype(x.dtype), vv)
-    ctx = ctx + jnp.einsum(
-        "bngqk,bknd->bqngd", prob_self.astype(x.dtype), v
-    )
-    ctx = ctx.reshape(b, s, nh * hd)
-
-    attn_out = _qmatmul(ctx, lp["o"]).astype(x.dtype)
-    x = x + attn_out
-    xn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    gate = _qmatmul(xn, lp["gate"])
-    up = _qmatmul(xn, lp["up"])
-    act = jax.nn.silu(gate) * up
-    down = _qmatmul(act.astype(x.dtype), lp["down"]).astype(x.dtype)
-    return x + down, k, v
+    return _attn_out_mlp(x, ctx, lp, cfg), k, v
 
 
 def forward(
@@ -659,7 +669,7 @@ def forward(
             f"max_seq={cfg.max_seq}"
         )
     start = cache.length
-    x = jnp.take(params["embed"], input_ids, axis=0).astype(dtype)
+    x = _embed(params, input_ids, dtype)
 
     positions = start + jnp.arange(s)
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)
@@ -680,8 +690,7 @@ def forward(
     x, (new_k, new_v) = lax.scan(
         scan_body, x, (params["layers"], cache.k, cache.v)
     )
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _qmatmul(x, params["lm_head"])
+    logits = _head(params, x, cfg)
     new_cache = KVCache(k=new_k, v=new_v, length=start + s)
     return logits, new_cache
 
@@ -763,7 +772,7 @@ def prefill_ring(
     from jax.sharding import NamedSharding, PartitionSpec
 
     b, s = input_ids.shape
-    x = jnp.take(params["embed"], input_ids, axis=0).astype(dtype)
+    x = _embed(params, input_ids, dtype)
     # Pin activations seq-sharded so the per-token work (norms, MLP,
     # projections) partitions over sp too, not just the attention.
     seq_sharded = NamedSharding(mesh, PartitionSpec(None, axis_name, None))
@@ -870,7 +879,7 @@ def decode_ragged(
         raise ValueError(f"decode_ragged is single-token: got chunk of {s}")
     quant = isinstance(cache, QuantRaggedKVCache)
     lengths = cache.lengths
-    x = jnp.take(params["embed"], token_ids, axis=0).astype(dtype)
+    x = _embed(params, token_ids, dtype)
 
     positions = lengths[:, None]  # [B, 1]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)  # [B, 1, head_dim]
@@ -1067,106 +1076,92 @@ def _block_verify_deferred(
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = _qmatmul(xn, lp["q"])
-    k = _qmatmul(xn, lp["k"])
-    v = _qmatmul(xn, lp["v"])
-    q = q.astype(x.dtype).reshape(b, s, nh, hd)
-    k = k.astype(x.dtype).reshape(b, s, nkv, hd)
-    v = v.astype(x.dtype).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _attn_qkv(x, lp, cos, sin, cfg)
 
-    group = nh // nkv
-    qg = q.reshape(b, s, nkv, group, hd)
-    quant_cache = isinstance(cache_k, tuple)
-    if quant_cache:
-        k8, ks = cache_k
-        v8, vs = cache_v
-        k8, ks = k8[:, :, :window], ks[:, :, :window]
-        v8, vs = v8[:, :, :window], vs[:, :, :window]
-        scores = jnp.einsum(
-            "bqngd,bnkd->bngqk",
-            qg,
-            k8.astype(x.dtype),
-            preferred_element_type=jnp.float32,
+    with jax.named_scope("layer.attn_core"):
+        group = nh // nkv
+        qg = q.reshape(b, s, nkv, group, hd)
+        quant_cache = isinstance(cache_k, tuple)
+        if quant_cache:
+            k8, ks = cache_k
+            v8, vs = cache_v
+            k8, ks = k8[:, :, :window], ks[:, :, :window]
+            v8, vs = v8[:, :, :window], vs[:, :, :window]
+            scores = jnp.einsum(
+                "bqngd,bnkd->bngqk",
+                qg,
+                k8.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(jnp.float32(hd))
+            kscale = ks[..., 0][:, :, None, None, :]
+            scores = scores * kscale
+        else:
+            kk = cache_k[:, :, :window].astype(x.dtype)
+            scores = jnp.einsum(
+                "bqngd,bnkd->bngqk", qg, kk, preferred_element_type=jnp.float32
+            ) / jnp.sqrt(jnp.float32(hd))
+        scores = scores + mask_bias[:, None]  # [B,1,1,W] -> over (n, g, q)
+
+        # In-chunk causal scores over the fresh (not-yet-written) K rows.
+        # Only the SELF position (j == q) may use the exact full-precision
+        # term — that mirrors _block_decode_deferred, where the current
+        # token is attended in-flight.  Every EARLIER chunk position was, on
+        # the sequential path, already committed to the cache before being
+        # attended — on the int8 cache that means a quantize round-trip —
+        # so the chunk term must read those positions through the same
+        # round-trip (raw int8 contraction, scales folded out, exactly like
+        # the cache-window term above) or verify logits diverge from plain
+        # int8kv decode by the QUANTIZATION error, not mere reduction
+        # rounding, and near-tie argmaxes break token parity.
+        score_self = jnp.einsum(
+            "bqngd,bjnd->bngqj", qg, k, preferred_element_type=jnp.float32
         ) / jnp.sqrt(jnp.float32(hd))
-        kscale = ks[..., 0][:, :, None, None, :]
-        scores = scores * kscale
-    else:
-        kk = cache_k[:, :, :window].astype(x.dtype)
-        scores = jnp.einsum(
-            "bqngd,bnkd->bngqk", qg, kk, preferred_element_type=jnp.float32
-        ) / jnp.sqrt(jnp.float32(hd))
-    scores = scores + mask_bias[:, None]  # [B,1,1,W] -> over (n, g, q)
+        if quant_cache:
+            k8c, kscc = _quant_kv(k)  # [B,S,NKV,D] / [B,S,NKV,1]
+            score_rt = jnp.einsum(
+                "bqngd,bjnd->bngqj",
+                qg,
+                k8c.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(jnp.float32(hd))
+            kscale_c = jnp.moveaxis(kscc[..., 0], 1, 2)[:, :, None, None, :]
+            score_rt = score_rt * kscale_c
+            eye = jnp.eye(s, dtype=bool)[None, None, None]
+            score_chunk = jnp.where(eye, score_self, score_rt)
+        else:
+            score_chunk = score_self
+        score_chunk = score_chunk + chunk_bias  # [1,1,1,S,S]
+        full = jnp.concatenate([scores, score_chunk], axis=-1)
+        probs = jax.nn.softmax(full, axis=-1)
+        probs_cache, probs_chunk = probs[..., :-s], probs[..., -s:]
 
-    # In-chunk causal scores over the fresh (not-yet-written) K rows.
-    # Only the SELF position (j == q) may use the exact full-precision
-    # term — that mirrors _block_decode_deferred, where the current
-    # token is attended in-flight.  Every EARLIER chunk position was, on
-    # the sequential path, already committed to the cache before being
-    # attended — on the int8 cache that means a quantize round-trip —
-    # so the chunk term must read those positions through the same
-    # round-trip (raw int8 contraction, scales folded out, exactly like
-    # the cache-window term above) or verify logits diverge from plain
-    # int8kv decode by the QUANTIZATION error, not mere reduction
-    # rounding, and near-tie argmaxes break token parity.
-    score_self = jnp.einsum(
-        "bqngd,bjnd->bngqj", qg, k, preferred_element_type=jnp.float32
-    ) / jnp.sqrt(jnp.float32(hd))
-    if quant_cache:
-        k8c, kscc = _quant_kv(k)  # [B,S,NKV,D] / [B,S,NKV,1]
-        score_rt = jnp.einsum(
-            "bqngd,bjnd->bngqj",
-            qg,
-            k8c.astype(x.dtype),
-            preferred_element_type=jnp.float32,
-        ) / jnp.sqrt(jnp.float32(hd))
-        kscale_c = jnp.moveaxis(kscc[..., 0], 1, 2)[:, :, None, None, :]
-        score_rt = score_rt * kscale_c
-        eye = jnp.eye(s, dtype=bool)[None, None, None]
-        score_chunk = jnp.where(eye, score_self, score_rt)
-    else:
-        score_chunk = score_self
-    score_chunk = score_chunk + chunk_bias  # [1,1,1,S,S]
-    full = jnp.concatenate([scores, score_chunk], axis=-1)
-    probs = jax.nn.softmax(full, axis=-1)
-    probs_cache, probs_chunk = probs[..., :-s], probs[..., -s:]
+        if quant_cache:
+            vscale = vs[..., 0][:, :, None, None, :]
+            probs_cache = (probs_cache * vscale).astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache, v8.astype(x.dtype))
+            # Chunk V: self row full-precision, earlier rows through the
+            # int8 round-trip (scales folded into the probabilities, like
+            # the cache-window term).
+            v8c, vscc = _quant_kv(v)
+            vscale_c = jnp.moveaxis(vscc[..., 0], 1, 2)[:, :, None, None, :]
+            eyef = eye.astype(probs.dtype)
+            ctx = ctx + jnp.einsum(
+                "bngqj,bjnd->bqngd", (probs_chunk * eyef).astype(x.dtype), v
+            )
+            ctx = ctx + jnp.einsum(
+                "bngqj,bjnd->bqngd",
+                (probs_chunk * (1.0 - eyef) * vscale_c).astype(x.dtype),
+                v8c.astype(x.dtype),
+            )
+        else:
+            vv = cache_v[:, :, :window].astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache.astype(x.dtype), vv)
+            ctx = ctx + jnp.einsum(
+                "bngqj,bjnd->bqngd", probs_chunk.astype(x.dtype), v
+            )
+        ctx = ctx.reshape(b, s, nh * hd)
 
-    if quant_cache:
-        vscale = vs[..., 0][:, :, None, None, :]
-        probs_cache = (probs_cache * vscale).astype(x.dtype)
-        ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache, v8.astype(x.dtype))
-        # Chunk V: self row full-precision, earlier rows through the
-        # int8 round-trip (scales folded into the probabilities, like
-        # the cache-window term).
-        v8c, vscc = _quant_kv(v)
-        vscale_c = jnp.moveaxis(vscc[..., 0], 1, 2)[:, :, None, None, :]
-        eyef = eye.astype(probs.dtype)
-        ctx = ctx + jnp.einsum(
-            "bngqj,bjnd->bqngd", (probs_chunk * eyef).astype(x.dtype), v
-        )
-        ctx = ctx + jnp.einsum(
-            "bngqj,bjnd->bqngd",
-            (probs_chunk * (1.0 - eyef) * vscale_c).astype(x.dtype),
-            v8c.astype(x.dtype),
-        )
-    else:
-        vv = cache_v[:, :, :window].astype(x.dtype)
-        ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache.astype(x.dtype), vv)
-        ctx = ctx + jnp.einsum(
-            "bngqj,bjnd->bqngd", probs_chunk.astype(x.dtype), v
-        )
-    ctx = ctx.reshape(b, s, nh * hd)
-
-    attn_out = _qmatmul(ctx, lp["o"]).astype(x.dtype)
-    x = x + attn_out
-    xn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    gate = _qmatmul(xn, lp["gate"])
-    up = _qmatmul(xn, lp["up"])
-    act = jax.nn.silu(gate) * up
-    down = _qmatmul(act.astype(x.dtype), lp["down"]).astype(x.dtype)
-    return x + down, k, v
+    return _attn_out_mlp(x, ctx, lp, cfg), k, v
 
 
 def verify_ragged(
@@ -1202,7 +1197,7 @@ def verify_ragged(
     b, s = token_ids.shape
     quant = isinstance(cache, QuantRaggedKVCache)
     lengths = cache.lengths
-    x = jnp.take(params["embed"], token_ids, axis=0).astype(dtype)
+    x = _embed(params, token_ids, dtype)
 
     positions = lengths[:, None] + jnp.arange(s)[None, :]  # [B, S]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)  # [B, S, head_dim]
@@ -1262,11 +1257,11 @@ def verify_ragged(
         return y, acc_k, acc_v
 
     x, k_news, v_news = lax.fori_loop(0, nlayers, layer_body, (x, acc_k, acc_v))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _qmatmul(x, params["lm_head"])
+    logits = _head(params, x, cfg)
     return logits, _commit_chunk(cache, k_news, v_news, lengths, quant, active)
 
 
+@jax.named_scope("kv_commit")
 def _commit_chunk(cache, k_news, v_news, lengths, quant, active=None):
     """Commit a verify chunk's K/V: row ``b``'s token ``j`` lands at
     position ``lengths[b] + j``, ONE batched drop-scatter per buffer
@@ -1354,7 +1349,7 @@ def prefill_chunks_ragged(
     """
     b, s = token_ids.shape
     quant = isinstance(cache, QuantRaggedKVCache)
-    x = jnp.take(params["embed"], token_ids, axis=0).astype(dtype)
+    x = _embed(params, token_ids, dtype)
 
     positions = offsets[:, None] + jnp.arange(s)[None, :]  # [B_p, C]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)
@@ -1417,11 +1412,11 @@ def prefill_chunks_ragged(
         return y, acc_k, acc_v
 
     x, k_news, v_news = lax.fori_loop(0, nlayers, layer_body, (x, acc_k, acc_v))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _qmatmul(x, params["lm_head"])
+    logits = _head(params, x, cfg)
     return logits, _commit_chunk_at(cache, k_news, v_news, slots, offsets, quant)
 
 
+@jax.named_scope("kv_commit")
 def _commit_chunk_at(cache, k_news, v_news, slots, offsets, quant):
     """Commit a packed prefill chunk's K/V: row ``b``'s token ``j`` lands
     at ``(slots[b], offsets[b] + j)`` — :func:`_commit_chunk` with a
@@ -1466,6 +1461,7 @@ ROLE_VERIFY = 2
 ROLE_PREFILL = 3
 
 
+@jax.named_scope("kv_commit")
 def _commit_block_at(cache, k_news, v_news, base, counts, quant):
     """Commit a super-step chunk's K/V with PER-POSITION parking: row
     ``b``'s token ``j`` lands at ``base[b] + j`` when ``j < counts[b]``
@@ -1591,7 +1587,7 @@ def super_step_ragged(
     # a forward.
     base = jnp.where(is_pre, offsets, lengths).astype(jnp.int32)
 
-    x = jnp.take(params["embed"], token_block, axis=0).astype(dtype)
+    x = _embed(params, token_block, dtype)
     positions = base[:, None] + jnp.arange(s)[None, :]  # [B, S]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)
 
@@ -1648,8 +1644,7 @@ def super_step_ragged(
         return y, acc_k, acc_v
 
     x, k_news, v_news = lax.fori_loop(0, nlayers, layer_body, (x, acc_k, acc_v))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _qmatmul(x, params["lm_head"])  # [B, S, vocab] f32
+    logits = _head(params, x, cfg)  # [B, S, vocab] f32
 
     cache = _commit_block_at(cache, k_news, v_news, base, counts, quant)
 
@@ -1703,8 +1698,7 @@ def _finish_decode(params, x, k_news, v_news, cache, lengths, active, quant, cfg
     token row, committed with one write pass (see ``_commit_rows``).
     """
     b = x.shape[0]
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = _qmatmul(x, params["lm_head"])
+    logits = _head(params, x, cfg)
     advance = (
         jnp.ones((b,), jnp.int32) if active is None else active.astype(jnp.int32)
     )
@@ -1733,6 +1727,7 @@ def _finish_decode(params, x, k_news, v_news, cache, lengths, active, quant, cfg
     )
 
 
+@jax.named_scope("kv_commit")
 def _commit_rows(buf: jax.Array, vals: jax.Array, lengths: jax.Array) -> jax.Array:
     """Write row ``b``'s new K/V at its own position, in place.
 
@@ -1765,6 +1760,7 @@ def _commit_rows(buf: jax.Array, vals: jax.Array, lengths: jax.Array) -> jax.Arr
     )
 
 
+@jax.named_scope("kv_commit")
 def insert_sequence(
     cache: "RaggedKVCache | QuantRaggedKVCache",
     seq: KVCache,

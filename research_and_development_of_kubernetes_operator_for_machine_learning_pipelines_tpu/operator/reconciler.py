@@ -14,6 +14,7 @@ the runtime can interleave many resources on one thread.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import logging
@@ -44,6 +45,7 @@ from ..utils.config import (
     TPU_TOPOLOGIES,
 )
 from ..utils.logging import model_logger
+from ..utils.tracing import span
 from .builder import build_deployment
 from .judge import should_promote
 from .rollout_recorder import CrashLoopRecord, GateRecord, TransitionRecord
@@ -84,25 +86,6 @@ def _capacity_summary(config: OperatorConfig) -> "dict | None":
         out["hbmGiBPerChip"] = hbm_per_chip
         out["hbmGiBTotal"] = hbm_per_chip * info.chips
     return out
-
-
-class _OpTimer:
-    """Context manager accumulating wall seconds into ``sink[component]``."""
-
-    __slots__ = ("_sink", "_component", "_t0")
-
-    def __init__(self, sink: dict, component: str):
-        self._sink = sink
-        self._component = component
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-
-    def __exit__(self, *exc):
-        self._sink[self._component] = self._sink.get(self._component, 0.0) + (
-            time.perf_counter() - self._t0
-        )
-        return False
 
 
 @dataclass
@@ -261,13 +244,27 @@ class Reconciler:
 
     # -- main entry ----------------------------------------------------------
 
+    @contextlib.contextmanager
     def _op_timer(self, component: str):
-        """Accumulate wall time of one operation class into the step's
-        timing breakdown (read back through ReconcileOutcome.timings)."""
-        return _OpTimer(self._timings, component)
+        """One operation class of the step: the span
+        ``operator.<component>`` (``/debug/spans``), its wall time also
+        accumulated into the step's timing breakdown (read back through
+        ReconcileOutcome.timings)."""
+        t0 = time.perf_counter()
+        try:
+            with span("operator." + component):
+                yield
+        finally:
+            self._timings[component] = self._timings.get(component, 0.0) + (
+                time.perf_counter() - t0
+            )
 
     def reconcile(self, obj: dict) -> ReconcileOutcome:
         """One reconcile step for the given CR object (spec+status+metadata)."""
+        with span("operator.reconcile"):
+            return self._reconcile_step(obj)
+
+    def _reconcile_step(self, obj: dict) -> ReconcileOutcome:
         self._timings = {}
         self._pending_records = []
         self._scale_record = None

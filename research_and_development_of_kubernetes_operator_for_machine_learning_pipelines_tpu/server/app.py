@@ -855,7 +855,9 @@ class TpuInferenceServer:
         tokens: asyncio.Queue = asyncio.Queue()
 
         def on_token(t: int) -> None:  # scheduler thread -> event loop
-            loop.call_soon_threadsafe(tokens.put_nowait, int(t))
+            loop.call_soon_threadsafe(
+                tokens.put_nowait, (int(t), time.perf_counter())
+            )
 
         trace = RequestTrace(
             request_id=request_id,
@@ -887,11 +889,13 @@ class TpuInferenceServer:
                 item = await tokens.get()
                 if item is None:
                     break
-                emitted.append(item)
+                token, stamped = item
+                emitted.append(token)
                 if len(emitted) == 1:
                     self.note_first_token()
-                payload = json.dumps({"index": len(emitted) - 1, "token": item})
+                payload = json.dumps({"index": len(emitted) - 1, "token": token})
                 await resp.write(f"data: {payload}\n\n".encode())
+                self.metrics.observe_emit_lag(time.perf_counter() - stamped)
             if fut.cancelled():
                 codebox["code"] = 499
                 await _write_sse_error(
@@ -974,13 +978,22 @@ class TpuInferenceServer:
 
         ``POST /debug/profile {"duration_s": 3}`` records device + host
         activity for the window and returns the trace directory (TensorBoard
-        / xprof readable; always under ``/tmp/tpumlops-profile`` — the
-        endpoint is unauthenticated, so no caller-chosen paths).  One
-        capture at a time.  After a successful capture only the newest
+        / xprof readable; always under ``<tempfile.gettempdir()>/
+        tpumlops-profile``, which honours ``$TMPDIR`` — the endpoint is
+        unauthenticated, so no caller-chosen paths).  One capture at a
+        time.  After a successful capture only the newest
         :data:`PROFILE_KEEP_DIRS` capture directories are kept — older
         ones are deleted (the dir used to grow without bound across
-        calls) and returned as ``evicted``."""
+        calls) and returned as ``evicted``.
+
+        The Python tracer is off (``python_tracer_level`` 0): it slows
+        the engine thread whose gaps the capture is meant to show, and
+        the ``engine.*`` spans (host TraceMe events, still recorded) say
+        what it was left on to show.  ``start_trace`` / ``stop_trace``
+        run in an executor: ``stop_trace`` serializes the capture for
+        seconds, and the event loop keeps writing SSE meanwhile."""
         import math
+        import tempfile
 
         import jax
 
@@ -992,26 +1005,44 @@ class TpuInferenceServer:
             if not math.isfinite(duration):
                 raise ValueError(f"duration_s must be finite, got {duration}")
             duration = min(max(duration, 0.1), 60.0)
-            out_dir = f"/tmp/tpumlops-profile/{self.model_name}-{int(time.time())}"
+            root = os.path.join(tempfile.gettempdir(), "tpumlops-profile")
+            out_dir = os.path.join(
+                root, f"{self.model_name}-{int(time.time())}"
+            )
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
             if not self._profile_lock.acquire(blocking=False):
                 return web.json_response(
                     {"error": "a profile capture is already running"}, status=409
                 )
+            loop = asyncio.get_running_loop()
+
+            def stop() -> float:
+                t0 = time.perf_counter()
+                with contextlib.suppress(Exception):
+                    # raises "no session" when start_trace itself failed
+                    jax.profiler.stop_trace()
+                return time.perf_counter() - t0
+
             try:
                 try:
-                    jax.profiler.start_trace(out_dir)
+                    await loop.run_in_executor(
+                        None,
+                        lambda: jax.profiler.start_trace(
+                            out_dir, profiler_options=options
+                        ),
+                    )
                     await asyncio.sleep(duration)
                 finally:
-                    with contextlib.suppress(Exception):
-                        # raises "no session" when start_trace itself failed
-                        jax.profiler.stop_trace()
-                evicted = _gc_profile_dirs("/tmp/tpumlops-profile")
+                    stop_s = await loop.run_in_executor(None, stop)
+                evicted = _gc_profile_dirs(root)
             finally:
                 self._profile_lock.release()
             return web.json_response(
                 {
                     "trace_dir": out_dir,
                     "duration_s": duration,
+                    "stop_trace_s": round(stop_s, 3),
                     "evicted": evicted,
                 }
             )
@@ -1105,11 +1136,10 @@ class TpuInferenceServer:
         return await self._debug_json(self.timeseries.snapshot)
 
     async def handle_debug_spans(self, request: web.Request) -> web.Response:
-        """GLOBAL_TRACER span stats (count/mean/max per name) — the
-        control-plane tracer finally readable off the data plane too."""
-        from ..utils.tracing import GLOBAL_TRACER
-
-        return web.json_response({"spans": GLOBAL_TRACER.as_dict()})
+        """The server's tracer (``utils/tracing.py``): per span name the
+        count, total, self time, mean and max — the engine loop's
+        ``engine.*`` phases."""
+        return web.json_response({"spans": self.metrics.tracer.as_dict()})
 
     async def handle_live(self, request: web.Request) -> web.Response:
         # Live through loading AND draining: kubelet must not kill a pod
@@ -1896,6 +1926,8 @@ def make_gen_engine(
         # superstep program must exist on both for lockstep replay.
         unified_step=config.tpu.unified_step,
         on_dispatch=metrics.inc_dispatch if metrics else None,
+        on_prefill_tokens=metrics.inc_prefill_tokens if metrics else None,
+        tracer=metrics.tracer if metrics else None,
         # Packed multi-admission prefill: same batch geometry on leader
         # and followers (this one construction site) — the compiled B_p
         # bucket variants must agree for lockstep replay.

@@ -395,6 +395,8 @@ class GenerationEngine:
         slo_class: str | None = None,  # default class for submissions
         preemption: bool = False,  # mid-decode eviction of lower classes
         on_preempt: Callable[[str], None] | None = None,  # "evict"|"restore"
+        on_prefill_tokens: Callable[[int], None] | None = None,
+        tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
     ):
         import jax
         import jax.numpy as jnp
@@ -690,6 +692,14 @@ class GenerationEngine:
             sw = max(sw, int(self._prefill_chunk_size))
         self._super_width = sw
         self._on_dispatch = on_dispatch
+        self._on_prefill_tokens = on_prefill_tokens
+        # Host-time spans of the scheduler loop (``engine.*``: one root
+        # per pass of ``_loop`` and nine phases under it).  Always on;
+        # the profiler sink puts them on the capture's clock.
+        from ..utils.tracing import Tracer
+
+        self.tracer = tracer if tracer is not None else Tracer(profiler=True)
+        self._span = self.tracer.span
         # Scheduler-loop watchdog (server/watchdog.py): None — the
         # default — keeps the loop byte-for-byte (every beat below is
         # guarded).  Leader-side only, like the recorder: followers
@@ -777,10 +787,12 @@ class GenerationEngine:
                 params, toks, cache, cfg, active=active, dtype=dtype,
                 window=window,
             )
-            keys2, use = split_keys(keys)
-            nxt = sample_logits(logits[:, -1, :], use, temps, tks, tps)
-            # Finished slots keep their last token so their rows stay inert.
-            toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
+            with jax.named_scope("sample"):
+                keys2, use = split_keys(keys)
+                nxt = sample_logits(logits[:, -1, :], use, temps, tks, tps)
+                # Finished slots keep their last token so their rows stay
+                # inert.
+                toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
             ck, cv = cache_repr(cache)
             return toks2, ck, cv, cache.lengths, keys2
 
@@ -800,8 +812,9 @@ class GenerationEngine:
                 params, toks, cache, cfg, active=active, dtype=dtype,
                 window=window,
             )
-            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+                toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
             ck, cv = cache_repr(cache)
             return toks2, ck, cv, cache.lengths
 
@@ -829,9 +842,10 @@ class GenerationEngine:
                 params, toks, cache, cfg, dtype=dtype, window=window,
                 active=active,
             )
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, S]
-            accepted, nxt = speculative_accept(toks, greedy, draft_len)
-            toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, S]
+                accepted, nxt = speculative_accept(toks, greedy, draft_len)
+                toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
             advance = jnp.where(active, accepted + 1, 0).astype(jnp.int32)
             ck, cv = cache_repr(cache)
             return toks2, ck, cv, cache.lengths + advance, greedy, accepted
@@ -854,6 +868,7 @@ class GenerationEngine:
 
             cache = make_cache(k, v, lengths)
 
+            @jax.named_scope("sample")
             def sample(logits, carry):
                 return sample_chain_step(logits, carry, temps, tks, tps)
 
@@ -878,6 +893,7 @@ class GenerationEngine:
             # work), mirroring _decode_greedy.
             cache = make_cache(k, v, lengths)
 
+            @jax.named_scope("sample")
             def sample(logits, carry):
                 return carry, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -1227,11 +1243,13 @@ class GenerationEngine:
 
             cache = make_cache(k, v, lengths)
             if sampling:
+                @jax.named_scope("sample")
                 def sample(lg, carry):
                     return sample_chain_step(lg, carry, temps, tks, tps)
 
                 carry0 = keys
             else:
+                @jax.named_scope("sample")
                 def sample(lg, carry):
                     return carry, jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
@@ -1390,6 +1408,9 @@ class GenerationEngine:
         self.prefix_cached_tokens = 0
         self.prefix_evictions = 0
         self.prefill_chunks_dispatched = 0
+        # Real (unpadded) prompt tokens whose K/V a prefill dispatch
+        # wrote; seeded (cached) tokens are prefix_cached_tokens'.
+        self.prefill_tokens = 0
         # Weight-streaming prefill dispatches (fused prefills, serial
         # chunk forwards, packed batched calls each count 1): the
         # packed_prefill_serving bench reads the packed-vs-serial drop
@@ -2428,16 +2449,18 @@ class GenerationEngine:
         matched = min(matched, (hist // C) * C)
         seed_chunks = list(cached[: matched // C])
         seed_chunks.extend(rec.chunks[matched // C:])
-        self._dispatch_seed_slot(seed_chunks, slot_idx, hist)
+        with self._span("engine.prefill_dispatch"):
+            self._dispatch_seed_slot(seed_chunks, slot_idx, hist)
         if matched:
             self.prefix_hits += 1
             self.prefix_cached_tokens += matched
             if not self._in_warmup and self._on_prefix_hit is not None:
                 self._on_prefix_hit(matched)
-        self._dispatch_restore(
-            slot_idx, hist, int(rec.generated[-1]), rec.key_data,
-            rec.temperature, rec.top_k, rec.top_p,
-        )
+        with self._span("engine.prefill_dispatch"):
+            self._dispatch_restore(
+                slot_idx, hist, int(rec.generated[-1]), rec.key_data,
+                rec.temperature, rec.top_k, rec.top_p,
+            )
         self._slots[slot_idx] = _Slot(
             future=rec.future,
             remaining=rec.remaining,
@@ -2460,15 +2483,18 @@ class GenerationEngine:
         )
         self.preempt_restores += 1
         if not self._in_warmup:
-            self._record_tick(
-                "preempt-restore", t0, time.perf_counter() - t0,
-                active_slots=sum(s is not None for s in self._slots),
-                tokens=hist,
-                cost=self._cost_seed(hist),
-            )
-            self._trace_event(rec.trace, "preempt-restore", slot=slot_idx)
-            if self._on_preempt is not None:
-                self._on_preempt("restore")
+            with self._span("engine.journal"):
+                self._record_tick(
+                    "preempt-restore", t0, time.perf_counter() - t0,
+                    active_slots=sum(s is not None for s in self._slots),
+                    tokens=hist,
+                    cost=self._cost_seed(hist),
+                )
+                self._trace_event(
+                    rec.trace, "preempt-restore", slot=slot_idx
+                )
+                if self._on_preempt is not None:
+                    self._on_preempt("restore")
 
     def _dispatch_restore(
         self, slot, length, pending, key_data, temp, tk, tp
@@ -2554,21 +2580,27 @@ class GenerationEngine:
         # Engine-assigned keys are distinct per request and disjoint from
         # any user-specified jax.random.key(seed) stream (see _slot_key_for).
         slot_key = self._slot_key_for(req)
+        span = self._span
         t0 = time.perf_counter()
         self._beat("admit")
-        first = self._dispatch_admit(
-            ids, slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p
-        )
+        with span("engine.prefill_dispatch"):
+            first = self._dispatch_admit(
+                ids, slot_idx, L, slot_key,
+                req.temperature, req.top_k, req.top_p,
+            )
         if not self._in_warmup:
             self.prefill_forwards += 1
+            self._note_prefill_tokens(L)
             if self._sync_ticks:
-                first = int(first)  # sync: the wall must cover device time
-            self._record_tick(
-                "prefill", t0, time.perf_counter() - t0,
-                active_slots=sum(s is not None for s in self._slots),
-                batch_fill=1, tokens=1,
-                cost=self._cost_prefill(1, bucket),
-            )
+                with span("engine.prefill_sync"):
+                    first = int(first)  # the wall must cover device time
+            with span("engine.journal"):
+                self._record_tick(
+                    "prefill", t0, time.perf_counter() - t0,
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=1, tokens=1,
+                    cost=self._cost_prefill(1, bucket),
+                )
         if req.trace is not None:
             req.trace.slot = slot_idx
             req.trace.prefill_chunks += 1  # fused: the whole prompt at once
@@ -2586,8 +2618,7 @@ class GenerationEngine:
             **self._class_slot_state(req),
         )
         self._slots[slot_idx] = slot
-        self._note_ttft(req)
-        self._record_token(slot_idx, int(first))
+        self._emit_first(slot_idx, req, first)
 
     def _sync_seq_state(self) -> None:
         """Journaling only: wait for the in-flight scratch-cache op so
@@ -2688,6 +2719,22 @@ class GenerationEngine:
             self._trace_event(req.trace, "first_token", slot=req.trace.slot)
         if self._on_ttft is not None:
             self._on_ttft(time.perf_counter() - req.t_submit)
+
+    def _emit_first(self, slot_idx: int, req: _Request, first) -> None:
+        """A fresh admission's first token: TTFT, then the read of the
+        sampled token (which waits for the prefill's work unless a
+        journaling sync already did) and its emission."""
+        self._note_ttft(req)
+        with self._span("engine.prefill_sync"):
+            token = int(first)
+        with self._span("engine.emit"):
+            self._record_token(slot_idx, token)
+
+    def _note_prefill_tokens(self, n: int) -> None:
+        """``n`` real prompt tokens' K/V written by a prefill dispatch."""
+        self.prefill_tokens += n
+        if self._on_prefill_tokens is not None:
+            self._on_prefill_tokens(n)
 
     def _note_admission_wait(self, req: _Request) -> None:
         """``req`` left the submission queue and its admission began."""
@@ -3010,9 +3057,9 @@ class GenerationEngine:
             return
         _, sk, sv, _slen = self._seq_state
         ck, cv = self._read_chunk(sk, sv, jnp.int32(start))
-        self._prefix_cache.insert_chunk(
-            prog.req.prompt, chunk_idx, np.asarray(ck), np.asarray(cv)
-        )
+        with self._span("engine.prefill_sync"):
+            ck, cv = np.asarray(ck), np.asarray(cv)
+        self._prefix_cache.insert_chunk(prog.req.prompt, chunk_idx, ck, cv)
 
     def _dispatch_chunk(self, ids: np.ndarray, fresh: bool) -> None:
         if self._channel is None:
@@ -3183,36 +3230,44 @@ class GenerationEngine:
         bucket = prefill_bucket(L, self.capacity)
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :L] = req.prompt
+        span = self._span
         self._beat("prefill")
         ts = time.perf_counter()
-        self._dispatch_sp_prefill(ids, L)
+        with span("engine.prefill_dispatch"):
+            self._dispatch_sp_prefill(ids, L)
         if not self._in_warmup:
             self.prefill_forwards += 1
-            self._sync_seq_state()
-            self._record_tick(
-                "sp-prefill", ts, time.perf_counter() - ts,
-                active_slots=sum(s is not None for s in self._slots),
-                batch_fill=1,
-                cost=self._cost_sp_prefill(bucket),
-            )
-            self._trace_event(req.trace, "sp_prefill")
+            self._note_prefill_tokens(L)
+            with span("engine.prefill_sync"):
+                self._sync_seq_state()
+            with span("engine.journal"):
+                self._record_tick(
+                    "sp-prefill", ts, time.perf_counter() - ts,
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=1,
+                    cost=self._cost_sp_prefill(bucket),
+                )
+                self._trace_event(req.trace, "sp_prefill")
         self._cache_sp_chunks(req)
         slot_key = self._slot_key_for(req)
         t0 = time.perf_counter()
         # The ring pass already selected the final real row: last_idx 0
         # indexes the [1, V] logits it returned.
-        first = self._dispatch_insert(
-            slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
-            last_idx=0,
-        )
+        with span("engine.prefill_dispatch"):
+            first = self._dispatch_insert(
+                slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
+                last_idx=0,
+            )
         if not self._in_warmup:
             if self._sync_ticks:
-                first = int(first)
-            self._record_tick(
-                "prefill", t0, time.perf_counter() - t0,
-                active_slots=sum(s is not None for s in self._slots),
-                batch_fill=1, tokens=1,
-            )
+                with span("engine.prefill_sync"):
+                    first = int(first)
+            with span("engine.journal"):
+                self._record_tick(
+                    "prefill", t0, time.perf_counter() - t0,
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=1, tokens=1,
+                )
         if req.trace is not None:
             req.trace.slot = slot_idx
         self._slots[slot_idx] = _Slot(
@@ -3228,8 +3283,7 @@ class GenerationEngine:
             **self._spec_slot_state(req),
             **self._class_slot_state(req),
         )
-        self._note_ttft(req)
-        self._record_token(slot_idx, int(first))
+        self._emit_first(slot_idx, req, first)
 
     def _cache_sp_chunks(self, req: _Request) -> None:
         """Radix write-back after a ring prefill: every FULL chunk of the
@@ -3356,34 +3410,16 @@ class GenerationEngine:
             if prog.cached_tokens and not prog.seeded:
                 # Cached-prefix hit: seed the radix K/V straight into the
                 # reserved cache row; those tokens never re-prefill.
-                ts = time.perf_counter()
-                self._dispatch_seed_slot(
-                    prog.cached_kv, prog.slot, prog.cached_tokens
+                self._seed_reserved_row(
+                    prog, sum(s is not None for s in self._slots)
                 )
-                prog.seeded = True
-                prog.cached_kv = []
-                self.prefix_hits += 1
-                self.prefix_cached_tokens += prog.cached_tokens
-                if not self._in_warmup:
-                    if self._on_prefix_hit is not None:
-                        self._on_prefix_hit(prog.cached_tokens)
-                    if self._sync_ticks:
-                        import jax
-
-                        jax.block_until_ready(self._cache_k)
-                    self._record_tick(
-                        "seed", ts, time.perf_counter() - ts,
-                        active_slots=sum(s is not None for s in self._slots),
-                        batch_fill=1,
-                        cost=self._cost_seed(prog.cached_tokens),
-                    )
-                    self._trace_event(prog.req.trace, "seed", slot=prog.slot)
             else:
                 chunk_progs.append(prog)
         if not chunk_progs:
             return
         import jax
 
+        span = self._span
         n = len(chunk_progs)
         bucket = self._pack_bucket(n)
         (
@@ -3406,13 +3442,15 @@ class GenerationEngine:
                     jax.random.key_data(self._slot_key_for(req))
                 )
         t0 = time.perf_counter()
-        firsts = self._dispatch_chunks(
-            ids, slots, offsets, last_pos, final_lens,
-            key_data, r_temps, r_tks, r_tps,
-        )
+        with span("engine.prefill_dispatch"):
+            firsts = self._dispatch_chunks(
+                ids, slots, offsets, last_pos, final_lens,
+                key_data, r_temps, r_tks, r_tps,
+            )
         if not self._in_warmup:
             self.prefill_chunks_dispatched += n
             self.prefill_forwards += 1
+            self._note_prefill_tokens(self._chunk_tokens(chunk_progs))
             if self._on_prefill_batch is not None:
                 self._on_prefill_batch(n)
             finals = sum(
@@ -3425,12 +3463,13 @@ class GenerationEngine:
             attended = (
                 sum(float(offsets[i]) for i in range(n)) / n + C / 2
             )
-            self._record_tick(
-                "packed-prefill", t0, time.perf_counter() - t0,
-                active_slots=sum(s is not None for s in self._slots),
-                batch_fill=n, tokens=finals,
-                cost=self._cost_prefill(bucket, C, attended=attended),
-            )
+            with span("engine.journal"):
+                self._record_tick(
+                    "packed-prefill", t0, time.perf_counter() - t0,
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=n, tokens=finals,
+                    cost=self._cost_prefill(bucket, C, attended=attended),
+                )
         for i, prog in enumerate(chunk_progs):
             if prog.req.trace is not None:
                 prog.req.trace.slot = prog.slot
@@ -3460,8 +3499,46 @@ class GenerationEngine:
                 **self._spec_slot_state(req),
                 **self._class_slot_state(req),
             )
-            self._note_ttft(req)
-            self._record_token(prog.slot, int(firsts[i]))
+            self._emit_first(prog.slot, req, firsts[i])
+
+    def _seed_reserved_row(self, prog: _PrefillProgress, active: int) -> None:
+        """Packed-mode cached-prefix hit: seed the radix K/V straight
+        into the admission's reserved cache row (its own op: a radix
+        copy is not a forward); those tokens never re-prefill."""
+        span = self._span
+        ts = time.perf_counter()
+        with span("engine.prefill_dispatch"):
+            self._dispatch_seed_slot(
+                prog.cached_kv, prog.slot, prog.cached_tokens
+            )
+        prog.seeded = True
+        prog.cached_kv = []
+        self.prefix_hits += 1
+        self.prefix_cached_tokens += prog.cached_tokens
+        if self._in_warmup:
+            return
+        if self._on_prefix_hit is not None:
+            self._on_prefix_hit(prog.cached_tokens)
+        if self._sync_ticks:
+            import jax
+
+            with span("engine.prefill_sync"):
+                jax.block_until_ready(self._cache_k)
+        with span("engine.journal"):
+            self._record_tick(
+                "seed", ts, time.perf_counter() - ts,
+                active_slots=active, batch_fill=1,
+                cost=self._cost_seed(prog.cached_tokens),
+            )
+            self._trace_event(prog.req.trace, "seed", slot=prog.slot)
+
+    def _chunk_tokens(self, chunk_progs: list) -> int:
+        """Real prompt tokens in the next chunk of each admission."""
+        C = self._prefill_chunk_size
+        return sum(
+            min(C, int(p.req.prompt.size) - p.cached_tokens - p.next_idx * C)
+            for p in chunk_progs
+        )
 
     def _maybe_cache_chunk_slot(self, prog: _PrefillProgress) -> None:
         """Packed-mode prefix write-back: like :meth:`_maybe_cache_chunk`
@@ -3554,7 +3631,8 @@ class GenerationEngine:
             jnp.asarray(r_tks),
             jnp.asarray(r_tps),
         )
-        return np.asarray(firsts)
+        with self._span("engine.prefill_sync"):
+            return np.asarray(firsts)
 
     def replay_chunks(
         self, ids, slots, offsets, last_pos, final_lens,
@@ -3622,13 +3700,15 @@ class GenerationEngine:
         (the batch-1 scratch cache serializes admissions); packed mode
         advances through :meth:`_packed_tick`."""
         assert self._pending
+        span = self._span
         self._beat("prefill")
         prog = self._pending[0]
         if prog.cached_tokens and not prog.seeded:
             # Cached-prefix hit: one seed op copies the radix-cached K/V
             # into a fresh sequence cache — those tokens never re-prefill.
             ts = time.perf_counter()
-            self._dispatch_seed(prog.cached_kv, prog.cached_tokens)
+            with span("engine.prefill_dispatch"):
+                self._dispatch_seed(prog.cached_kv, prog.cached_tokens)
             prog.seeded = True
             prog.cached_kv = []  # host copies handed off; free the refs
             self.prefix_hits += 1
@@ -3636,30 +3716,40 @@ class GenerationEngine:
             if not self._in_warmup:
                 if self._on_prefix_hit is not None:
                     self._on_prefix_hit(prog.cached_tokens)
-                self._sync_seq_state()
-                self._record_tick(
-                    "seed", ts, time.perf_counter() - ts,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=1,
-                    cost=self._cost_seed(prog.cached_tokens),
-                )
-                self._trace_event(prog.req.trace, "seed")
+                with span("engine.prefill_sync"):
+                    self._sync_seq_state()
+                with span("engine.journal"):
+                    self._record_tick(
+                        "seed", ts, time.perf_counter() - ts,
+                        active_slots=sum(
+                            s is not None for s in self._slots
+                        ),
+                        batch_fill=1,
+                        cost=self._cost_seed(prog.cached_tokens),
+                    )
+                    self._trace_event(prog.req.trace, "seed")
             return  # suffix chunks start next tick (decode cadence kept)
         ids = prog.chunks[prog.next_idx]
-        offset = prog.cached_tokens + prog.next_idx * self._prefill_chunk_size
+        C = self._prefill_chunk_size
+        offset = prog.cached_tokens + prog.next_idx * C
         ts = time.perf_counter()
-        self._dispatch_chunk(ids, fresh=prog.next_idx == 0 and not prog.seeded)
+        with span("engine.prefill_dispatch"):
+            self._dispatch_chunk(
+                ids, fresh=prog.next_idx == 0 and not prog.seeded
+            )
         if not self._in_warmup:
             self.prefill_chunks_dispatched += 1
             self.prefill_forwards += 1
-            self._sync_seq_state()
-            C = self._prefill_chunk_size
-            self._record_tick(
-                "prefill", ts, time.perf_counter() - ts,
-                active_slots=sum(s is not None for s in self._slots),
-                batch_fill=1,
-                cost=self._cost_prefill(1, C, attended=offset + C / 2),
-            )
+            self._note_prefill_tokens(self._chunk_tokens([prog]))
+            with span("engine.prefill_sync"):
+                self._sync_seq_state()
+            with span("engine.journal"):
+                self._record_tick(
+                    "prefill", ts, time.perf_counter() - ts,
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=1,
+                    cost=self._cost_prefill(1, C, attended=offset + C / 2),
+                )
         if prog.req.trace is not None:
             prog.req.trace.prefill_chunks += 1
             self._trace_event(prog.req.trace, "prefill_chunk")
@@ -3672,21 +3762,24 @@ class GenerationEngine:
         slot_idx = self._free_slot()
         assert slot_idx is not None  # reserved by the admission policy
         L = int(req.prompt.size)
-        C = self._prefill_chunk_size
         slot_key = self._slot_key_for(req)
         t0 = time.perf_counter()
-        first = self._dispatch_insert(
-            slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
-            last_idx=(L - 1) - prog.cached_tokens - C * (len(prog.chunks) - 1),
-        )
+        with span("engine.prefill_dispatch"):
+            first = self._dispatch_insert(
+                slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
+                last_idx=(L - 1) - prog.cached_tokens
+                - C * (len(prog.chunks) - 1),
+            )
         if not self._in_warmup:
             if self._sync_ticks:
-                first = int(first)  # sync: the wall must cover device time
-            self._record_tick(
-                "prefill", t0, time.perf_counter() - t0,
-                active_slots=sum(s is not None for s in self._slots),
-                batch_fill=1, tokens=1,
-            )
+                with span("engine.prefill_sync"):
+                    first = int(first)  # the wall must cover device time
+            with span("engine.journal"):
+                self._record_tick(
+                    "prefill", t0, time.perf_counter() - t0,
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=1, tokens=1,
+                )
         if req.trace is not None:
             req.trace.slot = slot_idx
         self._slots[slot_idx] = _Slot(
@@ -3702,8 +3795,7 @@ class GenerationEngine:
             **self._spec_slot_state(req),
             **self._class_slot_state(req),
         )
-        self._note_ttft(req)
-        self._record_token(slot_idx, int(first))
+        self._emit_first(slot_idx, req, first)
 
     def replay_reset(self) -> None:
         """Follower side of :meth:`_fail_all_and_recover`'s device reset."""
@@ -3794,32 +3886,46 @@ class GenerationEngine:
         if self._unified:
             self._super_tick()
             return
-        active_np = np.array([s is not None for s in self._slots])
+        span = self._span
+        with span("engine.decode_assemble"):
+            active_np = np.array([s is not None for s in self._slots])
+            drafts = None
+            if active_np.any():
+                # Attention window: smallest bucket covering every active
+                # row's next write position (prompt + tokens emitted so
+                # far).
+                needed = max(
+                    s.prompt_len + len(s.generated)
+                    for s in self._slots
+                    if s is not None
+                )
+                window = decode_window_bucket(needed, self.capacity)
+                sampling = any(
+                    s is not None and s.sampling for s in self._slots
+                )
+                if (
+                    self._spec is not None
+                    and not sampling
+                    and not self._in_warmup
+                ):
+                    drafts = self._collect_drafts()
         if not active_np.any():
             # Still report occupancy: without this the gauges freeze at
             # their last busy values and an idle server reads as loaded.
             # (observe_decode_step skips its histograms at 0 active.)
             if self._on_step is not None and not self._in_warmup:
-                self._on_step(0, 0.0, self._queue.qsize(), len(self._pending))
+                with span("engine.journal"):
+                    self._on_step(
+                        0, 0.0, self._queue.qsize(), len(self._pending)
+                    )
             return
-        # Attention window: smallest bucket covering every active row's
-        # next write position (prompt + tokens emitted so far).
-        needed = max(
-            s.prompt_len + len(s.generated)
-            for s in self._slots
-            if s is not None
-        )
-        window = decode_window_bucket(needed, self.capacity)
-        sampling = any(s is not None and s.sampling for s in self._slots)
-        if self._spec is not None and not sampling and not self._in_warmup:
-            drafts = self._collect_drafts()
-            if any(drafts):
-                # Speculative slots fall back to verify ticks (a draft in
-                # hand amortizes the weight stream by acceptance, which a
-                # fixed-K scan cannot beat on draftable text); ticks with
-                # no drafts anywhere fuse below like plain traffic.
-                self._verify_tick(active_np, window, drafts)
-                return
+        if drafts is not None and any(drafts):
+            # Speculative slots fall back to verify ticks (a draft in
+            # hand amortizes the weight stream by acceptance, which a
+            # fixed-K scan cannot beat on draftable text); ticks with
+            # no drafts anywhere fuse below like plain traffic.
+            self._verify_tick(active_np, window, drafts)
+            return
         if (
             self._fused
             and not self._in_warmup
@@ -3834,17 +3940,21 @@ class GenerationEngine:
             return
         t0 = time.perf_counter()
         self._beat("decode")
-        self._dispatch_step(active_np, window, sampling)
-        toks = np.asarray(self._tokens)[:, 0]
-        self._note_tick(
-            active_np, t0, tokens=int(active_np.sum()),
-            cost=self._cost_decode(window),
-        )
-        for i, was_active in enumerate(active_np):
-            if was_active and self._slots[i] is not None:
-                self._record_token(i, int(toks[i]))
-                if not self._in_warmup:
-                    self.decode_tokens += 1
+        with span("engine.decode_dispatch"):
+            self._dispatch_step(active_np, window, sampling)
+        with span("engine.decode_readback"):
+            toks = np.asarray(self._tokens)[:, 0]
+        with span("engine.journal"):
+            self._note_tick(
+                active_np, t0, tokens=int(active_np.sum()),
+                cost=self._cost_decode(window),
+            )
+        with span("engine.emit"):
+            for i, was_active in enumerate(active_np):
+                if was_active and self._slots[i] is not None:
+                    self._record_token(i, int(toks[i]))
+                    if not self._in_warmup:
+                        self.decode_tokens += 1
 
     def _note_tick(
         self, active_np, t0: float, kind: str = "decode",
@@ -3898,19 +4008,21 @@ class GenerationEngine:
         """
         K = self._decode_steps
         B = self.max_slots
-        # Burst-entry device inputs from exact host slot truth.
-        remaining = np.zeros((B,), np.int32)
-        eos_ids = np.full((B,), -1, np.int32)  # -1: no EOS (ids are >= 0)
-        hi = np.zeros((B,), np.int64)  # per-row next-write position bound
-        rem_hi = np.zeros((B,), np.int64)  # per-row emit-budget bound
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            remaining[i] = slot.remaining
-            if slot.eos_id is not None:
-                eos_ids[i] = slot.eos_id
-            hi[i] = slot.prompt_len + len(slot.generated)
-            rem_hi[i] = slot.remaining
+        span = self._span
+        with span("engine.decode_assemble"):
+            # Burst-entry device inputs from exact host slot truth.
+            remaining = np.zeros((B,), np.int32)
+            eos_ids = np.full((B,), -1, np.int32)  # -1: no EOS (ids are >= 0)
+            hi = np.zeros((B,), np.int64)  # per-row next-write position bound
+            rem_hi = np.zeros((B,), np.int64)  # per-row emit-budget bound
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                remaining[i] = slot.remaining
+                if slot.eos_id is not None:
+                    eos_ids[i] = slot.eos_id
+                hi[i] = slot.prompt_len + len(slot.generated)
+                rem_hi[i] = slot.remaining
         pending = None  # (tok_block_dev, valid_dev, t0, window)
         start = True
         while True:
@@ -3918,53 +4030,57 @@ class GenerationEngine:
             # it mid-flight, and the LAST step attends positions up to
             # needed + K - 1 (satellite: a row crossing a bucket edge
             # inside K steps must already be covered).
-            needed_hi = int(
-                max(
-                    hi[i]
-                    for i in range(B)
-                    if self._slots[i] is not None and rem_hi[i] > 0
+            with span("engine.decode_assemble"):
+                needed_hi = int(
+                    max(
+                        hi[i]
+                        for i in range(B)
+                        if self._slots[i] is not None and rem_hi[i] > 0
+                    )
                 )
-            )
-            window = decode_window_bucket(
-                min(needed_hi + K - 1, self.capacity), self.capacity
-            )
+                window = decode_window_bucket(
+                    min(needed_hi + K - 1, self.capacity), self.capacity
+                )
             t0 = time.perf_counter()
             self._beat("multistep")
-            tok_block, valid = self._dispatch_multistep(
-                active_np if start else None,
-                remaining if start else None,
-                eos_ids if start else None,
-                window, sampling,
-            )
-            for i in range(B):
-                emit = min(int(rem_hi[i]), K)
-                hi[i] += emit
-                rem_hi[i] -= emit
+            with span("engine.decode_dispatch"):
+                tok_block, valid = self._dispatch_multistep(
+                    active_np if start else None,
+                    remaining if start else None,
+                    eos_ids if start else None,
+                    window, sampling,
+                )
+            with span("engine.decode_assemble"):
+                for i in range(B):
+                    emit = min(int(rem_hi[i]), K)
+                    hi[i] += emit
+                    rem_hi[i] -= emit
             start = False
             if pending is not None:
                 # Lag-1: tick N+1 is in flight; block on tick N now.
                 self._harvest_fused(*pending)
             pending = (tok_block, valid, t0, window)
-            may_be_active = any(
-                self._slots[i] is not None and rem_hi[i] > 0
-                for i in range(B)
-            )
-            if (
-                not may_be_active
-                or self._stop.is_set()
-                or self._pending
-                or self._queued_work()
-            ):
-                break
-            if (
-                self._spec is not None
-                and not sampling
-                and any(self._collect_drafts())
-            ):
+            with span("engine.decode_assemble"):
+                may_be_active = any(
+                    self._slots[i] is not None and rem_hi[i] > 0
+                    for i in range(B)
+                )
                 # Speculative fallback is PER TICK: the harvest above
                 # refreshed slot histories, and a draft in hand beats a
                 # fixed-K scan on draftable text — end the burst so the
                 # next _step runs the verify path.
+                done = (
+                    not may_be_active
+                    or self._stop.is_set()
+                    or bool(self._pending)
+                    or self._queued_work()
+                    or (
+                        self._spec is not None
+                        and not sampling
+                        and any(self._collect_drafts())
+                    )
+                )
+            if done:
                 break
         if pending is not None:
             self._harvest_fused(*pending)
@@ -3980,36 +4096,40 @@ class GenerationEngine:
         valid tokens across the tick wall (clamped monotone against the
         row's previous token): K tokens on one instant would zero every
         ITL observation and stack the Perfetto instants."""
-        toks = np.asarray(tok_block_dev)  # the deferred device sync
-        valid = np.asarray(valid_dev)
+        with self._span("engine.decode_readback"):
+            toks = np.asarray(tok_block_dev)  # the deferred device sync
+            valid = np.asarray(valid_dev)
         end = time.perf_counter()
         wall = end - t0
         K = self._decode_steps
         active_slots = int((valid > 0).sum())
         total = int(valid.sum())
         self.decode_forwards += 1
-        self._record_tick(
-            "multistep", t0, wall,
-            active_slots=active_slots, tokens=total, steps=K,
-            cost=self._cost_decode(window, steps=K),
-        )
-        if self._on_step is not None:
-            self._on_step(
-                active_slots, wall, self._queue.qsize(), len(self._pending)
+        with self._span("engine.journal"):
+            self._record_tick(
+                "multistep", t0, wall,
+                active_slots=active_slots, tokens=total, steps=K,
+                cost=self._cost_decode(window, steps=K),
             )
-        for i in range(self.max_slots):
-            n = int(valid[i])
-            if n <= 0 or self._slots[i] is None:
-                continue
-            base = max(t0, self._slots[i].t_last_token)
-            span = max(end - base, 0.0)
-            for j in range(n):
-                self._record_token(
-                    i, int(toks[i, j]), t=base + span * (j + 1) / n
+            if self._on_step is not None:
+                self._on_step(
+                    active_slots, wall,
+                    self._queue.qsize(), len(self._pending),
                 )
-                self.decode_tokens += 1
-                if self._slots[i] is None:
-                    break  # finished (eos/length) or cancelled mid-block
+        with self._span("engine.emit"):
+            for i in range(self.max_slots):
+                n = int(valid[i])
+                if n <= 0 or self._slots[i] is None:
+                    continue
+                base = max(t0, self._slots[i].t_last_token)
+                spread = max(end - base, 0.0)
+                for j in range(n):
+                    self._record_token(
+                        i, int(toks[i, j]), t=base + spread * (j + 1) / n
+                    )
+                    self.decode_tokens += 1
+                    if self._slots[i] is None:
+                        break  # finished (eos/length) or cancelled
 
     def _dispatch_multistep(self, active_np, remaining, eos_ids, window,
                             sampling):
@@ -4150,102 +4270,89 @@ class GenerationEngine:
                 )
             for prog in self._pending[:max_chunks]:
                 if prog.cached_tokens and not prog.seeded:
-                    ts = time.perf_counter()
-                    self._dispatch_seed_slot(
-                        prog.cached_kv, prog.slot, prog.cached_tokens
-                    )
-                    prog.seeded = True
-                    prog.cached_kv = []
-                    self.prefix_hits += 1
-                    self.prefix_cached_tokens += prog.cached_tokens
-                    if not self._in_warmup:
-                        if self._on_prefix_hit is not None:
-                            self._on_prefix_hit(prog.cached_tokens)
-                        if self._sync_ticks:
-                            jax.block_until_ready(self._cache_k)
-                        self._record_tick(
-                            "seed", ts, time.perf_counter() - ts,
-                            active_slots=int(occupied.sum()),
-                            batch_fill=1,
-                            cost=self._cost_seed(prog.cached_tokens),
-                        )
-                        self._trace_event(
-                            prog.req.trace, "seed", slot=prog.slot
-                        )
+                    self._seed_reserved_row(prog, int(occupied.sum()))
                 else:
                     chunk_progs.append(prog)
+        span = self._span
         if not occupied.any() and not chunk_progs:
             # Still report occupancy: without this the gauges freeze at
             # their last busy values and an idle server reads as loaded.
             if self._on_step is not None and not self._in_warmup:
-                self._on_step(0, 0.0, self._queue.qsize(), len(self._pending))
+                with span("engine.journal"):
+                    self._on_step(
+                        0, 0.0, self._queue.qsize(), len(self._pending)
+                    )
             return
         self._beat("superstep")
-        K = self._decode_steps
-        sampling = any(s is not None and s.sampling for s in self._slots)
-        drafts: list[list[int]] = [[] for _ in range(B)]
-        if (
-            self._spec is not None
-            and not sampling
-            and not self._in_warmup
-            and occupied.any()
-        ):
-            drafts = self._collect_drafts()
-        (
-            ids, roles, offsets, counts, draft_len, active, remaining,
-            eos_ids, last_pos, final_lens, key_data, r_temps, r_tks, r_tps,
-        ) = self._parked_superstep()
-        decode_hi = other_hi = 0
-        n_dec = n_ver = 0
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            pos = slot.prompt_len + len(slot.generated)
-            ids[i, 0] = slot.generated[-1]  # pending (emitted, unfed) token
-            active[i] = True
-            d = drafts[i]
-            if d:
-                roles[i] = llama.ROLE_VERIFY
-                ids[i, 1 : 1 + len(d)] = d
-                draft_len[i] = len(d)
-                counts[i] = len(d) + 1
-                other_hi = max(other_hi, pos)
-                n_ver += 1
-            else:
-                roles[i] = llama.ROLE_DECODE
-                counts[i] = 1
-                remaining[i] = slot.remaining
-                if slot.eos_id is not None:
-                    eos_ids[i] = slot.eos_id
-                decode_hi = max(decode_hi, pos)
-                n_dec += 1
-        C = self._prefill_chunk_size
-        for prog in chunk_progs:
-            i, req = prog.slot, prog.req
-            roles[i] = llama.ROLE_PREFILL
-            off = prog.cached_tokens + prog.next_idx * C
-            offsets[i] = off
-            counts[i] = C
-            ids[i, :C] = prog.chunks[prog.next_idx][0]
-            other_hi = max(other_hi, off)
-            if prog.next_idx == len(prog.chunks) - 1:
-                L = int(req.prompt.size)
-                last_pos[i] = (L - 1) - off
-                final_lens[i] = L
-                r_temps[i] = req.temperature
-                r_tks[i] = req.top_k
-                r_tps[i] = req.top_p
-                key_data[i] = np.asarray(
-                    jax.random.key_data(self._slot_key_for(req))
-                )
-        window = superstep_window(decode_hi, other_hi, K, self.capacity)
+        with span("engine.decode_assemble"):
+            K = self._decode_steps
+            sampling = any(s is not None and s.sampling for s in self._slots)
+            drafts: list[list[int]] = [[] for _ in range(B)]
+            if (
+                self._spec is not None
+                and not sampling
+                and not self._in_warmup
+                and occupied.any()
+            ):
+                drafts = self._collect_drafts()
+            (
+                ids, roles, offsets, counts, draft_len, active, remaining,
+                eos_ids, last_pos, final_lens, key_data, r_temps, r_tks, r_tps,
+            ) = self._parked_superstep()
+            decode_hi = other_hi = 0
+            n_dec = n_ver = 0
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                pos = slot.prompt_len + len(slot.generated)
+                ids[i, 0] = slot.generated[-1]  # pending (emitted, unfed) token
+                active[i] = True
+                d = drafts[i]
+                if d:
+                    roles[i] = llama.ROLE_VERIFY
+                    ids[i, 1 : 1 + len(d)] = d
+                    draft_len[i] = len(d)
+                    counts[i] = len(d) + 1
+                    other_hi = max(other_hi, pos)
+                    n_ver += 1
+                else:
+                    roles[i] = llama.ROLE_DECODE
+                    counts[i] = 1
+                    remaining[i] = slot.remaining
+                    if slot.eos_id is not None:
+                        eos_ids[i] = slot.eos_id
+                    decode_hi = max(decode_hi, pos)
+                    n_dec += 1
+            C = self._prefill_chunk_size
+            for prog in chunk_progs:
+                i, req = prog.slot, prog.req
+                roles[i] = llama.ROLE_PREFILL
+                off = prog.cached_tokens + prog.next_idx * C
+                offsets[i] = off
+                counts[i] = C
+                ids[i, :C] = prog.chunks[prog.next_idx][0]
+                other_hi = max(other_hi, off)
+                if prog.next_idx == len(prog.chunks) - 1:
+                    L = int(req.prompt.size)
+                    last_pos[i] = (L - 1) - off
+                    final_lens[i] = L
+                    r_temps[i] = req.temperature
+                    r_tks[i] = req.top_k
+                    r_tps[i] = req.top_p
+                    key_data[i] = np.asarray(
+                        jax.random.key_data(self._slot_key_for(req))
+                    )
+            window = superstep_window(decode_hi, other_hi, K, self.capacity)
         n_pre = len(chunk_progs)
         t0 = time.perf_counter()
-        tok_block, valid, greedy, accepted, firsts = self._dispatch_superstep(
-            ids, roles, offsets, counts, draft_len, active, remaining,
-            eos_ids, last_pos, final_lens, key_data, r_temps, r_tks, r_tps,
-            window, sampling,
-        )
+        with span("engine.decode_dispatch"):  # the read-back nests in it
+            tok_block, valid, greedy, accepted, firsts = (
+                self._dispatch_superstep(
+                    ids, roles, offsets, counts, draft_len, active,
+                    remaining, eos_ids, last_pos, final_lens, key_data,
+                    r_temps, r_tks, r_tps, window, sampling,
+                )
+            )
         end = time.perf_counter()
         finals = sum(
             1 for prog in chunk_progs
@@ -4257,99 +4364,102 @@ class GenerationEngine:
             if n_pre:
                 self.prefill_chunks_dispatched += n_pre
                 self.prefill_forwards += 1
+                self._note_prefill_tokens(self._chunk_tokens(chunk_progs))
                 if self._on_prefill_batch is not None:
                     self._on_prefill_batch(n_pre)
             if n_ver:
                 self.spec_verify_ticks += 1
             wall = end - t0
-            self._record_tick(
-                "superstep", t0, wall,
-                active_slots=int(occupied.sum()),
-                batch_fill=n_pre,
-                tokens=int(valid.sum()) + n_ver + acc_total + finals,
-                spec_accepted=acc_total,
-                steps=K,
-                cost=self._cost_superstep(window, self._super_width, K),
-                roles={"prefill": n_pre, "decode": n_dec, "verify": n_ver},
-            )
-            if self._on_step is not None:
-                self._on_step(
-                    int(occupied.sum()), wall,
-                    self._queue.qsize(), len(self._pending),
+            with span("engine.journal"):
+                self._record_tick(
+                    "superstep", t0, wall,
+                    active_slots=int(occupied.sum()),
+                    batch_fill=n_pre,
+                    tokens=int(valid.sum()) + n_ver + acc_total + finals,
+                    spec_accepted=acc_total,
+                    steps=K,
+                    cost=self._cost_superstep(window, self._super_width, K),
+                    roles={"prefill": n_pre, "decode": n_dec, "verify": n_ver},
                 )
+                if self._on_step is not None:
+                    self._on_step(
+                        int(occupied.sum()), wall,
+                        self._queue.qsize(), len(self._pending),
+                    )
         # Prefill harvest: the _packed_tick bookkeeping, minus the
         # dispatch it no longer owns.
-        for i, prog in enumerate(chunk_progs):
-            if prog.req.trace is not None:
-                prog.req.trace.slot = prog.slot
-                prog.req.trace.prefill_chunks += 1
-                self._trace_event(
-                    prog.req.trace, "prefill_chunk", slot=prog.slot
-                )
-            self._maybe_cache_chunk_slot(prog)
-            prog.next_idx += 1
-            if prog.next_idx < len(prog.chunks):
-                continue
-            self._pending.remove(prog)
-            self._reserved.discard(prog.slot)
-            req = prog.req
-            self._slots[prog.slot] = _Slot(
-                future=req.future,
-                remaining=req.max_new_tokens,
-                eos_id=req.eos_id,
-                sampling=req.temperature > 0,
-                on_token=req.on_token,
-                prompt_len=int(req.prompt.size),
-                t_start=t0,
-                request_id=req.request_id,
-                trace=req.trace,
-                **self._spec_slot_state(req),
-                **self._class_slot_state(req),
-            )
-            self._note_ttft(req)
-            self._record_token(prog.slot, int(firsts[prog.slot]))
-        # Decode/verify harvest from the same readback.
-        for i in range(B):
-            if not occupied[i] or self._slots[i] is None:
-                continue
-            slot = self._slots[i]
-            if roles[i] == llama.ROLE_VERIFY:
-                n_prop, n_acc = int(draft_len[i]), int(accepted[i])
-                if slot.draft is not None:
-                    slot.draft.observe(n_prop, n_acc)
-                if n_prop and not self._in_warmup:
-                    self.spec_proposed_tokens += n_prop
-                    self.spec_accepted_tokens += n_acc
-                    if slot.trace is not None:
-                        slot.trace.spec_proposed += n_prop
-                        slot.trace.spec_accepted += n_acc
-                    if self._on_spec is not None:
-                        self._on_spec(n_prop, n_acc)
-                # Emit the accepted draft prefix plus the bonus token;
-                # stop early if the slot finishes (eos/budget/cancel).
-                for j in range(n_acc + 1):
-                    self._record_token(i, int(greedy[i, j]))
-                    if not self._in_warmup:
-                        self.decode_tokens += 1
-                    if self._slots[i] is None:
-                        break
-            else:
-                n = int(valid[i])
-                if n <= 0:
-                    continue
-                # Per-token timestamps spaced across the tick wall (the
-                # _harvest_fused discipline): K tokens on one instant
-                # would zero every ITL observation.
-                base = max(t0, slot.t_last_token)
-                span = max(end - base, 0.0)
-                for j in range(n):
-                    self._record_token(
-                        i, int(tok_block[i, j]), t=base + span * (j + 1) / n
+        with span("engine.admit"):
+            for i, prog in enumerate(chunk_progs):
+                if prog.req.trace is not None:
+                    prog.req.trace.slot = prog.slot
+                    prog.req.trace.prefill_chunks += 1
+                    self._trace_event(
+                        prog.req.trace, "prefill_chunk", slot=prog.slot
                     )
-                    if not self._in_warmup:
-                        self.decode_tokens += 1
-                    if self._slots[i] is None:
-                        break
+                self._maybe_cache_chunk_slot(prog)
+                prog.next_idx += 1
+                if prog.next_idx < len(prog.chunks):
+                    continue
+                self._pending.remove(prog)
+                self._reserved.discard(prog.slot)
+                req = prog.req
+                self._slots[prog.slot] = _Slot(
+                    future=req.future,
+                    remaining=req.max_new_tokens,
+                    eos_id=req.eos_id,
+                    sampling=req.temperature > 0,
+                    on_token=req.on_token,
+                    prompt_len=int(req.prompt.size),
+                    t_start=t0,
+                    request_id=req.request_id,
+                    trace=req.trace,
+                    **self._spec_slot_state(req),
+                    **self._class_slot_state(req),
+                )
+                self._emit_first(prog.slot, req, firsts[prog.slot])
+        # Decode/verify harvest from the same readback.
+        with span("engine.emit"):
+            for i in range(B):
+                if not occupied[i] or self._slots[i] is None:
+                    continue
+                slot = self._slots[i]
+                if roles[i] == llama.ROLE_VERIFY:
+                    n_prop, n_acc = int(draft_len[i]), int(accepted[i])
+                    if slot.draft is not None:
+                        slot.draft.observe(n_prop, n_acc)
+                    if n_prop and not self._in_warmup:
+                        self.spec_proposed_tokens += n_prop
+                        self.spec_accepted_tokens += n_acc
+                        if slot.trace is not None:
+                            slot.trace.spec_proposed += n_prop
+                            slot.trace.spec_accepted += n_acc
+                        if self._on_spec is not None:
+                            self._on_spec(n_prop, n_acc)
+                    # Emit the accepted draft prefix plus the bonus token;
+                    # stop early if the slot finishes (eos/budget/cancel).
+                    for j in range(n_acc + 1):
+                        self._record_token(i, int(greedy[i, j]))
+                        if not self._in_warmup:
+                            self.decode_tokens += 1
+                        if self._slots[i] is None:
+                            break
+                else:
+                    n = int(valid[i])
+                    if n <= 0:
+                        continue
+                    # Per-token timestamps spaced across the tick wall (the
+                    # _harvest_fused discipline): K tokens on one instant
+                    # would zero every ITL observation.
+                    base = max(t0, slot.t_last_token)
+                    spread = max(end - base, 0.0)
+                    for j in range(n):
+                        self._record_token(
+                            i, int(tok_block[i, j]), t=base + spread * (j + 1) / n
+                        )
+                        if not self._in_warmup:
+                            self.decode_tokens += 1
+                        if self._slots[i] is None:
+                            break
 
     def _dispatch_superstep(
         self, ids, roles, offsets, counts, draft_len, active, remaining,
@@ -4446,10 +4556,11 @@ class GenerationEngine:
             self._decode_steps,
             bool(sampling),
         )
-        return (
-            np.asarray(tok_block), np.asarray(valid), np.asarray(greedy),
-            np.asarray(accepted), np.asarray(firsts),
-        )
+        with self._span("engine.decode_readback"):
+            return (
+                np.asarray(tok_block), np.asarray(valid), np.asarray(greedy),
+                np.asarray(accepted), np.asarray(firsts),
+            )
 
     def replay_superstep(
         self, ids, roles, offsets, counts, draft_len, active, remaining,
@@ -4503,55 +4614,61 @@ class GenerationEngine:
         stream; per-slot greedy acceptance decides how many emit."""
         from .speculative import pad_to_chain
 
-        s_draft = pad_to_chain(
-            max(len(d) for d in drafts), self._spec_chain
-        )
-        toks = np.zeros((self.max_slots, s_draft + 1), np.int32)
-        draft_len = np.zeros((self.max_slots,), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            toks[i, 0] = slot.generated[-1]  # pending (emitted, unfed) token
-            d = drafts[i]
-            toks[i, 1 : 1 + len(d)] = d
-            draft_len[i] = len(d)
+        span = self._span
+        with span("engine.decode_assemble"):
+            s_draft = pad_to_chain(
+                max(len(d) for d in drafts), self._spec_chain
+            )
+            toks = np.zeros((self.max_slots, s_draft + 1), np.int32)
+            draft_len = np.zeros((self.max_slots,), np.int32)
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                toks[i, 0] = slot.generated[-1]  # pending (unfed) token
+                d = drafts[i]
+                toks[i, 1 : 1 + len(d)] = d
+                draft_len[i] = len(d)
         t0 = time.perf_counter()
         self._beat("verify")
-        greedy, accepted = self._dispatch_verify(
-            toks, active_np, draft_len, window
-        )
-        acc_total = int(np.asarray(accepted)[active_np].sum())
-        self._note_tick(
-            active_np, t0, kind="verify",
-            tokens=int(active_np.sum()) + acc_total,
-            spec_accepted=acc_total,
-            cost=self._cost_decode(window, s_draft + 1),
-        )
+        with span("engine.decode_dispatch"):  # the read-back nests in it
+            greedy, accepted = self._dispatch_verify(
+                toks, active_np, draft_len, window
+            )
+        with span("engine.journal"):
+            acc_total = int(np.asarray(accepted)[active_np].sum())
+            self._note_tick(
+                active_np, t0, kind="verify",
+                tokens=int(active_np.sum()) + acc_total,
+                spec_accepted=acc_total,
+                cost=self._cost_decode(window, s_draft + 1),
+            )
         if not self._in_warmup:
             self.spec_verify_ticks += 1
-        for i, was_active in enumerate(active_np):
-            if not was_active or self._slots[i] is None:
-                continue
-            slot = self._slots[i]
-            n_prop, n_acc = int(draft_len[i]), int(accepted[i])
-            if slot.draft is not None:
-                slot.draft.observe(n_prop, n_acc)
-            if n_prop and not self._in_warmup:
-                self.spec_proposed_tokens += n_prop
-                self.spec_accepted_tokens += n_acc
-                if slot.trace is not None:
-                    slot.trace.spec_proposed += n_prop
-                    slot.trace.spec_accepted += n_acc
-                if self._on_spec is not None:
-                    self._on_spec(n_prop, n_acc)
-            # Emit the accepted draft prefix plus the bonus token; stop
-            # early if the slot finishes (eos / budget) or cancels.
-            for j in range(n_acc + 1):
-                self._record_token(i, int(greedy[i, j]))
-                if not self._in_warmup:
-                    self.decode_tokens += 1
-                if self._slots[i] is None:
-                    break
+        with span("engine.emit"):
+            for i, was_active in enumerate(active_np):
+                if not was_active or self._slots[i] is None:
+                    continue
+                slot = self._slots[i]
+                n_prop, n_acc = int(draft_len[i]), int(accepted[i])
+                if slot.draft is not None:
+                    slot.draft.observe(n_prop, n_acc)
+                if n_prop and not self._in_warmup:
+                    self.spec_proposed_tokens += n_prop
+                    self.spec_accepted_tokens += n_acc
+                    if slot.trace is not None:
+                        slot.trace.spec_proposed += n_prop
+                        slot.trace.spec_accepted += n_acc
+                    if self._on_spec is not None:
+                        self._on_spec(n_prop, n_acc)
+                # Emit the accepted draft prefix plus the bonus token;
+                # stop early if the slot finishes (eos / budget) or
+                # cancels.
+                for j in range(n_acc + 1):
+                    self._record_token(i, int(greedy[i, j]))
+                    if not self._in_warmup:
+                        self.decode_tokens += 1
+                    if self._slots[i] is None:
+                        break
 
     def _dispatch_verify(self, toks, active_np, draft_len, window):
         if self._channel is None:
@@ -4592,7 +4709,8 @@ class GenerationEngine:
             jnp.asarray(draft_len),
             int(window),
         )
-        return np.asarray(greedy), np.asarray(accepted)
+        with self._span("engine.decode_readback"):
+            return np.asarray(greedy), np.asarray(accepted)
 
     def replay_verify(self, toks, active, draft_len, window) -> None:
         """Follower side of a verify tick (multihost lockstep)."""
@@ -4655,18 +4773,33 @@ class GenerationEngine:
             )
 
     def _loop(self) -> None:
+        span = self._span
         while not self._stop.is_set():
-            # Heartbeat: the idle stamp is overwritten by the dispatch
-            # sites below just before they block on a device call, so a
-            # wedged tick is attributed to its kind, not to "idle".
-            self._beat("idle")
-            if not self._admit_phase():
-                return  # shutdown sentinel
-            try:
-                self._step()
-            except Exception:
-                _log.exception("decode step failed")
-                self._fail_all_and_recover()
+            # One root span a pass; the phases under it (``engine.admit``
+            # here, the rest at their sites) cover it, so its self time
+            # is the uninstrumented remainder.
+            with span("engine.iteration"):
+                # Heartbeat: the idle stamp is overwritten by the dispatch
+                # sites below just before they block on a device call, so
+                # a wedged tick is attributed to its kind, not to "idle".
+                self._beat("idle")
+                with span("engine.admit"):
+                    alive = self._admit_phase()
+                if not alive:
+                    return  # shutdown sentinel
+                try:
+                    self._step()
+                except Exception:
+                    _log.exception("decode step failed")
+                    self._fail_all_and_recover()
+
+    def _dequeue_or_wait(self, block: bool):
+        """:meth:`_dequeue`; blocking (no slot active, nothing pending)
+        is waiting for traffic, not host cost: ``engine.wait_work``."""
+        if not block:
+            return self._dequeue(False, self._idle_poll_s)
+        with self._span("engine.wait_work"):
+            return self._dequeue(True, self._idle_poll_s)
 
     def _admit_phase(self) -> bool:
         """Admission work for one scheduler iteration.
@@ -4700,7 +4833,7 @@ class GenerationEngine:
         while self._free_slot() is not None:
             try:
                 idle = all(s is None for s in self._slots)
-                req = self._dequeue(idle, self._idle_poll_s)
+                req = self._dequeue_or_wait(idle)
             except queue.Empty:
                 break
             if isinstance(req, _Wake):
@@ -4778,9 +4911,7 @@ class GenerationEngine:
                 break
             idle = not self._pending and all(s is None for s in self._slots)
             try:
-                req = self._dequeue(
-                    idle and not popped, self._idle_poll_s
-                )
+                req = self._dequeue_or_wait(idle and not popped)
             except queue.Empty:
                 break
             if isinstance(req, _Wake):

@@ -26,6 +26,9 @@ from prometheus_client import (
     Histogram,
     generate_latest,
 )
+from prometheus_client.core import CounterMetricFamily
+
+from ..utils.tracing import Tracer
 
 # Latency SLOs live in the 1ms-10s range on TPU; buckets chosen to resolve
 # p95/p99 there.
@@ -33,6 +36,39 @@ _LATENCY_BUCKETS = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
+
+
+class _SpanCollector:
+    """``tpumlops_span_*``: the tracer's per-name stats rendered when
+    ``/metrics`` is scraped, so a span costs the hot path no prometheus
+    call."""
+
+    def __init__(self, tracer: Tracer, identity: dict[str, str]):
+        self._tracer = tracer
+        self._identity = identity
+
+    def collect(self):
+        labels = [*self._identity, "span"]
+        seconds = CounterMetricFamily(
+            "tpumlops_span_seconds",
+            "Host time inside spans of this name (children included)",
+            labels=labels,
+        )
+        self_seconds = CounterMetricFamily(
+            "tpumlops_span_self_seconds",
+            "Host time inside spans of this name that no child span "
+            "on the same thread covered",
+            labels=labels,
+        )
+        count = CounterMetricFamily(
+            "tpumlops_spans", "Spans of this name closed", labels=labels
+        )
+        for name, s in sorted(self._tracer.stats().items()):
+            values = [*self._identity.values(), name]
+            seconds.add_metric(values, s.total_s)
+            self_seconds.add_metric(values, s.self_s)
+            count.add_metric(values, s.count)
+        return [seconds, self_seconds, count]
 
 
 class ServerMetrics:
@@ -50,6 +86,12 @@ class ServerMetrics:
             "namespace": namespace,
         }
         ident_labels = list(self.identity)
+        # The server's one tracer (utils/tracing.py): the engine loop's
+        # ``engine.*`` spans, with the profiler sink.  Read by
+        # ``/debug/spans`` and by the collector below.
+        self.tracer = Tracer(profiler=True)
+        self.spans = _SpanCollector(self.tracer, self.identity)
+        self.registry.register(self.spans)
 
         self.client_requests = Histogram(
             "seldon_api_executor_client_requests_seconds",
@@ -137,6 +179,25 @@ class ServerMetrics:
         # Prefix KV cache (server/prefix_cache.py): the promotion gate's
         # operator can watch hit rate / cached-token volume per predictor
         # to judge whether a canary inherits the production prefix mix.
+        self.prefill_tokens = Counter(
+            "tpumlops_prefill_tokens_total",
+            "Real (unpadded) prompt tokens whose K/V a prefill dispatch "
+            "wrote; cached-prefix tokens count in "
+            "tpumlops_prefix_cache_cached_tokens instead",
+            ident_labels,
+            registry=self.registry,
+        )
+        # The engine thread stamps each streamed token as it hands it to
+        # the event loop; the SSE writer observes now - stamp after the
+        # event's write returns.  With the engine.* spans' maxima this
+        # tells an event-loop stall from an engine stall.
+        self.emit_lag = Histogram(
+            "tpumlops_emit_lag_seconds",
+            "Engine on_token stamp to the SSE event's write returning",
+            ident_labels,
+            buckets=_LATENCY_BUCKETS,
+            registry=self.registry,
+        )
         self.prefix_cache_hits = Counter(
             "tpumlops_prefix_cache_hits",
             "Admissions that reused a radix-cached prompt prefix",
@@ -591,6 +652,12 @@ class ServerMetrics:
         self.prefix_cache_cached_tokens.labels(**self.identity).inc(
             cached_tokens
         )
+
+    def inc_prefill_tokens(self, n: int):
+        self.prefill_tokens.labels(**self.identity).inc(n)
+
+    def observe_emit_lag(self, seconds: float):
+        self.emit_lag.labels(**self.identity).observe(seconds)
 
     def inc_prefix_evictions(self, n: int = 1):
         self.prefix_cache_evictions.labels(**self.identity).inc(n)

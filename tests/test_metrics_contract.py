@@ -22,7 +22,7 @@ commit.
 from prometheus_client.metrics import MetricWrapperBase
 
 from tpumlops.operator.telemetry import OperatorTelemetry
-from tpumlops.server.metrics import ServerMetrics
+from tpumlops.server.metrics import ServerMetrics, _SpanCollector
 
 _IDENT = ("deployment_name", "predictor_name", "namespace")
 
@@ -75,6 +75,16 @@ EXPECTED_SERVER = {
     "tpumlops_model_ready": ("gauge", _IDENT),
     "tpumlops_pipeline_wait_seconds": ("histogram", _IDENT),
     "tpumlops_prefill_batch_fill": ("histogram", _IDENT),
+    # Real prompt tokens prefilled (cached-prefix tokens excluded);
+    # exported as tpumlops_prefill_tokens_total.
+    "tpumlops_prefill_tokens": ("counter", _IDENT),
+    # Engine on_token stamp -> the SSE event's write returning.
+    "tpumlops_emit_lag_seconds": ("histogram", _IDENT),
+    # utils/tracing.py span stats, rendered at scrape time by one custom
+    # collector (no per-span prometheus call); exported with _total.
+    "tpumlops_span_seconds": ("counter", _IDENT + ("span",)),
+    "tpumlops_span_self_seconds": ("counter", _IDENT + ("span",)),
+    "tpumlops_spans": ("counter", _IDENT + ("span",)),
     "tpumlops_prefix_cache_cached_tokens": ("counter", _IDENT),
     "tpumlops_prefix_cache_evictions": ("counter", _IDENT),
     "tpumlops_prefix_cache_hits": ("counter", _IDENT),
@@ -161,6 +171,9 @@ def _inventory(obj) -> dict:
         if isinstance(attr, MetricWrapperBase):
             fam = attr.describe()[0]
             out[fam.name] = (fam.type, tuple(attr._labelnames))
+        elif isinstance(attr, _SpanCollector):
+            for fam in attr.collect():
+                out[fam.name] = (fam.type, tuple(fam._labelnames))
     return out
 
 

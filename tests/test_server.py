@@ -658,6 +658,31 @@ def test_generate_streaming_sse(llm_server):
     assert final == {"done": True, "output_ids": ref}
 
 
+def test_streaming_observes_one_emit_lag_per_token(llm_server):
+    """``tpumlops_emit_lag_seconds``: the engine stamps each token as it
+    hands it to the event loop, the SSE writer observes once per event
+    written; ``tpumlops_prefill_tokens_total`` counts the prompt."""
+    def scrape():
+        text = httpx.get(llm_server.base + "/metrics", timeout=10).text
+        return (_metric_total(text, "tpumlops_emit_lag_seconds_count"),
+                _metric_total(text, "tpumlops_emit_lag_seconds_sum"),
+                _metric_total(text, "tpumlops_prefill_tokens_total"))
+
+    n0, s0, p0 = scrape()
+    with httpx.stream(
+        "POST",
+        llm_server.base + "/v2/models/llm/generate",
+        json={"prompt_ids": [5, 9, 2, 7], "max_new_tokens": 5, "stream": True},
+        timeout=60,
+    ) as resp:
+        assert resp.status_code == 200
+        events = [ln for ln in resp.iter_lines() if ln.startswith("data: ")]
+    assert len(events) == 6  # five tokens and the final event
+    n1, s1, p1 = scrape()
+    assert n1 - n0 == 5 and 0.0 <= s1 - s0 < 5.0
+    assert p1 - p0 == 4
+
+
 def test_generate_streaming_rejects_multi_prompt(llm_server):
     resp = httpx.post(
         llm_server.base + "/v2/models/llm/generate",
@@ -723,19 +748,28 @@ def test_request_id_echo_and_traceparent(iris_server):
 
 
 def test_debug_spans_endpoint(iris_server):
-    """GLOBAL_TRACER stats readable off the data plane."""
-    from tpumlops.utils.tracing import GLOBAL_TRACER
-
+    """The server's tracer (the one its engine loop writes ``engine.*``
+    spans into) is readable off the data plane, self time included."""
     handle, *_ = iris_server
-    with GLOBAL_TRACER.span("test-span-probe"):
-        pass
+    tracer = handle.server.metrics.tracer
+    with tracer.span("test-span-probe"):
+        with tracer.span("test-span-child"):
+            pass
     resp = httpx.get(handle.base + "/debug/spans", timeout=10)
     assert resp.status_code == 200
     spans = resp.json()["spans"]
     assert spans["test-span-probe"]["count"] >= 1
     assert set(spans["test-span-probe"]) == {
-        "count", "total_s", "mean_ms", "max_ms"
+        "count", "total_s", "self_s", "mean_ms", "max_ms"
     }
+    assert spans["test-span-probe"]["self_s"] <= spans["test-span-probe"]["total_s"]
+    # ... and on /metrics, rendered at scrape time with the identity labels.
+    text = httpx.get(handle.base + "/metrics", timeout=10).text
+    line = next(
+        ln for ln in text.splitlines()
+        if ln.startswith("tpumlops_spans_total{") and 'span="test-span-probe"' in ln
+    )
+    assert 'deployment_name="iris"' in line and float(line.rsplit(" ", 1)[1]) >= 1
 
 
 def test_debug_timeseries_disabled_is_404_naming_the_flag(iris_server):
@@ -829,8 +863,17 @@ def test_generate_multi_row_debug_totals(llm_server):
     ]
 
 
-def test_debug_profile_endpoint(iris_server):
+def test_debug_profile_endpoint(iris_server, tmp_path, monkeypatch):
+    import os
+    import tempfile
+    import threading
+
+    import jax
+
     handle, *_ = iris_server
+    # The server runs in this process: its captures go under the
+    # temporary directory the process is given ($TMPDIR), not /tmp.
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
     resp = httpx.post(
         handle.base + "/debug/profile",
         json={"duration_s": 0.2},
@@ -839,9 +882,8 @@ def test_debug_profile_endpoint(iris_server):
     assert resp.status_code == 200, resp.text
     out = resp.json()
     # paths are server-chosen (unauthenticated endpoint: no client dirs)
-    assert out["trace_dir"].startswith("/tmp/tpumlops-profile/")
-    import os
-
+    assert out["trace_dir"].startswith(str(tmp_path / "tpumlops-profile") + os.sep)
+    assert out["stop_trace_s"] >= 0.0
     found = []
     for _root, _dirs, files in os.walk(out["trace_dir"]):
         found += files
@@ -851,10 +893,36 @@ def test_debug_profile_endpoint(iris_server):
         handle.base + "/debug/profile", json={"duration_s": "nan"}, timeout=10
     )
     assert bad.status_code == 400
-    again = httpx.post(
-        handle.base + "/debug/profile", json={"duration_s": 0.1}, timeout=30
-    )
-    assert again.status_code == 200
+
+    # stop_trace runs off the event loop: while a (slowed) stop is in
+    # progress the server still answers.
+    stopping, real_stop = threading.Event(), jax.profiler.stop_trace
+
+    def slow_stop():
+        stopping.set()
+        time.sleep(1.0)
+        try:
+            real_stop()
+        finally:
+            stopping.clear()
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    result = {}
+
+    def capture():
+        result["again"] = httpx.post(
+            handle.base + "/debug/profile", json={"duration_s": 0.1}, timeout=30
+        )
+
+    t = threading.Thread(target=capture)
+    t.start()
+    assert stopping.wait(timeout=20)
+    live = httpx.get(handle.base + "/v2/health/live", timeout=0.5)
+    assert live.status_code == 200 and stopping.is_set()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert result["again"].status_code == 200
+    assert result["again"].json()["stop_trace_s"] >= 1.0
 
 
 def test_profile_capture_gc_keeps_newest_dirs(tmp_path):
