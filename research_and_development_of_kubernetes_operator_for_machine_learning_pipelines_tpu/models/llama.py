@@ -191,6 +191,19 @@ class KVCache(NamedTuple):
         )
 
 
+def _layer_window(buf: jax.Array, layer, window: int) -> jax.Array:
+    """``buf[layer, :, :window]`` of a ragged-cache buffer ``[L, B, T, NKV,
+    *]`` as ONE dynamic slice, so a layer walk's per-layer read is
+    ``[B, window, NKV, *]`` — sized by the attended window, not by the
+    capacity (an index then a ``[:, :window]`` copies the whole-``T``
+    slab first; tests/test_tpu_compile.py pins the window-sized read)."""
+    _l, b, _t, n, d = buf.shape
+    z = jnp.zeros((), jnp.int32)
+    return lax.dynamic_slice(
+        buf, (jnp.asarray(layer, jnp.int32), z, z, z, z), (1, b, window, n, d)
+    )[0]
+
+
 class RaggedKVCache(NamedTuple):
     """Multi-slot KV cache with PER-ROW lengths (continuous batching).
 
@@ -200,9 +213,16 @@ class RaggedKVCache(NamedTuple):
     together in one static-shape batched step (``decode_ragged``).  The
     server's :class:`~..server.generation.GenerationEngine` owns slot
     assignment; this type is the pure-JAX state it schedules over.
+
+    Position-major is the layout the decode program computes in (a
+    commit writes whole ``[NKV, D]`` planes at one position), so the
+    donated buffers alias through it with no relayout copy
+    (tests/test_tpu_compile.py::test_ragged_programs_leave_the_cache_in_place).
+    The shape is made HERE and nowhere else: callers ask ``capacity`` and
+    ``layer_window``, never a ``shape[i]``.
     """
 
-    k: jax.Array  # [L, B, NKV, T, D] — head-major (see QuantRaggedKVCache)
+    k: jax.Array  # [L, B, T, NKV, D]
     v: jax.Array
     lengths: jax.Array  # int32 [B]: valid positions per slot
 
@@ -210,11 +230,24 @@ class RaggedKVCache(NamedTuple):
     def create(
         cls, cfg: LlamaConfig, batch: int, dtype=jnp.bfloat16
     ) -> "RaggedKVCache":
-        shape = (cfg.num_layers, batch, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
+        shape = (cfg.num_layers, batch, cfg.max_seq, cfg.num_kv_heads, cfg.head_dim)
         return cls(
             k=jnp.zeros(shape, dtype),
             v=jnp.zeros(shape, dtype),
             lengths=jnp.zeros((batch,), jnp.int32),
+        )
+
+    @property
+    def capacity(self) -> int:
+        """Positions a slot can hold (the static ``T``)."""
+        return self.k.shape[2]
+
+    def layer_window(self, layer, window: int):
+        """Layer ``layer``'s first ``window`` positions of every slot:
+        ``(k, v)``, each ``[B, window, NKV, D]``."""
+        return (
+            _layer_window(self.k, layer, window),
+            _layer_window(self.v, layer, window),
         )
 
 
@@ -235,19 +268,15 @@ class QuantRaggedKVCache(NamedTuple):
     ``spec.tpu.quantize: int8kv``.
     """
 
-    k8: jax.Array  # int8   [L, B, NKV, T, D] — head-major: one (slot,
-    #   kv-head)'s attended window is CONTIGUOUS, which is both the DMA-
-    #   friendly order for decode reads and the block shape the fused
-    #   Pallas kernel requires (ops/decode_attention.py; last two block
-    #   dims must be the tile-aligned (W, D)).
-    k_scale: jax.Array  # f32 [L, B, NKV, T, 1]
+    k8: jax.Array  # int8 [L, B, T, NKV, D], RaggedKVCache's layout
+    k_scale: jax.Array  # f32 [L, B, T, NKV, 1]
     v8: jax.Array
     v_scale: jax.Array
     lengths: jax.Array  # int32 [B]
 
     @classmethod
     def create(cls, cfg: LlamaConfig, batch: int) -> "QuantRaggedKVCache":
-        shape = (cfg.num_layers, batch, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
+        shape = (cfg.num_layers, batch, cfg.max_seq, cfg.num_kv_heads, cfg.head_dim)
         sshape = shape[:-1] + (1,)
         return cls(
             k8=jnp.zeros(shape, jnp.int8),
@@ -255,6 +284,24 @@ class QuantRaggedKVCache(NamedTuple):
             v8=jnp.zeros(shape, jnp.int8),
             v_scale=jnp.zeros(sshape, jnp.float32),
             lengths=jnp.zeros((batch,), jnp.int32),
+        )
+
+    @property
+    def capacity(self) -> int:
+        return self.k8.shape[2]
+
+    def layer_window(self, layer, window: int):
+        """``((k8, k_scale), (v8, v_scale))`` of layer ``layer``, each
+        buffer ``[B, window, NKV, *]``."""
+        return (
+            (
+                _layer_window(self.k8, layer, window),
+                _layer_window(self.k_scale, layer, window),
+            ),
+            (
+                _layer_window(self.v8, layer, window),
+                _layer_window(self.v_scale, layer, window),
+            ),
         )
 
 
@@ -411,6 +458,12 @@ def _head(params, x, cfg):
         return _qmatmul(x, params["lm_head"])
 
 
+def _scale_over_keys(scale: jax.Array) -> jax.Array:
+    """Per-(position, head) int8 scales ``[B, K, NKV, 1]`` -> ``[B, NKV,
+    1, 1, K]``: broadcast over the ``bngqk`` scores' (group, query) axes."""
+    return jnp.moveaxis(scale[..., 0], 1, 2)[:, :, None, None, :]
+
+
 def _block(
     x: jax.Array,
     lp: dict,
@@ -500,13 +553,10 @@ def _block(
                 k8.astype(x.dtype),
                 preferred_element_type=jnp.float32,
             ) / jnp.sqrt(jnp.float32(hd))
-            # ks: [B, W, NKV, 1] -> [B, NKV, 1, 1, W] broadcast over (G, S)
-            kscale = jnp.moveaxis(ks[..., 0], 1, 2)[:, :, None, None, :]
-            scores = scores * kscale
+            scores = scores * _scale_over_keys(ks)
             scores = scores + mask_bias[:, None]
             probs = jax.nn.softmax(scores, axis=-1)
-            vscale = jnp.moveaxis(vs[..., 0], 1, 2)[:, :, None, None, :]
-            probs = (probs * vscale).astype(x.dtype)
+            probs = (probs * _scale_over_keys(vs)).astype(x.dtype)
             ctx = jnp.einsum(
                 "bngqk,bknd->bqngd", probs, v8.astype(x.dtype)
             ).reshape(b, s, nh * hd)
@@ -534,10 +584,13 @@ def _block_decode_deferred(
     sin: jax.Array,
     mask_bias: jax.Array,
     cfg: LlamaConfig,
-    window: int,
 ):
     """One decoder layer for single-token ragged decode with the cache
     READ-ONLY: returns ``(y, k_new, v_new)`` instead of an updated cache.
+
+    ``cache_k``/``cache_v`` are the layer's attended window
+    ``[B, W, NKV, D]`` (``cache.layer_window``; ``(values, scales)``
+    pairs under int8kv) and ``mask_bias`` is ``[B, 1, 1, W]``.
 
     Why: if the layer scan carried an updated cache, the update would ride
     the scan's stacked outputs and XLA materializes that as a full cache
@@ -563,6 +616,7 @@ def _block_decode_deferred(
     qg = q.reshape(b, s, nkv, group, hd)
     quant_cache = isinstance(cache_k, tuple)
     impl = _decode_attn_impl()
+    window = mask_bias.shape[-1]
     if impl == "pallas_vpu" and (group != 1 or window % 128 != 0):
         # The VPU kernel is the G == 1 formulation over [W/128, 128]
         # lane tiles.  Reject, don't reroute: a run labeled
@@ -589,14 +643,17 @@ def _block_decode_deferred(
                 "pallas_single": decode_attention,
                 "pallas_vpu": decode_attention_vpu,
             }.get(impl, decode_attention_batched)
-            k8, ks = cache_k
-            v8, vs = cache_v
+            # The kernels' blocks are one (slot, kv-head)'s contiguous
+            # [W, D] window: hand them a transposed view of the slab.
+            k8, ks, v8, vs = (
+                jnp.swapaxes(a, 1, 2) for a in (*cache_k, *cache_v)
+            )
             ctx4 = attn_fn(
                 qg[:, 0],                                   # [B, NKV, G, D]
-                k8[:, :, :window],
-                ks[:, :, :window],                          # [B, NKV, W, 1]
-                v8[:, :, :window],
-                vs[:, :, :window],
+                k8,
+                ks,                                         # [B, NKV, W, 1]
+                v8,
+                vs,
                 k[:, 0][:, :, None, :],                     # [B, NKV, 1, D]
                 v[:, 0][:, :, None, :],
                 mask_bias[:, 0],                            # [B, 1, W]
@@ -607,22 +664,19 @@ def _block_decode_deferred(
         if quant_cache:
             k8, ks = cache_k
             v8, vs = cache_v
-            k8, ks = k8[:, :, :window], ks[:, :, :window]
-            v8, vs = v8[:, :, :window], vs[:, :, :window]
             scores = jnp.einsum(
-                "bqngd,bnkd->bngqk",
+                "bqngd,bknd->bngqk",
                 qg,
                 k8.astype(x.dtype),
                 preferred_element_type=jnp.float32,
             ) / jnp.sqrt(jnp.float32(hd))
-            # ks: [B, NKV, W, 1] -> [B, NKV, 1, 1, W] — head-major layout
-            # means NO transposed copy, just a reshape of the window slice.
-            kscale = ks[..., 0][:, :, None, None, :]
-            scores = scores * kscale
+            scores = scores * _scale_over_keys(ks)
         else:
-            kk = cache_k[:, :, :window].astype(x.dtype)
             scores = jnp.einsum(
-                "bqngd,bnkd->bngqk", qg, kk, preferred_element_type=jnp.float32
+                "bqngd,bknd->bngqk",
+                qg,
+                cache_k.astype(x.dtype),
+                preferred_element_type=jnp.float32,
             ) / jnp.sqrt(jnp.float32(hd))
         scores = scores + mask_bias[:, None]
 
@@ -636,12 +690,14 @@ def _block_decode_deferred(
         probs_cache, prob_self = probs[..., :-1], probs[..., -1:]
 
         if quant_cache:
-            vscale = vs[..., 0][:, :, None, None, :]
-            probs_cache = (probs_cache * vscale).astype(x.dtype)
-            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache, v8.astype(x.dtype))
+            probs_cache = (probs_cache * _scale_over_keys(vs)).astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bknd->bqngd", probs_cache, v8.astype(x.dtype))
         else:
-            vv = cache_v[:, :, :window].astype(x.dtype)
-            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache.astype(x.dtype), vv)
+            ctx = jnp.einsum(
+                "bngqk,bknd->bqngd",
+                probs_cache.astype(x.dtype),
+                cache_v.astype(x.dtype),
+            )
         ctx = ctx + jnp.einsum(
             "bngqk,bknd->bqngd", prob_self.astype(x.dtype), v
         )
@@ -831,12 +887,50 @@ def generate_greedy(
 # ---------------------------------------------------------------------------
 
 
-# Layer-walk strategy for decode_ragged: "fori" (default — dynamic-slice
-# reads against the original cache buffers) or "scan" (cache packed as
-# scan xs).  Kept switchable so the two loop forms can be A/B'd inside
-# ONE process (scripts/ab_decode.py) — this environment's cross-process
-# timing variance (~±20%) swamps the difference otherwise.
-_DECODE_LAYER_LOOP = "fori"
+def _attended_window(cache, window: int | None) -> int:
+    """The static attended prefix: ``window`` clamped to the capacity."""
+    return cache.capacity if window is None else min(int(window), cache.capacity)
+
+
+def _walk_layers(params, cache, x, cfg, window, block, rows=None):
+    """The layer loop every ragged program shares, the cache READ-ONLY:
+    ``block(x, lp, ck, cv) -> (y, k_new, v_new)`` per layer, where
+    ``ck``/``cv`` are ``cache.layer_window(l, window)`` (gathered to
+    ``rows`` when the batch is a subset of the slots) and ``k_new`` /
+    ``v_new`` are the layer's fresh ``[B, S, NKV, D]`` rows.  Returns
+    ``(x, k_news, v_news)`` with the rows stacked ``[L, B, S, NKV, D]``
+    for ONE commit after the loop.
+
+    A ``fori_loop`` over dynamic slices of the ORIGINAL buffers, not a
+    ``lax.scan`` with the cache as xs: packing multi-GiB buffers into a
+    scan's xs can make XLA copy them into loop state each step.
+    """
+    b, s, _h = x.shape
+    acc_k = jnp.zeros(
+        (cfg.num_layers, b, s, cfg.num_kv_heads, cfg.head_dim), x.dtype
+    )
+
+    def layer_body(l, carry):
+        x, acc_k, acc_v = carry
+        lp = jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, l, axis=0, keepdims=False),
+            params["layers"],
+        )
+        ck, cv = cache.layer_window(l, window)
+        if rows is not None:
+            ck, cv = jax.tree.map(lambda a: a[rows], (ck, cv))
+        y, k_new, v_new = block(x, lp, ck, cv)
+        acc_k = lax.dynamic_update_slice_in_dim(
+            acc_k, k_new[None].astype(acc_k.dtype), l, axis=0
+        )
+        acc_v = lax.dynamic_update_slice_in_dim(
+            acc_v, v_new[None].astype(acc_v.dtype), l, axis=0
+        )
+        return y, acc_k, acc_v
+
+    return lax.fori_loop(
+        0, cfg.num_layers, layer_body, (x, acc_k, jnp.zeros_like(acc_k))
+    )
 
 
 def decode_ragged(
@@ -884,10 +978,7 @@ def decode_ragged(
     positions = lengths[:, None]  # [B, 1]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)  # [B, 1, head_dim]
 
-    capacity = (cache.k8 if quant else cache.k).shape[3]  # [L,B,NKV,T,D]
-    if window is None:
-        window = capacity
-    window = min(int(window), capacity)
+    window = _attended_window(cache, window)
     key_pos = jnp.arange(window)
     # STRICT mask: the current position is attended via the exact
     # self-term inside _block_decode_deferred, not read back from the
@@ -896,77 +987,15 @@ def decode_ragged(
     valid = key_pos[None, None, :] < positions[:, :, None]  # [B, 1, W]
     mask_bias = jnp.where(valid, 0.0, -1e9).astype(jnp.float32)[:, None]  # [B,1,1,W]
 
-    if _DECODE_LAYER_LOOP == "scan":
-        def scan_body(carry, layer_inputs):
-            xc = carry
-            lp, ck, cv = layer_inputs
-            y, k_new, v_new = _block_decode_deferred(
-                xc, lp, ck, cv, cos, sin, mask_bias, cfg, window=window
-            )
-            return y, (k_new, v_new)
-
-        ck0 = (cache.k8, cache.k_scale) if quant else cache.k
-        cv0 = (cache.v8, cache.v_scale) if quant else cache.v
-        x, (k_news, v_news) = lax.scan(
-            scan_body, x, (params["layers"], ck0, cv0)
-        )
-        k_news = k_news[:, :, 0]  # [L, B, NKV, D]
-        v_news = v_news[:, :, 0]
-        return _finish_decode(
-            params, x, k_news, v_news, cache, lengths, active, quant, cfg
-        )
-
-    # Default: fori_loop + dynamic_index_in_dim, NOT lax.scan with the
-    # cache as xs — packing multi-GiB buffers into a scan's xs tuple can
-    # make XLA copy them into loop state each step.  The fori body reads
-    # each layer's weights and cache slabs with dynamic slices against
-    # the ORIGINAL buffers (read-only, no loop-state packing) and
-    # accumulates the tiny per-layer K/V rows in place.  A/B on chip:
-    # scripts/ab_decode.py (the scan variant stays selectable above so
-    # both compile in ONE process — timings from separate processes
-    # cannot compare variants).
-    nlayers = cfg.num_layers
-    kv_dtype = x.dtype
-    acc_k = jnp.zeros((nlayers, b, cfg.num_kv_heads, cfg.head_dim), kv_dtype)
-    acc_v = jnp.zeros_like(acc_k)
-
-    def idx(tree, l):
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, l, axis=0, keepdims=False),
-            tree,
-        )
-
-    def layer_body(l, carry):
-        x, acc_k, acc_v = carry
-        lp = idx(params["layers"], l)
-        if quant:
-            ck = (
-                lax.dynamic_index_in_dim(cache.k8, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(cache.k_scale, l, 0, keepdims=False),
-            )
-            cv = (
-                lax.dynamic_index_in_dim(cache.v8, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(cache.v_scale, l, 0, keepdims=False),
-            )
-        else:
-            ck = lax.dynamic_index_in_dim(cache.k, l, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cache.v, l, 0, keepdims=False)
-        y, k_new, v_new = _block_decode_deferred(
-            x, lp, ck, cv, cos, sin, mask_bias, cfg, window=window
-        )
-        acc_k = lax.dynamic_update_slice_in_dim(
-            acc_k, k_new[None, :, 0].astype(kv_dtype), l, axis=0
-        )
-        acc_v = lax.dynamic_update_slice_in_dim(
-            acc_v, v_new[None, :, 0].astype(kv_dtype), l, axis=0
-        )
-        return y, acc_k, acc_v
-
-    x, k_news, v_news = lax.fori_loop(
-        0, nlayers, layer_body, (x, acc_k, acc_v)
+    x, k_news, v_news = _walk_layers(
+        params, cache, x, cfg, window,
+        lambda x, lp, ck, cv: _block_decode_deferred(
+            x, lp, ck, cv, cos, sin, mask_bias, cfg
+        ),
     )
     return _finish_decode(
-        params, x, k_news, v_news, cache, lengths, active, quant, cfg
+        params, x, k_news[:, :, 0], v_news[:, :, 0], cache, lengths, active,
+        quant, cfg,
     )
 
 
@@ -1055,7 +1084,6 @@ def _block_verify_deferred(
     mask_bias: jax.Array,
     chunk_bias: jax.Array,
     cfg: LlamaConfig,
-    window: int,
 ):
     """One decoder layer for MULTI-token ragged verify with the cache
     READ-ONLY: ``x`` is ``[B, S, H]`` where row ``i``'s S tokens sit at
@@ -1064,7 +1092,8 @@ def _block_verify_deferred(
     commits every layer with one scatter pass after the scan, exactly
     like :func:`_block_decode_deferred` (whose S == 1 case this
     generalizes; see that docstring for the deferred-write traffic
-    argument).
+    argument).  ``cache_k``/``cache_v`` are the layer's attended window
+    ``[B, W, NKV, D]``, as there.
 
     Attention decomposes into two exact terms: the cache window (strict
     mask ``key_pos < lengths[i]`` — no chunk position has been written
@@ -1085,20 +1114,19 @@ def _block_verify_deferred(
         if quant_cache:
             k8, ks = cache_k
             v8, vs = cache_v
-            k8, ks = k8[:, :, :window], ks[:, :, :window]
-            v8, vs = v8[:, :, :window], vs[:, :, :window]
             scores = jnp.einsum(
-                "bqngd,bnkd->bngqk",
+                "bqngd,bknd->bngqk",
                 qg,
                 k8.astype(x.dtype),
                 preferred_element_type=jnp.float32,
             ) / jnp.sqrt(jnp.float32(hd))
-            kscale = ks[..., 0][:, :, None, None, :]
-            scores = scores * kscale
+            scores = scores * _scale_over_keys(ks)
         else:
-            kk = cache_k[:, :, :window].astype(x.dtype)
             scores = jnp.einsum(
-                "bqngd,bnkd->bngqk", qg, kk, preferred_element_type=jnp.float32
+                "bqngd,bknd->bngqk",
+                qg,
+                cache_k.astype(x.dtype),
+                preferred_element_type=jnp.float32,
             ) / jnp.sqrt(jnp.float32(hd))
         scores = scores + mask_bias[:, None]  # [B,1,1,W] -> over (n, g, q)
 
@@ -1124,8 +1152,7 @@ def _block_verify_deferred(
                 k8c.astype(x.dtype),
                 preferred_element_type=jnp.float32,
             ) / jnp.sqrt(jnp.float32(hd))
-            kscale_c = jnp.moveaxis(kscc[..., 0], 1, 2)[:, :, None, None, :]
-            score_rt = score_rt * kscale_c
+            score_rt = score_rt * _scale_over_keys(kscc)
             eye = jnp.eye(s, dtype=bool)[None, None, None]
             score_chunk = jnp.where(eye, score_self, score_rt)
         else:
@@ -1136,14 +1163,13 @@ def _block_verify_deferred(
         probs_cache, probs_chunk = probs[..., :-s], probs[..., -s:]
 
         if quant_cache:
-            vscale = vs[..., 0][:, :, None, None, :]
-            probs_cache = (probs_cache * vscale).astype(x.dtype)
-            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache, v8.astype(x.dtype))
+            probs_cache = (probs_cache * _scale_over_keys(vs)).astype(x.dtype)
+            ctx = jnp.einsum("bngqk,bknd->bqngd", probs_cache, v8.astype(x.dtype))
             # Chunk V: self row full-precision, earlier rows through the
             # int8 round-trip (scales folded into the probabilities, like
             # the cache-window term).
             v8c, vscc = _quant_kv(v)
-            vscale_c = jnp.moveaxis(vscc[..., 0], 1, 2)[:, :, None, None, :]
+            vscale_c = _scale_over_keys(vscc)
             eyef = eye.astype(probs.dtype)
             ctx = ctx + jnp.einsum(
                 "bngqj,bjnd->bqngd", (probs_chunk * eyef).astype(x.dtype), v
@@ -1154,8 +1180,11 @@ def _block_verify_deferred(
                 v8c.astype(x.dtype),
             )
         else:
-            vv = cache_v[:, :, :window].astype(x.dtype)
-            ctx = jnp.einsum("bngqk,bnkd->bqngd", probs_cache.astype(x.dtype), vv)
+            ctx = jnp.einsum(
+                "bngqk,bknd->bqngd",
+                probs_cache.astype(x.dtype),
+                cache_v.astype(x.dtype),
+            )
             ctx = ctx + jnp.einsum(
                 "bngqj,bjnd->bqngd", probs_chunk.astype(x.dtype), v
             )
@@ -1202,10 +1231,7 @@ def verify_ragged(
     positions = lengths[:, None] + jnp.arange(s)[None, :]  # [B, S]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)  # [B, S, head_dim]
 
-    capacity = (cache.k8 if quant else cache.k).shape[3]
-    if window is None:
-        window = capacity
-    window = min(int(window), capacity)
+    window = _attended_window(cache, window)
     key_pos = jnp.arange(window)
     # STRICT cache mask shared by every chunk query: no chunk position has
     # been written yet, so all of them see exactly key_pos < lengths[i];
@@ -1219,44 +1245,12 @@ def verify_ragged(
         None, None, None
     ]
 
-    nlayers = cfg.num_layers
-    kv_dtype = x.dtype
-    acc_k = jnp.zeros((nlayers, b, s, cfg.num_kv_heads, cfg.head_dim), kv_dtype)
-    acc_v = jnp.zeros_like(acc_k)
-
-    def idx(tree, l):
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, l, axis=0, keepdims=False),
-            tree,
-        )
-
-    def layer_body(l, carry):
-        x, acc_k, acc_v = carry
-        lp = idx(params["layers"], l)
-        if quant:
-            ck = (
-                lax.dynamic_index_in_dim(cache.k8, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(cache.k_scale, l, 0, keepdims=False),
-            )
-            cv = (
-                lax.dynamic_index_in_dim(cache.v8, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(cache.v_scale, l, 0, keepdims=False),
-            )
-        else:
-            ck = lax.dynamic_index_in_dim(cache.k, l, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cache.v, l, 0, keepdims=False)
-        y, k_new, v_new = _block_verify_deferred(
-            x, lp, ck, cv, cos, sin, mask_bias, chunk_bias, cfg, window=window
-        )
-        acc_k = lax.dynamic_update_slice_in_dim(
-            acc_k, k_new[None].astype(kv_dtype), l, axis=0
-        )
-        acc_v = lax.dynamic_update_slice_in_dim(
-            acc_v, v_new[None].astype(kv_dtype), l, axis=0
-        )
-        return y, acc_k, acc_v
-
-    x, k_news, v_news = lax.fori_loop(0, nlayers, layer_body, (x, acc_k, acc_v))
+    x, k_news, v_news = _walk_layers(
+        params, cache, x, cfg, window,
+        lambda x, lp, ck, cv: _block_verify_deferred(
+            x, lp, ck, cv, cos, sin, mask_bias, chunk_bias, cfg
+        ),
+    )
     logits = _head(params, x, cfg)
     return logits, _commit_chunk(cache, k_news, v_news, lengths, quant, active)
 
@@ -1276,24 +1270,27 @@ def _commit_chunk(cache, k_news, v_news, lengths, quant, active=None):
     be mid-packed-prefill (its K/V written by the admission path, not
     this tick), and the old always-write garbage row would corrupt it.
     """
-    s = k_news.shape[2]
-    capacity = (cache.k8 if quant else cache.k).shape[3]
+    b, s = k_news.shape[1:3]
     write_base = lengths
     if active is not None:
-        write_base = jnp.where(active, lengths, jnp.int32(capacity))
+        write_base = jnp.where(active, lengths, jnp.int32(cache.capacity))
+    pos = write_base[:, None] + jnp.arange(s)[None, :]
+    return _commit_at(cache, k_news, v_news, jnp.arange(b), pos, quant)
+
+
+def _commit_at(cache, k_news, v_news, rows, pos, quant):
+    """Write chunk rows ``k_news``/``v_news`` ``[L, B_c, S, NKV, D]`` at
+    cache ``(rows[b], pos[b, j])`` — ``rows`` ``[B_c]``, ``pos`` ``[B_c,
+    S]`` — with ONE batched drop-scatter per buffer and ``lengths``
+    unchanged.  The advanced indices sit on the buffers' adjacent (row,
+    position) axes, so the updates are the rows as they stand.  The
+    (row, position) tuples must be pairwise distinct
+    (``unique_indices``); positions at or past capacity drop, never
+    clamp."""
 
     def commit(buf, vals):
-        # buf [L, B, NKV, T, ...]; vals [L, B, S, NKV, ...].  Advanced
-        # indices rows [B,1] (axis 1) and positions [B,S] (axis 3)
-        # broadcast to [B, S] and move to the front: updates are
-        # [B, S, L, NKV, ...].  Indices stay unique (distinct j per
-        # row); rows spilling past capacity drop, never clamp.
-        b = buf.shape[1]
-        rows = jnp.arange(b)[:, None]
-        pos = write_base[:, None] + jnp.arange(s)[None, :]
-        v = jnp.moveaxis(vals, (1, 2), (0, 1)).astype(buf.dtype)
-        return buf.at[:, rows, :, pos].set(
-            v, mode="drop", unique_indices=True
+        return buf.at[:, rows[:, None], pos].set(
+            vals.astype(buf.dtype), mode="drop", unique_indices=True
         )
 
     if quant:
@@ -1304,10 +1301,10 @@ def _commit_chunk(cache, k_news, v_news, lengths, quant, active=None):
             commit(cache.k_scale, kqs),
             commit(cache.v8, vq),
             commit(cache.v_scale, vqs),
-            lengths,
+            cache.lengths,
         )
     return RaggedKVCache(
-        commit(cache.k, k_news), commit(cache.v, v_news), lengths
+        commit(cache.k, k_news), commit(cache.v, v_news), cache.lengths
     )
 
 
@@ -1354,7 +1351,7 @@ def prefill_chunks_ragged(
     positions = offsets[:, None] + jnp.arange(s)[None, :]  # [B_p, C]
     cos, sin = rope_cos_sin(positions, cfg, jnp.float32)
 
-    capacity = (cache.k8 if quant else cache.k).shape[3]
+    capacity = cache.capacity
     key_pos = jnp.arange(capacity)
     # STRICT cache mask, exactly verify_ragged's: no chunk position has
     # been written yet, so every chunk query sees key_pos < offsets[b];
@@ -1367,51 +1364,16 @@ def prefill_chunks_ragged(
         None, None, None
     ]
 
-    nlayers = cfg.num_layers
-    kv_dtype = x.dtype
-    acc_k = jnp.zeros((nlayers, b, s, cfg.num_kv_heads, cfg.head_dim), kv_dtype)
-    acc_v = jnp.zeros_like(acc_k)
-
-    def idx(tree, l):
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, l, axis=0, keepdims=False),
-            tree,
-        )
-
-    def layer_body(l, carry):
-        x, acc_k, acc_v = carry
-        # Gather the B_p admissions' cache rows out of the full slot
-        # batch: the compute (and the weight stream it amortizes) scales
-        # with the B_p bucket, not max_slots.
-        if quant:
-            ck = (
-                lax.dynamic_index_in_dim(cache.k8, l, 0, keepdims=False)[slots],
-                lax.dynamic_index_in_dim(
-                    cache.k_scale, l, 0, keepdims=False
-                )[slots],
-            )
-            cv = (
-                lax.dynamic_index_in_dim(cache.v8, l, 0, keepdims=False)[slots],
-                lax.dynamic_index_in_dim(
-                    cache.v_scale, l, 0, keepdims=False
-                )[slots],
-            )
-        else:
-            ck = lax.dynamic_index_in_dim(cache.k, l, 0, keepdims=False)[slots]
-            cv = lax.dynamic_index_in_dim(cache.v, l, 0, keepdims=False)[slots]
-        y, k_new, v_new = _block_verify_deferred(
-            x, idx(params["layers"], l), ck, cv, cos, sin, mask_bias,
-            chunk_bias, cfg, window=capacity,
-        )
-        acc_k = lax.dynamic_update_slice_in_dim(
-            acc_k, k_new[None].astype(kv_dtype), l, axis=0
-        )
-        acc_v = lax.dynamic_update_slice_in_dim(
-            acc_v, v_new[None].astype(kv_dtype), l, axis=0
-        )
-        return y, acc_k, acc_v
-
-    x, k_news, v_news = lax.fori_loop(0, nlayers, layer_body, (x, acc_k, acc_v))
+    # Gather the B_p admissions' cache rows out of the full slot batch:
+    # the compute (and the weight stream it amortizes) scales with the
+    # B_p bucket, not max_slots.
+    x, k_news, v_news = _walk_layers(
+        params, cache, x, cfg, capacity,
+        lambda x, lp, ck, cv: _block_verify_deferred(
+            x, lp, ck, cv, cos, sin, mask_bias, chunk_bias, cfg
+        ),
+        rows=slots,
+    )
     logits = _head(params, x, cfg)
     return logits, _commit_chunk_at(cache, k_news, v_news, slots, offsets, quant)
 
@@ -1428,29 +1390,8 @@ def _commit_chunk_at(cache, k_news, v_news, slots, offsets, quant):
     carry slots distinct from each other (their positions start at
     ``capacity``, so they cannot collide with a real row's tuple even
     on an equal slot value)."""
-    s = k_news.shape[2]
-
-    def commit(buf, vals):
-        rows = slots[:, None]
-        pos = offsets[:, None] + jnp.arange(s)[None, :]
-        v = jnp.moveaxis(vals, (1, 2), (0, 1)).astype(buf.dtype)
-        return buf.at[:, rows, :, pos].set(
-            v, mode="drop", unique_indices=True
-        )
-
-    if quant:
-        kq, kqs = _quant_kv(k_news)
-        vq, vqs = _quant_kv(v_news)
-        return QuantRaggedKVCache(
-            commit(cache.k8, kq),
-            commit(cache.k_scale, kqs),
-            commit(cache.v8, vq),
-            commit(cache.v_scale, vqs),
-            cache.lengths,
-        )
-    return RaggedKVCache(
-        commit(cache.k, k_news), commit(cache.v, v_news), cache.lengths
-    )
+    pos = offsets[:, None] + jnp.arange(k_news.shape[2])[None, :]
+    return _commit_at(cache, k_news, v_news, slots, pos, quant)
 
 
 # Per-row roles for the unified super-step (super_step_ragged): what each
@@ -1475,36 +1416,14 @@ def _commit_block_at(cache, k_news, v_news, base, counts, quant):
     increasing and bounded by ``capacity + S - 1`` (drop-scatter spill),
     and its parked positions start at ``capacity + S`` — the two ranges
     cannot collide, so every (row, position) tuple stays distinct."""
-    s = k_news.shape[2]
-    capacity = (cache.k8 if quant else cache.k).shape[3]
-
-    def commit(buf, vals):
-        b = buf.shape[1]
-        rows = jnp.arange(b)[:, None]
-        j = jnp.arange(s)[None, :]
-        pos = jnp.where(
-            j < counts[:, None],
-            base[:, None] + j,
-            jnp.int32(capacity + s) + j,
-        )
-        v = jnp.moveaxis(vals, (1, 2), (0, 1)).astype(buf.dtype)
-        return buf.at[:, rows, :, pos].set(
-            v, mode="drop", unique_indices=True
-        )
-
-    if quant:
-        kq, kqs = _quant_kv(k_news)
-        vq, vqs = _quant_kv(v_news)
-        return QuantRaggedKVCache(
-            commit(cache.k8, kq),
-            commit(cache.k_scale, kqs),
-            commit(cache.v8, vq),
-            commit(cache.v_scale, vqs),
-            cache.lengths,
-        )
-    return RaggedKVCache(
-        commit(cache.k, k_news), commit(cache.v, v_news), cache.lengths
+    b, s = k_news.shape[1:3]
+    j = jnp.arange(s)[None, :]
+    pos = jnp.where(
+        j < counts[:, None],
+        base[:, None] + j,
+        jnp.int32(cache.capacity + s) + j,
     )
+    return _commit_at(cache, k_news, v_news, jnp.arange(b), pos, quant)
 
 
 def super_step_ragged(
@@ -1573,10 +1492,7 @@ def super_step_ragged(
     b, s = token_block.shape
     quant = isinstance(cache, QuantRaggedKVCache)
     lengths = cache.lengths
-    capacity = (cache.k8 if quant else cache.k).shape[3]
-    if window is None:
-        window = capacity
-    window = min(int(window), capacity)
+    window = _attended_window(cache, window)
 
     is_dec = roles == ROLE_DECODE
     is_ver = roles == ROLE_VERIFY
@@ -1606,44 +1522,12 @@ def super_step_ragged(
         None, None, None
     ]
 
-    nlayers = cfg.num_layers
-    kv_dtype = x.dtype
-    acc_k = jnp.zeros((nlayers, b, s, cfg.num_kv_heads, cfg.head_dim), kv_dtype)
-    acc_v = jnp.zeros_like(acc_k)
-
-    def idx(tree, l):
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, l, axis=0, keepdims=False),
-            tree,
-        )
-
-    def layer_body(l, carry):
-        x, acc_k, acc_v = carry
-        if quant:
-            ck = (
-                lax.dynamic_index_in_dim(cache.k8, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(cache.k_scale, l, 0, keepdims=False),
-            )
-            cv = (
-                lax.dynamic_index_in_dim(cache.v8, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(cache.v_scale, l, 0, keepdims=False),
-            )
-        else:
-            ck = lax.dynamic_index_in_dim(cache.k, l, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cache.v, l, 0, keepdims=False)
-        y, k_new, v_new = _block_verify_deferred(
-            x, idx(params["layers"], l), ck, cv, cos, sin, mask_bias,
-            chunk_bias, cfg, window=window,
-        )
-        acc_k = lax.dynamic_update_slice_in_dim(
-            acc_k, k_new[None].astype(kv_dtype), l, axis=0
-        )
-        acc_v = lax.dynamic_update_slice_in_dim(
-            acc_v, v_new[None].astype(kv_dtype), l, axis=0
-        )
-        return y, acc_k, acc_v
-
-    x, k_news, v_news = lax.fori_loop(0, nlayers, layer_body, (x, acc_k, acc_v))
+    x, k_news, v_news = _walk_layers(
+        params, cache, x, cfg, window,
+        lambda x, lp, ck, cv: _block_verify_deferred(
+            x, lp, ck, cv, cos, sin, mask_bias, chunk_bias, cfg
+        ),
+    )
     logits = _head(params, x, cfg)  # [B, S, vocab] f32
 
     cache = _commit_block_at(cache, k_news, v_news, base, counts, quant)
@@ -1706,10 +1590,9 @@ def _finish_decode(params, x, k_news, v_news, cache, lengths, active, quant, cfg
     # by the scatter): an empty slot may be mid-packed-prefill, and its
     # rows are being written by the admission path — the old
     # always-write garbage token would corrupt the prefilled prompt.
-    capacity = (cache.k8 if quant else cache.k).shape[3]
     write_pos = lengths
     if active is not None:
-        write_pos = jnp.where(active, lengths, jnp.int32(capacity))
+        write_pos = jnp.where(active, lengths, jnp.int32(cache.capacity))
     if quant:
         kq, kqs = _quant_kv(k_news)
         vq, vqs = _quant_kv(v_news)
@@ -1731,32 +1614,16 @@ def _finish_decode(params, x, k_news, v_news, cache, lengths, active, quant, cfg
 def _commit_rows(buf: jax.Array, vals: jax.Array, lengths: jax.Array) -> jax.Array:
     """Write row ``b``'s new K/V at its own position, in place.
 
-    ``buf`` is head-major ``[L, B, NKV, T, ...]``, ``vals`` ``[L, B, NKV,
-    ...]``; row ``b`` writes at position ``lengths[b]`` on axis 3, and a
-    row parked at capacity (``lengths[b] == T``) must be DROPPED, never
-    clamped onto its last real position.
-
-    One batched scatter with drop semantics.  History, because this spot
-    has flip-flopped on measurement twice: round 4 found the scatter
-    forcing a full cache copy per step — but only because the layer scan
-    then consumed the cache as its xs, and the xs-read + scatter
-    interplay defeated XLA's copy elimination; the fix was a fori-loop
-    of per-row ``dynamic_update_slice``.  Round 5's layer walk reads the
-    ORIGINAL buffers via ``dynamic_index_in_dim`` (no xs packing), and
-    re-measuring in the production-shaped program showed the fori form
-    itself had become the step's dominant linear term — 6.0 ms of a
-    14.9 ms step at 1.35B/32 slots (~0.2 ms per slot, ~1500x the bytes
-    actually written) against ~3.8 ms for this scatter, with the no-op
-    commit at 8.9 ms as the floor.  In-process A/B of both spellings
-    plus a vmapped-DUS variant: scatter 12.68 / fori 14.92 / vmap 28.7
-    ms/step at 32 slots."""
-    b = buf.shape[1]
-    rows = jnp.arange(b)
-    # Advanced indices at axes 1 and 3 broadcast to (B,) and move to the
-    # front: the updates tensor is [B, L, NKV, ...].
-    v = jnp.moveaxis(vals, 1, 0).astype(buf.dtype)
-    return buf.at[:, rows, :, lengths].set(
-        v, mode="drop", unique_indices=True
+    ``buf`` is ``[L, B, T, NKV, ...]``, ``vals`` ``[L, B, NKV, ...]``; row
+    ``b`` writes at position ``lengths[b]``, and a row parked at capacity
+    (``lengths[b] == T``) must be DROPPED, never clamped onto its last
+    real position: one batched scatter with drop semantics.  A position
+    is a whole ``[NKV, ...]`` plane of the buffer, so the donated buffer
+    is updated where it lies — no relayout of the cache around the write
+    (tests/test_tpu_compile.py::test_ragged_programs_leave_the_cache_in_place)."""
+    rows = jnp.arange(buf.shape[1])
+    return buf.at[:, rows, lengths].set(
+        vals.astype(buf.dtype), mode="drop", unique_indices=True
     )
 
 
@@ -1779,16 +1646,14 @@ def insert_sequence(
     slot = jnp.asarray(slot, jnp.int32)
     z = jnp.zeros((), jnp.int32)
     lengths = cache.lengths.at[slot].set(jnp.asarray(length, jnp.int32))
-    # prefill's KVCache is position-major [L, 1, Tp, NKV, D]; the ragged
-    # cache is head-major [L, B, NKV, T, D] — one transpose per insert
-    # (prefill-rate, not decode-rate, so the copy is off the hot path).
-    seq_k = jnp.swapaxes(seq.k, 2, 3)
-    seq_v = jnp.swapaxes(seq.v, 2, 3)
+    # prefill's KVCache [L, 1, Tp, NKV, D] is the ragged cache's layout
+    # with one row: the sequence lands as it stands.
+    at = (z, slot, z, z, z)
     if isinstance(cache, QuantRaggedKVCache):
-        k8, ks = _quant_kv(seq_k)
-        v8, vs = _quant_kv(seq_v)
+        k8, ks = _quant_kv(seq.k)
+        v8, vs = _quant_kv(seq.v)
         ins = lambda buf, vals: lax.dynamic_update_slice(
-            buf, vals.astype(buf.dtype), (z, slot, z, z, z)
+            buf, vals.astype(buf.dtype), at
         )
         return QuantRaggedKVCache(
             ins(cache.k8, k8),
@@ -1797,12 +1662,8 @@ def insert_sequence(
             ins(cache.v_scale, vs),
             lengths,
         )
-    k = lax.dynamic_update_slice(
-        cache.k, seq_k.astype(cache.k.dtype), (z, slot, z, z, z)
-    )
-    v = lax.dynamic_update_slice(
-        cache.v, seq_v.astype(cache.v.dtype), (z, slot, z, z, z)
-    )
+    k = lax.dynamic_update_slice(cache.k, seq.k.astype(cache.k.dtype), at)
+    v = lax.dynamic_update_slice(cache.v, seq.v.astype(cache.v.dtype), at)
     return RaggedKVCache(k, v, lengths)
 
 

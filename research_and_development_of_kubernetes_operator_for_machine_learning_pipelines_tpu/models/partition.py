@@ -74,15 +74,15 @@ LLAMA_PARTITION_RULES: tuple[tuple[str, PartitionSpec], ...] = (
     (r"(attn_norm|mlp_norm|final_norm)$", P()),
 )
 
-# Engine device state outside the param tree.  The ragged cache is
-# head-major [L, B, NKV, T, D]; the prefill scratch is position-major
-# [L, B, T, NKV, D]; the int8kv scale planes share their buffer's rank.
+# Engine device state outside the param tree.  The ragged cache and the
+# prefill scratch are both [L, B, T, NKV, D] (kv heads on axis 3); the
+# int8kv scale planes share their buffer's rank.
 # Under dp > 1 the ragged cache ALSO shards its row (batch) axis — see
 # ``ragged_kv_spec`` — so each dp shard holds B/dp cache rows and the
 # decode forward partitions on batch with replicated weights.
-RAGGED_KV_SPEC = P(None, None, TP, None, None)
-RAGGED_KV_SPEC_DP = P(None, DP, TP, None, None)
 SEQ_KV_SPEC = P(None, None, None, TP, None)
+RAGGED_KV_SPEC = SEQ_KV_SPEC
+RAGGED_KV_SPEC_DP = P(None, DP, None, TP, None)
 REPLICATED = P()
 
 

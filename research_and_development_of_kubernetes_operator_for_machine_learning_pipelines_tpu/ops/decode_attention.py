@@ -24,10 +24,11 @@ with dequantize-then-attend up to f32 summation order.
 Layouts (B slots, W window, NKV kv heads, G = heads/kv_head, D head_dim):
 
   q       [B, NKV, G, D]   current token's queries, grouped by kv head
-  k8, v8  [B, NKV, W, D]   int8 cache window (head-major cache layout —
-                           one (slot, head)'s window is contiguous)
-  ks, vs  [B, NKV, W, 1]   f32 scales (the cache's window slice as-is;
-                           the trailing 1 keeps the block tile-legal)
+  k8, v8  [B, NKV, W, D]   int8 cache window, one (slot, head)'s window
+                           contiguous (the caller transposes the
+                           cache's [B, W, NKV, D] slab)
+  ks, vs  [B, NKV, W, 1]   f32 scales, transposed likewise (the trailing
+                           1 keeps the block tile-legal)
   k_self  [B, NKV, 1, D]   current token's K/V (exact, never quantized)
   v_self  [B, NKV, 1, D]
   mask    [B, 1, W]        f32 additive bias (0 keep / large negative

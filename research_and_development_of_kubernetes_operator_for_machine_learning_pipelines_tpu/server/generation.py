@@ -1129,28 +1129,24 @@ class GenerationEngine:
             # Packed-mode prefix-cache hit: copy one cached chunk's K/V
             # straight into the reserved cache row at its absolute
             # offset (the scratch-path _seed_chunk, retargeted at a slot
-            # of the ragged cache).  ck/cv arrive position-major
-            # [L, 1, C, NKV, D] — the radix cache's storage layout, so
+            # of the ragged cache).  ck/cv arrive [L, 1, C, NKV, D] — the
+            # radix cache's storage layout and the ragged cache's own, so
             # entries stay interchangeable between modes.
-            z = jnp.int32(0)
-            ckh = jnp.swapaxes(ck, 2, 3)  # -> head-major [L,1,NKV,C,D]
-            cvh = jnp.swapaxes(cv, 2, 3)
+            at = (jnp.int32(0), slot, start, jnp.int32(0), jnp.int32(0))
             if self._kv_quant:
                 from ..models.llama import _quant_kv
 
-                k8, ksc = _quant_kv(ckh.astype(dtype))
-                v8, vsc = _quant_kv(cvh.astype(dtype))
+                k8, ksc = _quant_kv(ck.astype(dtype))
+                v8, vsc = _quant_kv(cv.astype(dtype))
                 kb, ks = k
                 vb, vs = v
-                at = (z, slot, z, start, z)
                 return (
                     (lax_dus(kb, k8, at), lax_dus(ks, ksc, at)),
                     (lax_dus(vb, v8, at), lax_dus(vs, vsc, at)),
                 )
-            at = (z, slot, z, start, z)
             return (
-                lax_dus(k, ckh.astype(k.dtype), at),
-                lax_dus(v, cvh.astype(v.dtype), at),
+                lax_dus(k, ck.astype(k.dtype), at),
+                lax_dus(v, cv.astype(v.dtype), at),
             )
 
         self._seed_slot = jit_sharded(
@@ -1160,31 +1156,26 @@ class GenerationEngine:
 
         def _read_chunk_slot(k, v, slot, start):
             # Packed-mode prefix-cache write-back: pull one freshly
-            # prefilled chunk's K/V off the reserved cache row, returned
-            # position-major (the radix cache's storage layout).  An
+            # prefilled chunk's K/V off the reserved cache row, in the
+            # radix cache's storage layout (the cache's own).  An
             # int8kv cache dequantizes on the way out — lossless round
             # trip: re-quantizing q8*scale reproduces q8 and scale
             # exactly (the per-head max is preserved).
             C = self._prefill_chunk_size
             z = jnp.int32(0)
-            at = (z, slot, z, start, z)
 
-            def pull(buf, width):
-                size = (buf.shape[0], 1, buf.shape[2], C, width)
-                return lax_ds(buf, at, size)
+            def pull(buf):
+                nl, _b, _t, nkv, width = buf.shape
+                return lax_ds(buf, (z, slot, start, z, z), (nl, 1, C, nkv, width))
 
             if self._kv_quant:
                 kb, ks = k
                 vb, vs = v
-                ck = pull(kb, kb.shape[4]).astype(dtype) * pull(ks, 1)
-                cv = pull(vb, vb.shape[4]).astype(dtype) * pull(vs, 1)
+                ck = pull(kb).astype(dtype) * pull(ks)
+                cv = pull(vb).astype(dtype) * pull(vs)
             else:
-                ck = pull(k, k.shape[4])
-                cv = pull(v, v.shape[4])
-            return (
-                jnp.swapaxes(ck, 2, 3).astype(dtype),
-                jnp.swapaxes(cv, 2, 3).astype(dtype),
-            )
+                ck, cv = pull(k), pull(v)
+            return ck.astype(dtype), cv.astype(dtype)
 
         self._read_slot = jit_sharded(
             _read_chunk_slot, out_shardings=(rep, rep) if rep else None

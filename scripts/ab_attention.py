@@ -1,9 +1,8 @@
 """In-process A/B of decode attention variants (xla einsum chain vs the
 fused Pallas kernel, ops/decode_attention.py) at serving geometry.
 
-Interleaved in one process for the same reason as ab_decode.py: timings
-drift between processes, so only A/B/A/B comparisons in one session are
-valid.  Reports each variant's
+Interleaved in one process: timings drift between processes, so only
+A/B/A/B comparisons in one session are valid.  Reports each variant's
 MIN over rounds.
 
 Usage: ``python scripts/ab_attention.py [--slots 8,16,32] [--rounds 2]``

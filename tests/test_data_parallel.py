@@ -302,7 +302,7 @@ def test_dp_tp_composed_mesh_parity(tiny):
             engine.generate(p, n, timeout=300).tolist() for p, n in prompts
         ]
         spec = engine._cache_k.sharding.spec
-        assert spec[1] == "dp" and spec[2] == "tp"
+        assert spec[1] == "dp" and spec[3] == "tp"
     finally:
         engine.shutdown()
     assert outs == [_ref(params, cfg, p, n) for p, n in prompts]

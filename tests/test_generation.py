@@ -49,13 +49,7 @@ def _ref(params, cfg, prompt, n):
 
 
 def _fresh_cache(cfg, batch):
-    # Head-major ragged layout: [L, B, NKV, T, D] (models/llama.py).
-    shape = (cfg.num_layers, batch, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
-    return llama.RaggedKVCache(
-        jnp.zeros(shape, jnp.float64),
-        jnp.zeros(shape, jnp.float64),
-        jnp.zeros((batch,), jnp.int32),
-    )
+    return llama.RaggedKVCache.create(cfg, batch, jnp.float64)
 
 
 def _admit(params, cfg, cache, toks, prompt, slot):

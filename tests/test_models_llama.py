@@ -117,6 +117,88 @@ def test_cache_is_static_shape():
     _, cache2 = llama.forward(params, ids, cache, TINY)
     assert cache2.k.shape == cache.k.shape  # capacity never changes
     assert int(cache2.length) == 4
+    # The ragged cache is the same layout with per-row lengths; its
+    # capacity is asked of the type, never read off a shape index.
+    ragged = llama.RaggedKVCache.create(TINY, batch=2)
+    assert ragged.k.shape == ragged.v.shape == cache.k.shape
+    quant = llama.QuantRaggedKVCache.create(TINY, batch=2)
+    assert quant.k8.shape == cache.k.shape
+    assert quant.k_scale.shape == cache.k.shape[:-1] + (1,)
+    assert ragged.capacity == quant.capacity == TINY.max_seq
+
+
+@pytest.mark.parametrize("kv", ["plain", "int8kv"])
+def test_ragged_cache_equals_plain_cache_position_for_position(kv):
+    """insert + N ragged decode steps leave slot ``b`` holding what the
+    plain single-sequence ``KVCache`` path holds, position for position
+    (teacher-forced, two slots at different lengths and a parked one).
+    ``int8kv`` stores what ``_quant_kv`` makes of those rows: after
+    layer 0 its K/V sit within the quantisation error of the plain
+    path's, because each layer attends the quantised cache below it."""
+    params = llama.init(jax.random.key(0), TINY)
+    prompts = {0: [5, 9, 2, 77, 31], 2: [8, 1, 4]}
+    forced = jax.random.randint(jax.random.key(3), (6, 3), 1, TINY.vocab_size)
+    steps = forced.shape[0]
+
+    # Plain path: one KVCache a sequence, prefill then decode_step.
+    plain = {}
+    for slot, prompt in prompts.items():
+        _, seq = llama.prefill(
+            params, jnp.asarray([prompt], jnp.int32), TINY, dtype=jnp.float32
+        )
+        for t in range(steps):
+            _, seq = llama.decode_step(
+                params, forced[t, slot][None, None], seq, TINY, dtype=jnp.float32
+            )
+        plain[slot] = seq
+
+    # Ragged path: padded prefill, insert, batched decode with slot 1 idle.
+    if kv == "int8kv":
+        cache = llama.QuantRaggedKVCache.create(TINY, 3)
+    else:
+        cache = llama.RaggedKVCache.create(TINY, 3, jnp.float32)
+    for slot, prompt in prompts.items():
+        ids = np.zeros((1, 8), np.int32)
+        ids[0, : len(prompt)] = prompt
+        _, seq = llama.prefill(params, jnp.asarray(ids), TINY, dtype=jnp.float32)
+        cache = llama.insert_sequence(
+            cache, seq, jnp.int32(slot), jnp.int32(len(prompt))
+        )
+    active = jnp.asarray([True, False, True])
+    step = jax.jit(
+        lambda toks, cache: llama.decode_ragged(
+            params, toks, cache, TINY, active=active, dtype=jnp.float32,
+            window=16,
+        )
+    )
+    for t in range(steps):
+        _, cache = step(forced[t][:, None], cache)
+
+    assert np.asarray(cache.lengths).tolist() == [5 + steps, 0, 3 + steps]
+    if kv == "int8kv":
+        got_k = cache.k8.astype(jnp.float32) * cache.k_scale
+        got_v = cache.v8.astype(jnp.float32) * cache.v_scale
+        tol = dict(atol=0.02, rtol=0.05)
+    else:
+        got_k, got_v = cache.k, cache.v
+        tol = dict(atol=2e-5, rtol=2e-5)
+    for slot, seq in plain.items():
+        n = int(seq.length)
+        assert n == len(prompts[slot]) + steps
+        for got, want in ((got_k, seq.k), (got_v, seq.v)):
+            np.testing.assert_allclose(
+                np.asarray(got[:, slot, :n]), np.asarray(want[:, 0, :n]), **tol
+            )
+            if kv == "int8kv":
+                # Layer 0 reads no cache: exactly the quantised plain rows.
+                q8, scale = llama._quant_kv(want[0, 0, :n])
+                np.testing.assert_allclose(
+                    np.asarray(got[0, slot, :n]),
+                    np.asarray(q8.astype(jnp.float32) * scale),
+                    atol=1e-5, rtol=1e-5,
+                )
+    # The parked slot was never written.
+    assert not np.asarray(got_k[:, 1]).any() and not np.asarray(got_v[:, 1]).any()
 
 
 @pytest.mark.slow
@@ -167,12 +249,14 @@ def test_commit_rows_drops_write_at_capacity():
     from tpumlops.models.llama import _commit_rows
 
     L, B, H, T, D = 2, 3, 2, 4, 3
-    buf = jnp.zeros((L, B, H, T, D), jnp.float32)
+    buf = jnp.zeros((L, B, T, H, D), jnp.float32)
     vals = jnp.ones((L, B, H, D), jnp.float32)
     lengths = jnp.array([1, T, 3], jnp.int32)  # row 1 is AT capacity
     out = jax.jit(_commit_rows)(buf, vals, lengths)
-    np.testing.assert_array_equal(np.asarray(out[:, 0, :, 1]), 1.0)
-    np.testing.assert_array_equal(np.asarray(out[:, 2, :, 3]), 1.0)
+    np.testing.assert_array_equal(np.asarray(out[:, 0, 1]), 1.0)
+    np.testing.assert_array_equal(np.asarray(out[:, 2, 3]), 1.0)
+    # ... and nowhere else in the rows that did write.
+    assert float(out[:, 0].sum()) == float(out[:, 2].sum()) == L * H * D
     # Row 1: untouched everywhere, including the last position a clamped
     # start would have overwritten.
     np.testing.assert_array_equal(np.asarray(out[:, 1]), 0.0)

@@ -177,14 +177,8 @@ def test_decode_multistep_matches_sequential_steps(tiny):
     from tpumlops.models import llama
 
     params, cfg = tiny
-    shape = (cfg.num_layers, 2, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
-
     def fresh():
-        return llama.RaggedKVCache(
-            jnp.zeros(shape, jnp.float64),
-            jnp.zeros(shape, jnp.float64),
-            jnp.zeros((2,), jnp.int32),
-        )
+        return llama.RaggedKVCache.create(cfg, 2, jnp.float64)
 
     prompt = [5, 9, 2]
     ids = np.zeros((1, 16), np.int32)
@@ -236,8 +230,8 @@ def test_decode_multistep_matches_sequential_steps(tiny):
     # Lengths advanced by exactly the valid counts; inactive row frozen.
     assert np.asarray(cache2.lengths).tolist() == [L + K, 0]
     np.testing.assert_allclose(
-        np.asarray(cache.k[:, 0, :, : L + K]),
-        np.asarray(cache2.k[:, 0, :, : L + K]),
+        np.asarray(cache.k[:, 0, : L + K]),
+        np.asarray(cache2.k[:, 0, : L + K]),
         rtol=1e-5, atol=1e-6,
     )
     assert bool(np.asarray(act2)[0]) and not bool(np.asarray(act2)[1])
@@ -257,13 +251,8 @@ def test_decode_multistep_eos_latch_freezes_row(tiny):
     prompt = [5, 9, 2]
     ref = _ref(params, cfg, prompt, 8)
     eos = ref[3]  # the 4th generated token: mid-scan for K=8
-    shape = (cfg.num_layers, 2, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
     cache = llama.insert_sequence(
-        llama.RaggedKVCache(
-            jnp.zeros(shape, jnp.float64),
-            jnp.zeros(shape, jnp.float64),
-            jnp.zeros((2,), jnp.int32),
-        ),
+        llama.RaggedKVCache.create(cfg, 2, jnp.float64),
         llama.prefill(
             params,
             jnp.asarray(

@@ -73,12 +73,7 @@ def test_prefill_chunks_ragged_matches_fused_forward_logits(tiny):
         )
         refs.append(np.asarray(logits)[0])  # [L, vocab]
 
-    shape = (cfg.num_layers, 2, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
-    cache = llama.RaggedKVCache(
-        jnp.zeros(shape, jnp.float64),
-        jnp.zeros(shape, jnp.float64),
-        jnp.zeros((2,), jnp.int32),
-    )
+    cache = llama.RaggedKVCache.create(cfg, 2, jnp.float64)
     got = {0: [], 1: []}
     for chunk_idx in range(2):
         ids = np.stack(
@@ -110,7 +105,7 @@ def test_prefill_chunks_ragged_parked_rows_write_nothing(tiny):
     """A pad row (offset == capacity) must leave the cache bit-identical
     — that is what lets a packed call pad up to a power-of-two bucket."""
     params, cfg = tiny
-    shape = (cfg.num_layers, 2, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim)
+    shape = llama.RaggedKVCache.create(cfg, 2).k.shape
     k0 = jax.random.normal(jax.random.key(1), shape, jnp.float64)
     v0 = jax.random.normal(jax.random.key(2), shape, jnp.float64)
     cache = llama.RaggedKVCache(k0, v0, jnp.zeros((2,), jnp.int32))

@@ -243,16 +243,8 @@ def test_verify_forward_matches_sequential_decode(tiny):
     from tpumlops.models import llama
 
     params, cfg = tiny
-    shape = (
-        cfg.num_layers, 2, cfg.num_kv_heads, cfg.max_seq, cfg.head_dim
-    )
-
     def fresh():
-        return llama.RaggedKVCache(
-            jnp.zeros(shape, jnp.float64),
-            jnp.zeros(shape, jnp.float64),
-            jnp.zeros((2,), jnp.int32),
-        )
+        return llama.RaggedKVCache.create(cfg, 2, jnp.float64)
 
     prompt = [5, 9, 2]
     ids = np.zeros((1, 16), np.int32)
@@ -304,8 +296,8 @@ def test_verify_forward_matches_sequential_decode(tiny):
     # truncation leaves these bytes as the only live state).
     L = len(prompt)
     np.testing.assert_allclose(
-        np.asarray(cache.k[:, 0, :, : L + 4]),
-        np.asarray(cache2.k[:, 0, :, : L + 4]),
+        np.asarray(cache.k[:, 0, : L + 4]),
+        np.asarray(cache2.k[:, 0, : L + 4]),
         rtol=1e-5, atol=1e-6,
     )
     # verify_ragged leaves lengths for the CALLER to advance.

@@ -218,7 +218,7 @@ def test_greedy_parity_with_slot_churn(tiny, tp):
             counts[degree] = dict(engine.dispatches_total)
             if degree > 1:
                 assert engine._cache_k.sharding.spec == P(
-                    None, None, "tp", None, None
+                    None, None, None, "tp", None
                 )
                 assert engine._lengths.sharding.spec == P()
         finally:
@@ -312,8 +312,8 @@ def test_int8kv_cache_parity_tp2(tiny):
                 from jax.sharding import PartitionSpec as P
 
                 k8, kscale = engine._cache_k
-                assert k8.sharding.spec == P(None, None, "tp", None, None)
-                assert kscale.sharding.spec == P(None, None, "tp", None, None)
+                assert k8.sharding.spec == P(None, None, None, "tp", None)
+                assert kscale.sharding.spec == P(None, None, None, "tp", None)
         finally:
             engine.shutdown()
     assert outs[2] == outs[1]

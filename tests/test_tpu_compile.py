@@ -15,6 +15,8 @@ so exactly one xdist worker loads the TPU library.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -249,6 +251,161 @@ def test_engine_programs_fit_one_chip_at_smoke_geometry(one_chip, program):
 
     compiled = jax.jit(fn, donate_argnums=(2,)).lower(params, *args).compile()
     assert _resident_bytes(compiled) < HBM
+
+
+# The benchmark's two configurations (benchmarks/configs/*.json) and a
+# decode window below each capacity, as the engine's buckets give.
+_CELL_GEOMETRY = {
+    "mistral": (
+        dict(vocab_size=32768, hidden_size=4096, num_layers=32, num_heads=32,
+             num_kv_heads=8, intermediate_size=14336, max_seq=2048,
+             rope_theta=1e6),
+        1024,
+    ),
+    "deepseek": (
+        dict(vocab_size=102400, hidden_size=4096, num_layers=30, num_heads=32,
+             num_kv_heads=32, intermediate_size=11008, max_seq=1024),
+        768,
+    ),
+}
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\("
+)
+
+
+def _array_instructions(hlo_text):
+    """(name, dims, opcode) of every array-typed instruction."""
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            name, dims, opcode = m.groups()
+            yield name, [int(d) for d in dims.split(",") if d], opcode
+
+
+def _ragged_program(program, cfg, window, slots, one_chip):
+    """``(fn, args)``: the engine's jit bodies over the ragged cache
+    (server/generation.py), ``k``/``v`` at argument positions 2 and 3."""
+    from tpumlops.models.sampling import sample_logits, split_keys
+
+    s = functools.partial(_sds, one_chip)
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+    vec = s((slots,), i32)
+    toks, active = s((slots, 1), i32), s((slots,), b)
+    chunk, steps = 128, 4
+
+    def greedy(logits, carry):
+        return carry, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def cache_of(k, v, lengths):
+        return llama.RaggedKVCache(k, v, lengths)
+
+    if program == "decode_greedy":
+        def fn(params, toks, k, v, lengths, active):
+            logits, c = llama.decode_ragged(
+                params, toks, cache_of(k, v, lengths), cfg, active=active,
+                window=window,
+            )
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(i32)
+            return jnp.where(active, nxt, toks[:, 0])[:, None], c.k, c.v, c.lengths
+        args = (toks, active)
+    elif program == "decode_sampling":
+        def fn(params, toks, k, v, lengths, active, keys, temps, tks, tps):
+            logits, c = llama.decode_ragged(
+                params, toks, cache_of(k, v, lengths), cfg, active=active,
+                window=window,
+            )
+            keys2, use = split_keys(keys)
+            nxt = sample_logits(logits[:, -1, :], use, temps, tks, tps)
+            toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
+            return toks2, c.k, c.v, c.lengths, keys2
+        keys = _on(one_chip, jax.eval_shape(
+            lambda: jax.random.split(jax.random.key(0), slots)
+        ))
+        args = (toks, active, keys, s((slots,), f32), vec, s((slots,), f32))
+    elif program == "multistep":
+        def fn(params, toks, k, v, lengths, active, remaining, eos):
+            out = llama.decode_multistep(
+                params, toks, cache_of(k, v, lengths), cfg, active,
+                remaining, eos, steps, greedy, window=window,
+            )
+            c = out[3]
+            return out[:3] + (c.k, c.v, c.lengths) + out[4:6]
+        args = (toks, active, vec, vec)
+    elif program == "verify":
+        def fn(params, toks, k, v, lengths, active):
+            logits, c = llama.verify_ragged(
+                params, toks, cache_of(k, v, lengths), cfg, window=window,
+                active=active,
+            )
+            return jnp.argmax(logits, axis=-1), c.k, c.v
+        args = (s((slots, 5), i32), active)
+    elif program == "packed_prefill":
+        def fn(params, toks, k, v, lengths, rows, offsets):
+            logits, c = llama.prefill_chunks_ragged(
+                params, toks, cache_of(k, v, lengths), rows, offsets, cfg
+            )
+            return jnp.argmax(logits, axis=-1), c.k, c.v
+        args = (s((4, chunk), i32), s((4,), i32), s((4,), i32))
+    else:
+        def fn(params, block, k, v, lengths, roles, offsets, counts, draft,
+               active, remaining, eos):
+            out = llama.super_step_ragged(
+                params, block, cache_of(k, v, lengths), cfg, roles=roles,
+                offsets=offsets, counts=counts, draft_len=draft,
+                active=active, remaining=remaining, eos_ids=eos, steps=steps,
+                sample_fn=greedy, window=window,
+            )
+            c = out[6]
+            return out[1:6] + (c.k, c.v, c.lengths) + out[7:9]
+        args = (s((slots, chunk), i32), vec, vec, vec, vec, active, vec, vec)
+    return fn, args
+
+
+@pytest.mark.parametrize("geometry", sorted(_CELL_GEOMETRY))
+@pytest.mark.parametrize(
+    "program",
+    ["decode_greedy", "decode_sampling", "multistep", "verify",
+     "packed_prefill", "superstep"],
+)
+def test_ragged_programs_leave_the_cache_in_place(one_chip, geometry, program):
+    """The donated ``[L, B, T, NKV, D]`` buffers are the layout the
+    programs compute in: the chip's compiler aliases both through every
+    ragged program with no cache-shaped ``copy``/``transpose`` (with the
+    cache head-major it put four around every step, half the step's
+    device time), and the layer loop reads a window-sized slab, not a
+    capacity-sized one.  The two chunk programs keep only the copy and
+    alias rules: packed prefill attends the whole capacity by contract,
+    and both carry ``[B, chunk, ...]`` float32 temporaries past 64 MiB."""
+    kw, window = _CELL_GEOMETRY[geometry]
+    cfg = llama.LlamaConfig(**kw)
+    slots = 8
+    windowed = program not in ("packed_prefill", "superstep")
+    params = _on(one_chip, _int8_params(cfg))
+    cache = _bf16_cache(cfg, slots, one_chip)
+    fn, args = _ragged_program(program, cfg, window, slots, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(
+        params, args[0], cache.k, cache.v, cache.lengths, *args[1:]
+    ).compile()
+
+    buffer_elements = math.prod(cache.k.shape)
+    buffer_bytes = buffer_elements * cache.k.dtype.itemsize
+    slab_elements = buffer_elements // cfg.num_layers
+    for name, dims, opcode in _array_instructions(compiled.as_text()):
+        n = math.prod(dims)
+        relayout = opcode in ("copy", "copy-start", "transpose") or (
+            opcode == "fusion" and ("copy" in name or "transpose" in name)
+        )
+        assert not (relayout and n == buffer_elements), (
+            f"{name}: a whole-cache {opcode} of {dims}"
+        )
+        if windowed:
+            assert not (
+                cfg.max_seq in dims and slab_elements <= n < buffer_elements
+            ), f"{name}: a capacity-sized slab {dims} from {opcode}"
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * buffer_bytes, "k/v donation not credited"
+    assert m.temp_size_in_bytes < (64 * 2**20 if windowed else buffer_bytes)
 
 
 def test_batch_generate_program_does_not_reserve_a_full_capacity_cache(one_chip):
