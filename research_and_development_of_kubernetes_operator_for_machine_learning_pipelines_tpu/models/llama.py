@@ -33,6 +33,13 @@ from .common import rms_norm
 from .quantization import dequantize_tensor, is_quantized
 
 
+# What the serving engine reads off a causal-LM family's module besides its
+# programs (``models/mla_moe.py`` declares the same names).
+FLAVOR = "llama-generate"  # registry / artifact name of this family
+PAD_ID = 0  # the id a short prompt chunk is padded with
+UNSUPPORTED: dict[str, str] = {}  # serving mechanisms these programs lack
+
+
 # Decode attention dispatch: "xla" (einsum chain), "pallas" (fused
 # ops/decode_attention kernels), "pallas_single" (one program per
 # (slot, head)), or "auto".  "auto" resolves to XLA: measured on a v5e

@@ -28,9 +28,11 @@ class Predictor:
     jittable: bool = True
     example_input: Callable[[int], Any] | None = None  # batch_size -> inputs
     metadata: dict = field(default_factory=dict)
-    # Causal-LM handles ({"params", "cfg", "eos_id"?}) for flavors that
-    # support autoregressive decoding: the server builds a continuous-
-    # batching GenerationEngine from these and exposes /generate.
+    # Causal-LM handles ({"params", "cfg", "eos_id"?, "family"?}) for
+    # flavors that support autoregressive decoding: the server builds a
+    # continuous-batching GenerationEngine from these and exposes
+    # /generate.  ``family`` is the module the engine reaches the model
+    # through (cache tuples, forward, decode_ragged...); absent: llama.
     causal_lm: dict | None = None
     # Declarative sequence bucketing (server/batching.apply_seq_pad):
     # collapses variable request lengths into power-of-two buckets so the
@@ -268,6 +270,33 @@ def _build_resnet(params: Any, cfg: Any = None, image_size: int = 224, **_kw) ->
     )
 
 
+def _causal_lm_predictor(
+    module, name: str, params: Any, cfg: Any, max_new_tokens: int,
+    eos_id: int | None, **handle,
+) -> Predictor:
+    """A causal-LM family's Predictor: ``module.generate_greedy`` behind
+    the batch predict path, and the handles the generation engine takes
+    (``handle``: what the family adds to them)."""
+    # The batch predict path pairs a fixed example prompt length with a
+    # fixed generation budget; both must fit the KV-cache capacity.
+    example_len = min(16, cfg.max_seq // 4)
+    max_new_tokens = min(max_new_tokens, cfg.max_seq - example_len)
+
+    def apply(params, prompt_ids):
+        return module.generate_greedy(params, prompt_ids, max_new_tokens, cfg)
+
+    return Predictor(
+        name=name,
+        predict=functools.partial(apply, params),
+        params=params,
+        apply=apply,
+        jittable=True,
+        example_input=lambda b: np.ones((b, example_len), np.int32),
+        metadata={"max_new_tokens": max_new_tokens, "max_seq": cfg.max_seq},
+        causal_lm={"params": params, "cfg": cfg, "eos_id": eos_id, **handle},
+    )
+
+
 @register("llama-generate")
 def _build_llama(
     params: Any,
@@ -278,21 +307,22 @@ def _build_llama(
 ) -> Predictor:
     from . import llama
 
-    # The batch predict path pairs a fixed example prompt length with a
-    # fixed generation budget; both must fit the KV-cache capacity.
-    example_len = min(16, cfg.max_seq // 4)
-    max_new_tokens = min(max_new_tokens, cfg.max_seq - example_len)
+    return _causal_lm_predictor(
+        llama, "llama-generate", params, cfg, max_new_tokens, eos_id
+    )
 
-    def apply(params, prompt_ids):
-        return llama.generate_greedy(params, prompt_ids, max_new_tokens, cfg)
 
-    return Predictor(
-        name="llama-generate",
-        predict=functools.partial(apply, params),
-        params=params,
-        apply=apply,
-        jittable=True,
-        example_input=lambda b: np.ones((b, example_len), np.int32),
-        metadata={"max_new_tokens": max_new_tokens, "max_seq": cfg.max_seq},
-        causal_lm={"params": params, "cfg": cfg, "eos_id": eos_id},
+@register("mla-moe-generate")
+def _build_mla_moe(
+    params: Any,
+    cfg: Any,
+    max_new_tokens: int = 64,
+    eos_id: int | None = None,
+    **_kw,
+) -> Predictor:
+    from . import mla_moe
+
+    return _causal_lm_predictor(
+        mla_moe, mla_moe.FLAVOR, params, cfg, max_new_tokens, eos_id,
+        family=mla_moe,
     )

@@ -1864,8 +1864,15 @@ def make_gen_engine(
     bit-identical slot counts / dtype / kv_quant on every host, so the
     shared knobs must never be spelled twice.
     """
+    from ..utils.config import validate_serving_for_family
     from .generation import GenerationEngine
 
+    family = predictor.causal_lm.get("family")
+    if family is not None:
+        # The engine refuses its own knobs; the fleet role is the server's.
+        validate_serving_for_family(
+            family.FLAVOR, family.UNSUPPORTED, fleet_role=config.fleet_role
+        )
     ts = timeseries  # per-second ring: fans onto the metric callbacks
 
     prefix_cache = None
@@ -1927,6 +1934,8 @@ def make_gen_engine(
         unified_step=config.tpu.unified_step,
         on_dispatch=metrics.inc_dispatch if metrics else None,
         on_prefill_tokens=metrics.inc_prefill_tokens if metrics else None,
+        family=family,
+        on_moe=metrics.inc_moe if metrics else None,
         tracer=metrics.tracer if metrics else None,
         # Packed multi-admission prefill: same batch geometry on leader
         # and followers (this one construction site) — the compiled B_p

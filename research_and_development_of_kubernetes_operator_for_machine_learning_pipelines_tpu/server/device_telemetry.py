@@ -221,12 +221,17 @@ def weights_bytes_by_dtype(params, per_chip: bool = False) -> dict[str, int]:
 
 
 def kv_cache_bytes_per_row(
-    cfg, kv_quant: bool, dtype_bytes: int = 2, tp: int = 1
+    cfg, kv_quant: bool, dtype_bytes: int = 2, tp: int = 1, family=None
 ) -> int:
     """Bytes one cache row (slot at full ``max_seq``) holds: k + v across
     all layers, plus the int8kv layout's per-(pos, head) f32 scales.
     ``tp`` > 1 gives the PER-CHIP row (the heads axis is what shards, so
-    each chip holds num_kv_heads/tp of every row)."""
+    each chip holds num_kv_heads/tp of every row).  ``family``: the
+    causal-LM family's module where it is not the dense one whose
+    arithmetic this is (the predictor's ``causal_lm["family"]``); it then
+    answers for its own cache (``kv_row_bytes``)."""
+    if family is not None:
+        return family.kv_row_bytes(cfg, dtype_bytes)
     heads = cfg.num_kv_heads // max(1, int(tp))
     elems = cfg.num_layers * heads * cfg.max_seq * cfg.head_dim
     if kv_quant:
@@ -312,13 +317,23 @@ def build_hbm_ledger(
     prefix_cache_budget_bytes: int = 0,
     tp: int = 1,
     dp: int = 1,
+    family=None,
 ) -> HbmLedger:
     dp = max(1, int(dp))
     ledger = HbmLedger(
-        kv_bytes_per_row=kv_cache_bytes_per_row(cfg, kv_quant, dtype_bytes),
+        kv_bytes_per_row=kv_cache_bytes_per_row(
+            cfg, kv_quant, dtype_bytes, family=family
+        ),
         max_slots=int(max_slots),
         chips=max(1, int(tp)) * dp,
     )
+    if family is not None:
+        # Sparse experts: the routed experts (all resident, a few read a
+        # token) apart from the weights every token streams.
+        ledger.components["weights_routed_experts"] = sum(
+            weights_bytes_by_dtype(family.routed_expert_leaves(params)).values()
+        )
+        params = _without_routed_experts(params)
     for dtype, nbytes in weights_bytes_by_dtype(params).items():
         ledger.components[f"weights_{dtype}"] = nbytes
     ledger.components["kv_cache"] = ledger.kv_bytes_per_row * int(max_slots)
@@ -346,8 +361,14 @@ def build_hbm_ledger(
     return ledger
 
 
+def _without_routed_experts(params) -> dict:
+    layers = [{k: v for k, v in lp.items() if k != "experts"}
+              for lp in params["layers"]]
+    return {**params, "layers": layers}
+
+
 def capacity_log_line(params, cfg, kv_quant: bool,
-                      peaks: DevicePeaks | None = None) -> str:
+                      peaks: DevicePeaks | None = None, family=None) -> str:
     """The model-capacity startup line ``server/loader.py`` stamps (even
     with telemetry off): weights by dtype, KV bytes/row, max cache rows.
     HBM covers the device set the params are sharded over.  On a device
@@ -356,7 +377,7 @@ def capacity_log_line(params, cfg, kv_quant: bool,
     n_chips = param_device_count(params)
     by_dtype = weights_bytes_by_dtype(params)
     total = sum(by_dtype.values())
-    per_row = kv_cache_bytes_per_row(cfg, kv_quant)
+    per_row = kv_cache_bytes_per_row(cfg, kv_quant, family=family)
     try:
         peaks = (peaks or detect_peaks()).scaled(n_chips)
     except UnknownDeviceKind as e:
@@ -387,11 +408,15 @@ def capacity_log_line(params, cfg, kv_quant: bool,
             f", per-chip weights {chip_w / 2**20:.1f} MiB "
             f"kv {chip_row} B/row"
         )
+    sparse = ""
+    if family is not None:
+        active, held = family.param_counts(cfg)
+        sparse = f", params active {active} of {held}"
     return (
         f"model capacity: weights {total / 2**20:.1f} MiB ({dtypes}), "
         f"kv {per_row} B/row (max_seq {cfg.max_seq}"
         f"{', int8kv' if kv_quant else ''}), "
-        f"{capacity}{per_chip}"
+        f"{capacity}{per_chip}{sparse}"
     )
 
 
@@ -761,7 +786,9 @@ class DeviceTelemetry:
         self.observatory = CompileObservatory(readiness_budget_s)
         self.observatory.install()
         self.ledger: HbmLedger | None = None
-        self.cost: LlamaCostModel | None = None
+        self.cost = None  # LlamaCostModel, or the family's own cost model
+        # {"active", "total"} where the family tells them apart.
+        self.param_counts: dict | None = None
         self._metrics = None
         # Last computed utilization per tick kind (the /debug/device
         # mirror of the gauges).  Written by the engine scheduler
@@ -787,7 +814,7 @@ class DeviceTelemetry:
     def attach_model(self, params, cfg, max_slots: int,
                      kv_quant: bool = False, dtype_bytes: int = 2,
                      prefix_cache_budget_bytes: int = 0,
-                     mesh_shape=None) -> None:
+                     mesh_shape=None, family=None) -> None:
         """Build the ledger + cost model once the engine geometry is
         known; exports the per-component HBM gauges.  Peaks scale to the
         device set actually holding the params (the cost model and
@@ -809,12 +836,17 @@ class DeviceTelemetry:
             params, cfg, max_slots, kv_quant=kv_quant,
             dtype_bytes=dtype_bytes,
             prefix_cache_budget_bytes=prefix_cache_budget_bytes,
-            tp=tp, dp=dp,
+            tp=tp, dp=dp, family=family,
         )
-        self.cost = LlamaCostModel.for_model(
-            params, cfg, kv_quant=kv_quant, dtype_bytes=dtype_bytes,
-            mesh_shape=mesh_shape,
-        )
+        if family is not None:
+            self.cost = family.cost_model(params, cfg, dtype_bytes)
+            active, total = family.param_counts(cfg)
+            self.param_counts = {"active": active, "total": total}
+        else:
+            self.cost = LlamaCostModel.for_model(
+                params, cfg, kv_quant=kv_quant, dtype_bytes=dtype_bytes,
+                mesh_shape=mesh_shape,
+            )
         if self._metrics is not None:
             for comp, nbytes in self.ledger.components.items():
                 self._metrics.observe_hbm_component(comp, nbytes)
@@ -883,7 +915,7 @@ class DeviceTelemetry:
         """The ``GET /debug/device`` payload."""
         with self._util_lock:
             utilization = {k: dict(v) for k, v in self.last_util.items()}
-        return {
+        out = {
             "peaks": {
                 "device": self.peaks.kind,
                 "source": self.peaks.source,
@@ -896,3 +928,7 @@ class DeviceTelemetry:
             "utilization": utilization,
             "compile": self.observatory.snapshot(),
         }
+        if self.param_counts is not None:
+            # What a token multiplies through against what the chip holds.
+            out["params"] = dict(self.param_counts)
+        return out

@@ -397,12 +397,43 @@ class GenerationEngine:
         on_preempt: Callable[[str], None] | None = None,  # "evict"|"restore"
         on_prefill_tokens: Callable[[int], None] | None = None,
         tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
+        family=None,  # the causal-LM family's module (None: models.llama)
+        on_moe: Callable[[str, int, int], None] | None = None,
     ):
         import jax
         import jax.numpy as jnp
 
         from ..models import llama
+        from ..utils.config import validate_serving_for_family
 
+        # The model is reached through its family's module (the
+        # predictor's ``causal_lm["family"]``): cache tuples, ``forward``,
+        # ``prefill``, ``decode_ragged``, ``insert_sequence`` under llama's
+        # names.  What a family's programs lack is refused HERE, typed and
+        # naming the mechanism, before any device state exists.
+        lm = llama if family is None else family
+        self._lm = lm
+        validate_serving_for_family(
+            lm.FLAVOR,
+            lm.UNSUPPORTED,
+            quantize="int8kv" if kv_quant else "none",
+            mesh_shape=mesh_shape,
+            multihost=channel is not None,
+            speculative=speculative is not None and speculative.enabled,
+            prefix_cache=prefix_cache is not None and prefix_cache.enabled,
+            prefill_batch=prefill_batch,
+            decode_steps=decode_steps,
+            unified_step=unified_step,
+            preemption=preemption,
+        )
+        # A family with routed experts pads a prompt chunk with an id it
+        # does not route, and its programs return, behind llama's outputs,
+        # the int32 count of experts that got a real token.
+        # ``_moe_pending`` holds (program, real tokens, device scalar)
+        # until a read-back the loop makes anyway.
+        self._pad_id = lm.PAD_ID
+        self._on_moe = on_moe
+        self._moe_pending: list = []
         self._params = params
         self._cfg = cfg
         self._eos_default = eos_id
@@ -770,7 +801,7 @@ class GenerationEngine:
             """k/v are arrays (bf16 cache) or (values, scales) pairs."""
             if self._kv_quant:
                 return llama.QuantRaggedKVCache(k[0], k[1], v[0], v[1], lengths)
-            return llama.RaggedKVCache(k, v, lengths)
+            return lm.RaggedKVCache(k, v, lengths)
 
         def cache_repr(cache):
             if self._kv_quant:
@@ -783,7 +814,7 @@ class GenerationEngine:
             from ..models.sampling import sample_logits, split_keys
 
             cache = make_cache(k, v, lengths)
-            logits, cache = llama.decode_ragged(
+            logits, cache, *aux = lm.decode_ragged(
                 params, toks, cache, cfg, active=active, dtype=dtype,
                 window=window,
             )
@@ -794,7 +825,7 @@ class GenerationEngine:
                 # inert.
                 toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
             ck, cv = cache_repr(cache)
-            return toks2, ck, cv, cache.lengths, keys2
+            return (toks2, ck, cv, cache.lengths, keys2, *aux)
 
         # ``window`` is static: one compiled program per power-of-two bucket
         # of the longest active sequence (short traffic stops paying
@@ -808,7 +839,7 @@ class GenerationEngine:
             # Hot path when every occupied slot is greedy (the default):
             # plain argmax — no full-vocab sort/softmax/categorical work.
             cache = make_cache(k, v, lengths)
-            logits, cache = llama.decode_ragged(
+            logits, cache, *aux = lm.decode_ragged(
                 params, toks, cache, cfg, active=active, dtype=dtype,
                 window=window,
             )
@@ -816,7 +847,7 @@ class GenerationEngine:
                 nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
                 toks2 = jnp.where(active, nxt, toks[:, 0])[:, None]
             ck, cv = cache_repr(cache)
-            return toks2, ck, cv, cache.lengths
+            return (toks2, ck, cv, cache.lengths, *aux)
 
         self._decode_greedy = jit_sharded(
             _decode_greedy, donate_argnums=(2, 3), static_argnums=(6,),
@@ -939,8 +970,8 @@ class GenerationEngine:
         ):
             from ..models.sampling import sample_logits
 
-            logits, seq = llama.prefill(params, ids, cfg, dtype=dtype)
-            cache = llama.insert_sequence(
+            logits, seq, *aux = lm.prefill(params, ids, cfg, dtype=dtype)
+            cache = lm.insert_sequence(
                 make_cache(k, v, lengths), seq, slot, actual_len
             )
             # Install the slot's sampling state, then draw the first token
@@ -958,7 +989,7 @@ class GenerationEngine:
             ck, cv = cache_repr(cache)
             return (
                 ck, cv, cache.lengths, toks2,
-                keys2, temps2, tks2, tps2, first,
+                keys2, temps2, tks2, tps2, first, *aux,
             )
 
         # One compiled program per prompt bucket (jit caches by ids shape).
@@ -971,9 +1002,9 @@ class GenerationEngine:
         )
 
         def _prefill_one_chunk(params, ids, sk, sv, slen):
-            seq = llama.KVCache(sk, sv, slen)
-            logits, seq = llama.forward(params, ids, seq, cfg, dtype=dtype)
-            return logits[0], seq.k, seq.v, seq.length
+            seq = lm.KVCache(sk, sv, slen)
+            logits, seq, *aux = lm.forward(params, ids, seq, cfg, dtype=dtype)
+            return (logits[0], seq.k, seq.v, seq.length, *aux)
 
         self._prefill_one_chunk = jit_sharded(
             _prefill_one_chunk, donate_argnums=(2, 3),
@@ -1022,8 +1053,8 @@ class GenerationEngine:
         ):
             from ..models.sampling import sample_logits
 
-            seq = llama.KVCache(sk, sv, jnp.zeros((), jnp.int32))
-            cache = llama.insert_sequence(
+            seq = lm.KVCache(sk, sv, jnp.zeros((), jnp.int32))
+            cache = lm.insert_sequence(
                 make_cache(k, v, lengths), seq, slot, actual_len
             )
             carry, use = jax.random.split(slot_key)
@@ -1335,6 +1366,7 @@ class GenerationEngine:
                 dtype_bytes=jnp.dtype(dtype).itemsize,
                 prefix_cache_budget_bytes=prefix_budget,
                 mesh_shape=mesh_shape,
+                family=family,
             )
 
         self._slots: list[_Slot | None] = [None] * self.max_slots
@@ -1440,7 +1472,7 @@ class GenerationEngine:
             self._cache_k = (cache.k8, cache.k_scale)
             self._cache_v = (cache.v8, cache.v_scale)
         else:
-            cache = llama.RaggedKVCache.create(
+            cache = self._lm.RaggedKVCache.create(
                 self._cfg, self.max_slots, self._dtype
             )
             self._cache_k, self._cache_v = cache.k, cache.v
@@ -1473,6 +1505,7 @@ class GenerationEngine:
         self._ms_active = None
         self._ms_remaining = None
         self._ms_eos = None
+        self._moe_pending = []  # device scalars of the programs just lost
 
     def _put_seq(self, buf):
         """Commit a fresh batch-1 prefill scratch buffer to the seq-cache
@@ -2565,7 +2598,7 @@ class GenerationEngine:
         assert slot_idx is not None
         L = int(req.prompt.size)
         bucket = prefill_bucket(L, self.capacity)
-        ids = np.zeros((1, bucket), np.int32)
+        ids = np.full((1, bucket), self._pad_id, np.int32)
         ids[0, :L] = req.prompt
 
         # Engine-assigned keys are distinct per request and disjoint from
@@ -2718,8 +2751,29 @@ class GenerationEngine:
         self._note_ttft(req)
         with self._span("engine.prefill_sync"):
             token = int(first)
+            self._read_experts()
         with self._span("engine.emit"):
             self._record_token(slot_idx, token)
+
+    def _note_experts(self, program: str, tokens: int, aux) -> None:
+        """A routed family's program call: ``tokens`` real tokens went
+        through it and ``aux`` holds its on-device count of experts that
+        got one.  Kept as a device value until :meth:`_read_experts`."""
+        if aux and self._on_moe is not None and not self._in_warmup:
+            self._moe_pending.append((program, tokens, aux[0]))
+
+    def _read_experts(self) -> None:
+        """Hand the pending expert counts to ``on_moe(program,
+        assignments, activations)``.  Called where the loop has just read
+        a later program's result back, so every count here is already
+        computed: no synchronisation of its own."""
+        if not self._moe_pending:
+            return
+        pending, self._moe_pending = self._moe_pending, []
+        for program, tokens, hit in pending:
+            self._on_moe(
+                program, self._lm.routed_assignments(self._cfg, tokens), int(hit)
+            )
 
     def _note_prefill_tokens(self, n: int) -> None:
         """``n`` real prompt tokens' K/V written by a prefill dispatch."""
@@ -2844,6 +2898,7 @@ class GenerationEngine:
             self._topk,
             self._topp,
             first,
+            *aux,
         ) = self._prefill_insert(
             self._params,
             jnp.asarray(ids),
@@ -2862,6 +2917,7 @@ class GenerationEngine:
             jnp.int32(tk),
             jnp.float32(tp),
         )
+        self._note_experts("prefill", int(L), aux)
         return first
 
     def replay_admit(self, ids, slot, length, key_data, temp, tk, tp) -> None:
@@ -2881,7 +2937,7 @@ class GenerationEngine:
         C = self._prefill_chunk_size
         L = int(prompt.size)
         n = -(-L // C)
-        padded = np.zeros((n * C,), np.int32)
+        padded = np.full((n * C,), self._pad_id, np.int32)
         padded[:L] = prompt
         return [padded[i * C : (i + 1) * C][None, :] for i in range(n)]
 
@@ -3064,17 +3120,16 @@ class GenerationEngine:
     def _device_chunk(self, ids: np.ndarray, fresh: bool) -> None:
         import jax.numpy as jnp
 
-        from ..models import llama
-
         if fresh:
-            seq = llama.KVCache.create(self._cfg, 1, self._dtype)
+            seq = self._lm.KVCache.create(self._cfg, 1, self._dtype)
             sk0, sv0 = self._put_seq(seq.k), self._put_seq(seq.v)
             self._seq_state = (None, sk0, sv0, seq.length)
         _, sk, sv, slen = self._seq_state
-        logits0, sk, sv, slen = self._prefill_one_chunk(
+        logits0, sk, sv, slen, *aux = self._prefill_one_chunk(
             self._params, jnp.asarray(ids), sk, sv, slen
         )
         self._seq_state = (logits0, sk, sv, slen)
+        self._note_experts("prefill", int((ids >= 0).sum()), aux)
 
     def replay_chunk(self, ids, fresh) -> None:
         self._device_chunk(np.asarray(ids), bool(fresh))
@@ -3108,9 +3163,7 @@ class GenerationEngine:
     def _device_seed(self, cached_kv: list, length: int) -> None:
         import jax.numpy as jnp
 
-        from ..models import llama
-
-        seq = llama.KVCache.create(self._cfg, 1, self._dtype)
+        seq = self._lm.KVCache.create(self._cfg, 1, self._dtype)
         sk, sv = self._put_seq(seq.k), self._put_seq(seq.v)
         C = self._prefill_chunk_size
         off = 0
@@ -3935,6 +3988,7 @@ class GenerationEngine:
             self._dispatch_step(active_np, window, sampling)
         with span("engine.decode_readback"):
             toks = np.asarray(self._tokens)[:, 0]
+            self._read_experts()
         with span("engine.journal"):
             self._note_tick(
                 active_np, t0, tokens=int(active_np.sum()),
@@ -4734,6 +4788,7 @@ class GenerationEngine:
                 self._cache_v,
                 self._lengths,
                 self._keys,
+                *aux,
             ) = self._decode(
                 self._params,
                 self._tokens,
@@ -4753,6 +4808,7 @@ class GenerationEngine:
                 self._cache_k,
                 self._cache_v,
                 self._lengths,
+                *aux,
             ) = self._decode_greedy(
                 self._params,
                 self._tokens,
@@ -4762,6 +4818,7 @@ class GenerationEngine:
                 jnp.asarray(active_np),
                 window,
             )
+        self._note_experts("decode", int(np.sum(active_np)), aux)
 
     def _loop(self) -> None:
         span = self._span

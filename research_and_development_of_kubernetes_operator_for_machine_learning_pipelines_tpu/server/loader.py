@@ -183,17 +183,22 @@ _CONFIG_CLASSES = {
     "bert-classifier": ("bert", "BertConfig"),
     "resnet-classifier": ("resnet", "ResNetConfig"),
     "llama-generate": ("llama", "LlamaConfig"),
+    "mla-moe-generate": ("mla_moe", "MlaMoeConfig"),
 }
+
+
+def _model_module(flavor: str):
+    import importlib
+
+    return importlib.import_module(
+        f"..models.{_CONFIG_CLASSES[flavor][0]}", __package__
+    )
 
 
 def _build_config(flavor: str, config_dict: dict) -> Any:
     if flavor not in _CONFIG_CLASSES:
         return None
-    mod_name, cls_name = _CONFIG_CLASSES[flavor]
-    import importlib
-
-    mod = importlib.import_module(f"..models.{mod_name}", __package__)
-    cls = getattr(mod, cls_name)
+    cls = getattr(_model_module(flavor), _CONFIG_CLASSES[flavor][1])
     known = {f for f in cls.__dataclass_fields__}
     return cls(**{k: v for k, v in config_dict.items() if k in known})
 
@@ -350,7 +355,8 @@ def _log_capacity(
         from .device_telemetry import capacity_log_line
 
         line = capacity_log_line(
-            lm["params"], lm["cfg"], kv_quant=quantize == "int8kv"
+            lm["params"], lm["cfg"], kv_quant=quantize == "int8kv",
+            family=lm.get("family"),
         )
         if load_stats:
             line += " load_breakdown_s=" + json.dumps(
@@ -899,6 +905,16 @@ def _load_predictor_impl(
     if (path / "params.npz").exists():
         if not flavor:
             raise ModelLoadError(f"{path} has params.npz but no flavor recorded")
+        from ..utils.config import validate_serving_for_family
+
+        # Before a byte streams: what a causal-LM family's module says it
+        # cannot do (``UNSUPPORTED``; no other module declares one) is
+        # refused typed, not discovered as a shape error after the load.
+        if flavor in _CONFIG_CLASSES:
+            validate_serving_for_family(
+                flavor, getattr(_model_module(flavor), "UNSUPPORTED", {}),
+                quantize=quantize, mesh_shape=mesh_shape,
+            )
         n_devices = 1
         for v in (mesh_shape or {}).values():
             n_devices *= int(v)

@@ -187,6 +187,23 @@ class ServerMetrics:
             ident_labels,
             registry=self.registry,
         )
+        # Routed-expert traffic of a sparse-expert family, by program
+        # (prefill | decode): assignments / activations is the mean
+        # number of tokens an expert that was read got to work on.
+        self.moe_assignments = Counter(
+            "tpumlops_moe_assignments_total",
+            "(token, expert) pairs routed: real tokens x experts per "
+            "token x expert layers",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.moe_expert_activations = Counter(
+            "tpumlops_moe_expert_activations_total",
+            "(program call, layer, expert) triples in which the expert "
+            "got at least one real token, counted on the device",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
         # The engine thread stamps each streamed token as it hands it to
         # the event loop; the SSE writer observes now - stamp after the
         # event's write returns.  With the engine.* spans' maxima this
@@ -655,6 +672,14 @@ class ServerMetrics:
 
     def inc_prefill_tokens(self, n: int):
         self.prefill_tokens.labels(**self.identity).inc(n)
+
+    def inc_moe(self, program: str, assignments: int, activations: int):
+        self.moe_assignments.labels(
+            **self.identity, program=program
+        ).inc(assignments)
+        self.moe_expert_activations.labels(
+            **self.identity, program=program
+        ).inc(activations)
 
     def observe_emit_lag(self, seconds: float):
         self.emit_lag.labels(**self.identity).observe(seconds)

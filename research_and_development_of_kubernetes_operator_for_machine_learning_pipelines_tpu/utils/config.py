@@ -1284,6 +1284,70 @@ def validate_mesh_for_model(
             )
 
 
+class UnsupportedForFamily(ValueError):
+    """A serving knob asks for a mechanism the model family's programs do
+    not implement.  Raised at load / engine construction, never replaced
+    by a silent fallback to another program."""
+
+    def __init__(self, family: str, mechanism: str, knob: str):
+        super().__init__(
+            f"model family {family!r} does not implement {mechanism} "
+            f"({knob}); unset it for this model"
+        )
+        self.family = family
+        self.mechanism = mechanism
+
+
+def validate_serving_for_family(
+    family: str,
+    lacks: Mapping[str, str],
+    *,
+    quantize: str = "none",
+    mesh_shape: Mapping[str, int] | None = None,
+    multihost: bool = False,
+    speculative: bool = False,
+    prefix_cache: bool = False,
+    prefill_batch: int | None = 1,
+    decode_steps: int | None = 1,
+    unified_step: bool = False,
+    preemption: bool = False,
+    fleet_role: str | None = None,
+) -> None:
+    """Reject, typed and naming the mechanism, every ``spec.tpu`` knob that
+    asks a causal-LM family for a program it does not have.  ``family``
+    and ``lacks`` are the family module's ``FLAVOR`` and ``UNSUPPORTED``
+    (mechanism key -> its wording): the module says what it lacks, this
+    maps the knobs onto those keys.  Called by the loader (quantize,
+    mesh: before gigabytes stream), the server (fleet role) and the
+    generation engine (its own knobs); the operator cannot know the
+    flavor at reconcile.  Arguments left at their defaults are not
+    checked."""
+    if not lacks:
+        return
+    devices = 1
+    for n in dict(mesh_shape or {}).values():
+        devices *= int(n)
+    asked = (
+        ("quantize", quantize not in (None, "none"),
+         f"spec.tpu.quantize={quantize!r}"),
+        ("mesh", devices > 1 or multihost,
+         f"spec.tpu.meshShape={dict(mesh_shape or {})}"),
+        ("speculative", speculative, "spec.tpu.speculative.enabled"),
+        ("prefix_cache", prefix_cache or preemption,
+         "spec.tpu.prefixCache.enabled / spec.tpu.preemption"),
+        ("prefill_batch", int(prefill_batch or 1) > 1,
+         f"spec.tpu.prefillBatch={prefill_batch}"),
+        ("decode_steps", int(decode_steps or 1) > 1,
+         f"spec.tpu.decodeSteps={decode_steps}"),
+        ("unified_step", bool(unified_step), "spec.tpu.unifiedStep"),
+        ("kv_transfer", fleet_role not in (None, "", "unified"),
+         f"fleet role {fleet_role!r}"),
+    )
+    for mechanism, wanted, knob in asked:
+        if wanted and mechanism in lacks:
+            raise UnsupportedForFamily(family, lacks[mechanism], knob)
+
+
 def _parse_quantize(value) -> str:
     """Reject bad quantize values at reconcile time — a typo'd CR field must
     surface in status, not as a pod CrashLoopBackOff at argparse."""

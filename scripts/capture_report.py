@@ -37,7 +37,10 @@ ROOT = "engine.iteration"
 STEP = "engine.decode_readback"
 NOT_HOST = ("engine.wait_work", "engine.decode_readback", "engine.prefill_sync")
 SCOPES = ("embed", "layer.attn_qkv", "layer.attn_core", "layer.attn_out",
-          "layer.mlp", "kv_commit", "head", "sample")
+          "layer.mlp", "kv_commit", "head", "sample",
+          # models/mla_moe.py (PR 27)
+          "layer.mla_q", "layer.mla_kv", "layer.moe_router",
+          "layer.moe_experts", "layer.moe_shared")
 _SCOPE = re.compile("|".join(re.escape(s) for s in SCOPES))
 
 

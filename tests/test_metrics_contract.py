@@ -80,6 +80,10 @@ EXPECTED_SERVER = {
     "tpumlops_prefill_tokens": ("counter", _IDENT),
     # Engine on_token stamp -> the SSE event's write returning.
     "tpumlops_emit_lag_seconds": ("histogram", _IDENT),
+    # Routed-expert traffic of a sparse-expert family by program (prefill
+    # | decode): (token, expert) pairs, and experts that got a real token.
+    "tpumlops_moe_assignments": ("counter", _IDENT + ("program",)),
+    "tpumlops_moe_expert_activations": ("counter", _IDENT + ("program",)),
     # utils/tracing.py span stats, rendered at scrape time by one custom
     # collector (no per-span prometheus call); exported with _total.
     "tpumlops_span_seconds": ("counter", _IDENT + ("span",)),
