@@ -15,9 +15,11 @@ pair stays ``(k, v)``: ``k`` the RoPE key ``[L, B, T, 1, rope]``, ``v``
 the normalised latent ``[L, B, T, 1, kv_lora_rank]``, position-major),
 ``forward``, ``prefill``, ``decode_ragged``, ``insert_sequence``,
 ``generate_greedy``.  ``forward`` and ``decode_ragged`` return one value
-more than llama's: the int32 count of (layer, expert) pairs that got at
-least one real token, which the engine turns into
-``tpumlops_moe_expert_activations_total``.
+more than llama's: int32 ``[2]`` (the ``counts`` below), the (layer, expert)
+pairs that got at least one real token and the (layer, expert, row tile)
+visits the grouped matmuls made, which the engine turns into
+``tpumlops_moe_expert_activations_total`` and
+``tpumlops_moe_row_tile_visits_total``.
 
 Design decisions:
 
@@ -27,9 +29,17 @@ Design decisions:
   decode absorbs ``W_kvb`` into the query and the context (``q_nope W_uk``
   scores the latent itself, ``P c`` is expanded by ``W_uv`` after): the
   same mathematics, and a step reads 576 numbers a position, not 8192.
-- Experts are ``jax.lax.ragged_dot`` over token copies sorted by expert
-  (XLA lowers it to a grouped matmul that reads only the experts that
-  got tokens).  No capacity factor, no dropped token.
+- Experts are ``ops.grouped_matmul`` over token copies sorted by expert:
+  on the TPU a Pallas kernel whose row tile follows from the static
+  (token copies, experts) of the call, 128 rows at a 512-token chunk's
+  4096 copies over 256 experts and 16 at a decode step's 64, which
+  visits only the (expert, row tile) pairs that share a row and reads
+  each expert's matrix once; off it ``jax.lax.ragged_dot``.  XLA's own
+  lowering of ``ragged_dot`` tiles 512 rows at 4096 copies and walks
+  every group: a chunk multiplied 512-row tiles for the ~16 rows an
+  expert gets, 2.4 ms a matmul against a 0.98 ms stream (PERF.md §5,
+  PR 27), and a step walked 256 groups to reach ~57.  No capacity
+  factor, no dropped token.
 - Layers are a LIST of per-layer trees and the layer loop is unrolled,
   where llama stacks and scans: the grouped matmul is a custom call whose
   operand must be a whole buffer, so a dynamic slice of experts stacked
@@ -53,6 +63,7 @@ Design decisions:
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import NamedTuple
@@ -61,6 +72,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.grouped_matmul import grouped_matmul, row_tile, row_tile_schedule
 from .common import rms_norm
 from .llama import _attended_window, _commit_rows, _embed, _head, _layer_window, _qmatmul
 
@@ -198,6 +210,12 @@ def routed_assignments(cfg: MlaMoeConfig, tokens: int) -> int:
     """(token, expert) pairs ``tokens`` real tokens make in one forward
     pass: what ``tpumlops_moe_assignments_total`` counts."""
     return int(tokens) * cfg.num_experts_per_tok * cfg.num_moe_layers
+
+
+def moe_row_tile(cfg: MlaMoeConfig, tokens: int) -> int:
+    """Rows a visit of the grouped matmuls multiplies in a program call
+    over ``tokens`` token rows (padding included: the shape is static)."""
+    return row_tile(int(tokens) * cfg.num_experts_per_tok, cfg.n_routed_experts)
 
 
 def kv_row_bytes(cfg: MlaMoeConfig, dtype_bytes: int = 2) -> int:
@@ -546,11 +564,16 @@ def route(xn, router, bias, cfg):
     return idx, weights * cfg.routed_scaling_factor
 
 
+@functools.partial(jax.jit, static_argnums=3)
 def moe_ffn(xn, lp, valid, cfg):
     """The routed + shared expert FFN of normed tokens ``xn`` [N, H];
     ``valid`` bool [N] marks the real ones (padding is not routed and
     yields the shared experts' output alone, which nobody reads).
-    Returns ``(y [N, H] float32, experts_hit int32)``."""
+    Returns ``(y [N, H] float32, counts int32 [2])``: the experts that got
+    a real token and the row-tile visits of the grouped matmuls' schedule.
+    Jitted, so the expert layers of every serving program share one trace
+    and each program lowers the block once: a cached boot re-traces all
+    36 programs, and that, not XLA, is what its warm-up waits for."""
     n, h = xn.shape
     e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     with jax.named_scope("layer.moe_router"):
@@ -563,37 +586,37 @@ def moe_ffn(xn, lp, valid, cfg):
         sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
         xs = xn[order // k]
         ex = lp["experts"]
+        # One schedule of (expert, row tile) visits for the three matmuls.
+        plan = row_tile_schedule(sizes, n * k, row_tile(n * k, e))
         act = jax.nn.silu(
-            lax.ragged_dot(xs, ex["gate"].astype(xn.dtype), sizes,
-                           preferred_element_type=jnp.float32)
-        ) * lax.ragged_dot(xs, ex["up"].astype(xn.dtype), sizes,
-                           preferred_element_type=jnp.float32)
-        ys = lax.ragged_dot(act.astype(xn.dtype), ex["down"].astype(xn.dtype),
-                            sizes, preferred_element_type=jnp.float32)
+            grouped_matmul(xs, ex["gate"].astype(xn.dtype), sizes, plan)
+        ) * grouped_matmul(xs, ex["up"].astype(xn.dtype), sizes, plan)
+        ys = grouped_matmul(act.astype(xn.dtype), ex["down"].astype(xn.dtype),
+                            sizes, plan)
         # Rows behind the last group are whatever the grouped matmul left.
         ys = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None], ys, 0.0)
         routed = jnp.einsum(
             "nkh,nk->nh", ys[jnp.argsort(order)].reshape(n, k, h), weights
         )
-        hit = jnp.sum(sizes > 0).astype(jnp.int32)
+        counts = jnp.stack([jnp.sum(sizes > 0).astype(jnp.int32), plan.visits])
     with jax.named_scope("layer.moe_shared"):
         shared = _swiglu(xn, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
-    return routed + shared, hit
+    return routed + shared, counts
 
 
 def _ffn(x, lp, valid, cfg):
     """A layer's FFN with its residual: SwiGLU where the layer carries
     one, experts where it carries a router.  ``valid`` bool [B, S] marks
-    the real tokens.  Returns ``(x, experts_hit)``."""
+    the real tokens.  Returns ``(x, counts)`` (as ``moe_ffn``'s)."""
     b, s, h = x.shape
     if "router" not in lp:
         with jax.named_scope("layer.mlp"):
             xn = rms_norm(x, lp["ffn_norm"], cfg.rms_eps)
             y = _swiglu(xn, lp["gate"], lp["up"], lp["down"])
-            return x + y.astype(x.dtype), jnp.zeros((), jnp.int32)
+            return x + y.astype(x.dtype), jnp.zeros((2,), jnp.int32)
     xn = rms_norm(x, lp["ffn_norm"], cfg.rms_eps).reshape(b * s, h)
-    y, hit = moe_ffn(xn, lp, valid.reshape(b * s), cfg)
-    return x + y.reshape(b, s, h).astype(x.dtype), hit
+    y, counts = moe_ffn(xn, lp, valid.reshape(b * s), cfg)
+    return x + y.reshape(b, s, h).astype(x.dtype), counts
 
 
 # ---------------------------------------------------------------------------
@@ -610,7 +633,8 @@ def forward(
 ):
     """Run ``input_ids`` [B,S] through the model starting at
     ``cache.length``; ids < 0 are padding (embedded as id 0, not routed).
-    Returns ``(logits [B,S,vocab] float32, cache, experts_hit)``."""
+    Returns ``(logits [B,S,vocab] float32, cache, counts)`` (``counts``
+    int32 [2]: ``moe_ffn``'s, summed over layers)."""
     b, s = input_ids.shape
     if s > cfg.max_seq:
         raise ValueError(
@@ -627,7 +651,7 @@ def forward(
     mask_bias = jnp.where(visible, 0.0, -1e9).astype(jnp.float32)[None, None]
     z = jnp.zeros((), jnp.int32)
     ck, cv = cache.k, cache.v
-    hits = jnp.zeros((), jnp.int32)
+    counts = jnp.zeros((2,), jnp.int32)
     for l, lp in enumerate(params["layers"]):
         xn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q_nope, q_rope = _mla_q(xn, lp, cos, sin, cfg)
@@ -638,9 +662,9 @@ def forward(
             cv = lax.dynamic_update_slice(cv, c[None, :, :, None].astype(cv.dtype), at)
         ctx = _attn_expanded(q_nope, q_rope, ck[l, :, :, 0], cv[l, :, :, 0],
                              mask_bias, lp, cfg)
-        x, hit = _ffn(_attn_out(x, ctx, lp), lp, valid, cfg)
-        hits = hits + hit
-    return _head(params, x, cfg), KVCache(ck, cv, start + s), hits
+        x, layer_counts = _ffn(_attn_out(x, ctx, lp), lp, valid, cfg)
+        counts = counts + layer_counts
+    return _head(params, x, cfg), KVCache(ck, cv, start + s), counts
 
 
 def prefill(params, input_ids, cfg, dtype=jnp.bfloat16):
@@ -697,7 +721,8 @@ def decode_ragged(
     ``window``, the current position attended in flight, every layer's new
     row committed by one drop-scatter after the loop, inactive rows
     neither written nor advanced).  Inactive rows are not routed either.
-    Returns ``(logits [B,1,vocab] float32, cache, experts_hit)``."""
+    Returns ``(logits [B,1,vocab] float32, cache, counts)`` (as
+    ``forward``'s)."""
     b, s = token_ids.shape
     if s != 1:
         raise ValueError(f"decode_ragged is single-token: got chunk of {s}")
@@ -710,15 +735,15 @@ def decode_ragged(
     mask_bias = jnp.where(before, 0.0, -1e9).astype(jnp.float32)[:, None]
 
     k_news, v_news = [], []
-    hits = jnp.zeros((), jnp.int32)
+    counts = jnp.zeros((2,), jnp.int32)
     for l, lp in enumerate(params["layers"]):
         xn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q_nope, q_rope = _mla_q(xn, lp, cos, sin, cfg)
         kr, c = _mla_kv(xn, lp, cos, sin, cfg)
         ck, cv = cache.layer_window(l, window)
         ctx = _attn_absorbed(q_nope, q_rope, kr, c, ck, cv, mask_bias, lp, cfg)
-        x, hit = _ffn(_attn_out(x, ctx, lp), lp, live[:, None], cfg)
-        hits = hits + hit
+        x, layer_counts = _ffn(_attn_out(x, ctx, lp), lp, live[:, None], cfg)
+        counts = counts + layer_counts
         k_news.append(kr)
         v_news.append(c)
     k_news, v_news = jnp.stack(k_news), jnp.stack(v_news)  # [L, B, 1, *]
@@ -731,7 +756,7 @@ def decode_ragged(
             _commit_rows(cache.v, v_news, write_pos),
             lengths + live.astype(jnp.int32),
         ),
-        hits,
+        counts,
     )
 
 

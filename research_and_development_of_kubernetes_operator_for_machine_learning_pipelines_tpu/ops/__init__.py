@@ -10,6 +10,12 @@ data plane:
 - ``ring_attention``   — sequence parallelism over the ``sp`` mesh axis:
   KV blocks rotate around the ICI ring while each device keeps only its
   sequence shard (long-context serving).
+- ``grouped_matmul``   — the sparse-expert FFN's matmuls over token copies
+  sorted by expert: a row tile that follows from the call's static shapes,
+  only the (expert, row tile) pairs that share a row visited, each
+  expert's matrix read once (the one kernel on a family's default path;
+  import it from ``ops.grouped_matmul``, whose module also holds the tile
+  choice and the visit schedule).
 
 Every op has a pure-XLA reference implementation used as fallback off-TPU
 and as the numerical oracle in tests (kernels run in interpret mode on CPU).

@@ -398,7 +398,7 @@ class GenerationEngine:
         on_prefill_tokens: Callable[[int], None] | None = None,
         tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
         family=None,  # the causal-LM family's module (None: models.llama)
-        on_moe: Callable[[str, int, int], None] | None = None,
+        on_moe: Callable[[str, int, int, int, int], None] | None = None,
     ):
         import jax
         import jax.numpy as jnp
@@ -428,9 +428,10 @@ class GenerationEngine:
         )
         # A family with routed experts pads a prompt chunk with an id it
         # does not route, and its programs return, behind llama's outputs,
-        # the int32 count of experts that got a real token.
-        # ``_moe_pending`` holds (program, real tokens, device scalar)
-        # until a read-back the loop makes anyway.
+        # int32 [2]: the experts that got a real token and the row-tile
+        # visits of the grouped matmuls.
+        # ``_moe_pending`` holds (program, real tokens, token rows, device
+        # counts) until a read-back the loop makes anyway.
         self._pad_id = lm.PAD_ID
         self._on_moe = on_moe
         self._moe_pending: list = []
@@ -2755,24 +2756,28 @@ class GenerationEngine:
         with self._span("engine.emit"):
             self._record_token(slot_idx, token)
 
-    def _note_experts(self, program: str, tokens: int, aux) -> None:
-        """A routed family's program call: ``tokens`` real tokens went
-        through it and ``aux`` holds its on-device count of experts that
-        got one.  Kept as a device value until :meth:`_read_experts`."""
+    def _note_experts(self, program: str, tokens: int, rows: int, aux) -> None:
+        """A routed family's program call over ``rows`` token rows, of
+        which ``tokens`` were real; ``aux`` holds its on-device counts
+        (experts hit, row-tile visits).  Kept as a device value until
+        :meth:`_read_experts`."""
         if aux and self._on_moe is not None and not self._in_warmup:
-            self._moe_pending.append((program, tokens, aux[0]))
+            self._moe_pending.append((program, tokens, rows, aux[0]))
 
     def _read_experts(self) -> None:
         """Hand the pending expert counts to ``on_moe(program,
-        assignments, activations)``.  Called where the loop has just read
-        a later program's result back, so every count here is already
-        computed: no synchronisation of its own."""
+        assignments, activations, row_tile_visits, row_tile)``.  Called
+        where the loop has just read a later program's result back, so
+        every count here is already computed: no synchronisation of its
+        own."""
         if not self._moe_pending:
             return
         pending, self._moe_pending = self._moe_pending, []
-        for program, tokens, hit in pending:
+        for program, tokens, rows, counts in pending:
+            hit, visits = np.asarray(counts).tolist()
             self._on_moe(
-                program, self._lm.routed_assignments(self._cfg, tokens), int(hit)
+                program, self._lm.routed_assignments(self._cfg, tokens), hit,
+                visits, self._lm.moe_row_tile(self._cfg, rows),
             )
 
     def _note_prefill_tokens(self, n: int) -> None:
@@ -2917,7 +2922,7 @@ class GenerationEngine:
             jnp.int32(tk),
             jnp.float32(tp),
         )
-        self._note_experts("prefill", int(L), aux)
+        self._note_experts("prefill", int(L), ids.size, aux)
         return first
 
     def replay_admit(self, ids, slot, length, key_data, temp, tk, tp) -> None:
@@ -3129,7 +3134,7 @@ class GenerationEngine:
             self._params, jnp.asarray(ids), sk, sv, slen
         )
         self._seq_state = (logits0, sk, sv, slen)
-        self._note_experts("prefill", int((ids >= 0).sum()), aux)
+        self._note_experts("prefill", int((ids >= 0).sum()), ids.size, aux)
 
     def replay_chunk(self, ids, fresh) -> None:
         self._device_chunk(np.asarray(ids), bool(fresh))
@@ -4818,7 +4823,7 @@ class GenerationEngine:
                 jnp.asarray(active_np),
                 window,
             )
-        self._note_experts("decode", int(np.sum(active_np)), aux)
+        self._note_experts("decode", int(np.sum(active_np)), len(active_np), aux)
 
     def _loop(self) -> None:
         span = self._span

@@ -189,7 +189,9 @@ class ServerMetrics:
         )
         # Routed-expert traffic of a sparse-expert family, by program
         # (prefill | decode): assignments / activations is the mean
-        # number of tokens an expert that was read got to work on.
+        # number of tokens an expert that was read got to work on;
+        # assignments / (row_tile_visits x row_tile_rows) is how full the
+        # row tiles are that the grouped matmuls multiply.
         self.moe_assignments = Counter(
             "tpumlops_moe_assignments_total",
             "(token, expert) pairs routed: real tokens x experts per "
@@ -201,6 +203,21 @@ class ServerMetrics:
             "tpumlops_moe_expert_activations_total",
             "(program call, layer, expert) triples in which the expert "
             "got at least one real token, counted on the device",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.moe_row_tile_visits = Counter(
+            "tpumlops_moe_row_tile_visits_total",
+            "(program call, layer, expert, row tile) visits of the grouped "
+            "matmuls' schedule (the three matmuls of a layer share it), "
+            "counted on the device from the group sizes",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.moe_row_tile_rows = Gauge(
+            "tpumlops_moe_row_tile_rows",
+            "Rows a visit multiplies in the program's last call: static, "
+            "from the call's token copies and expert count",
             ident_labels + ["program"],
             registry=self.registry,
         )
@@ -673,13 +690,13 @@ class ServerMetrics:
     def inc_prefill_tokens(self, n: int):
         self.prefill_tokens.labels(**self.identity).inc(n)
 
-    def inc_moe(self, program: str, assignments: int, activations: int):
-        self.moe_assignments.labels(
-            **self.identity, program=program
-        ).inc(assignments)
-        self.moe_expert_activations.labels(
-            **self.identity, program=program
-        ).inc(activations)
+    def inc_moe(self, program: str, assignments: int, activations: int,
+                row_tile_visits: int, row_tile: int):
+        labels = dict(self.identity, program=program)
+        self.moe_assignments.labels(**labels).inc(assignments)
+        self.moe_expert_activations.labels(**labels).inc(activations)
+        self.moe_row_tile_visits.labels(**labels).inc(row_tile_visits)
+        self.moe_row_tile_rows.labels(**labels).set(row_tile)
 
     def observe_emit_lag(self, seconds: float):
         self.emit_lag.labels(**self.identity).observe(seconds)

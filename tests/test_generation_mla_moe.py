@@ -2,8 +2,8 @@
 the same scheduler, slot cache and chunked prefill as llama-generate,
 reached through the predictor's ``causal_lm["family"]`` handle; greedy
 tokens equal the plain reference's in float32; what the family's programs
-lack is refused typed; loader, HBM ledger, cost model and the two
-``tpumlops_moe_*`` counter families."""
+lack is refused typed; loader, HBM ledger, cost model and the
+``tpumlops_moe_*`` metric families."""
 
 import dataclasses
 import sys
@@ -97,10 +97,16 @@ def test_engine_tokens_equal_the_reference_as_requests_join_and_leave(
     # and warm-up counted nothing.
     fan = CFG.num_experts_per_tok * CFG.num_moe_layers
     by = {"prefill": [0, 0], "decode": [0, 0]}
-    for program, assignments, activations in seen:
+    for program, assignments, activations, visits, tile in seen:
         by[program][0] += assignments
         by[program][1] += activations
         assert 0 < activations <= min(assignments, CFG.num_moe_layers * CFG.n_routed_experts)
+        # An expert that got a token costs a visit, a tile boundary inside
+        # its rows one more; the tile is the call's static one.
+        assert activations <= visits <= assignments
+        assert tile in (16, 32, 64, 128)
+    assert {tile for program, *_, tile in seen if program == "decode"} == {
+        mla_moe.moe_row_tile(CFG, 3)}
     assert by["prefill"][0] == fan * sum(len(p) for p in prompts())
     # A request's first token comes from its prefill; the rest are steps.
     assert by["decode"][0] == fan * sum(n - 1 for n in news)
@@ -248,13 +254,65 @@ def test_moe_counter_families_on_the_registry():
     from tpumlops.server.metrics import ServerMetrics
 
     m = ServerMetrics(deployment_name="d", predictor_name="p", namespace="n")
-    m.inc_moe("prefill", 4096 * 4, 1024)
-    m.inc_moe("decode", 64 * 4, 228)
+    m.inc_moe("prefill", 4096 * 4, 1024, 1140, 128)
+    m.inc_moe("decode", 64 * 4, 228, 230, 16)
+    m.inc_moe("decode", 64 * 4, 226, 226, 16)
     text = generate_latest(m.registry).decode()
     for family, program, value in (
         ("tpumlops_moe_assignments_total", "prefill", 16384.0),
-        ("tpumlops_moe_expert_activations_total", "decode", 228.0),
+        ("tpumlops_moe_expert_activations_total", "decode", 454.0),
+        ("tpumlops_moe_row_tile_visits_total", "prefill", 1140.0),
+        ("tpumlops_moe_row_tile_visits_total", "decode", 456.0),
+        ("tpumlops_moe_row_tile_rows", "prefill", 128.0),
+        ("tpumlops_moe_row_tile_rows", "decode", 16.0),
     ):
         line = next(l for l in text.splitlines()
                     if l.startswith(family + "{") and f'program="{program}"' in l)
         assert float(line.rsplit(" ", 1)[1]) == value
+
+
+def test_row_tile_visits_on_metrics_after_a_generate_and_not_through_warm_up(tmp_path):
+    """A real server on a tiny bf16 artifact: the warm-up sweep (every
+    program, run) leaves no ``tpumlops_moe_*`` sample; one /generate puts
+    the visits of both programs on /metrics, between the experts that got
+    a token and the token copies, with the static tile beside them."""
+    import httpx
+
+    from tpumlops.clients.localplane import free_port, start_model_server
+    from tpumlops.server import loader
+    from tpumlops.utils.config import TpuSpec
+
+    loader.save_native_model(
+        tmp_path / "m", mla_moe.FLAVOR, mla_moe.init(jax.random.key(0), CFG, jnp.bfloat16),
+        config=dataclasses.asdict(CFG))
+    port = free_port()
+    handle = start_model_server(
+        str(tmp_path / "m"), "v1", port, model_name="m",
+        tpu=TpuSpec.from_spec({"meshShape": {"tp": 1}, "maxSlots": 2, "prefillChunk": 8}))
+
+    def moe_samples():
+        text = httpx.get(f"http://127.0.0.1:{port}/metrics", timeout=30).text
+        out = {}
+        for line in text.splitlines():
+            if line.startswith("tpumlops_moe_") and "_created" not in line:
+                name, labels = line.split("{", 1)
+                program = labels.split('program="', 1)[1].split('"', 1)[0]
+                out[name, program] = float(line.rsplit(" ", 1)[1])
+        return out
+
+    try:
+        assert moe_samples() == {}
+        r = httpx.post(
+            f"http://127.0.0.1:{port}/v2/models/m/generate",
+            json={"prompt_ids": list(range(1, 14)), "max_new_tokens": 5}, timeout=120)
+        assert r.status_code == 200, r.text
+        got = moe_samples()
+    finally:
+        handle.stop()
+    fan = CFG.num_experts_per_tok * CFG.num_moe_layers
+    for program, tokens, rows in (("prefill", 13, 8), ("decode", 4, 2)):
+        assert got["tpumlops_moe_assignments_total", program] == fan * tokens
+        hit = got["tpumlops_moe_expert_activations_total", program]
+        visits = got["tpumlops_moe_row_tile_visits_total", program]
+        assert 0 < hit <= visits <= fan * tokens
+        assert got["tpumlops_moe_row_tile_rows", program] == mla_moe.moe_row_tile(CFG, rows)

@@ -84,6 +84,11 @@ EXPECTED_SERVER = {
     # | decode): (token, expert) pairs, and experts that got a real token.
     "tpumlops_moe_assignments": ("counter", _IDENT + ("program",)),
     "tpumlops_moe_expert_activations": ("counter", _IDENT + ("program",)),
+    # (expert, row tile) visits of the grouped matmuls' schedule, and the
+    # static rows a visit multiplies: assignments / (visits x rows) is how
+    # full the tiles are.
+    "tpumlops_moe_row_tile_visits": ("counter", _IDENT + ("program",)),
+    "tpumlops_moe_row_tile_rows": ("gauge", _IDENT + ("program",)),
     # utils/tracing.py span stats, rendered at scrape time by one custom
     # collector (no per-span prometheus call); exported with _total.
     "tpumlops_span_seconds": ("counter", _IDENT + ("span",)),

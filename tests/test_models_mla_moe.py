@@ -4,6 +4,7 @@ seeded random weights at tiny widths: logits, never tokens, wherever the
 two can be compared position by position."""
 
 import dataclasses
+import functools
 import sys
 from pathlib import Path
 
@@ -87,11 +88,11 @@ def want(want_and_hit):
 
 def test_full_forward_logits_equal_the_reference(params, toks, want_and_hit):
     want, hit = want_and_hit
-    logits, cache, hits = mla_moe.prefill(params, jnp.asarray(toks), CFG, jnp.float32)
+    logits, cache, counts = mla_moe.prefill(params, jnp.asarray(toks), CFG, jnp.float32)
     np.testing.assert_allclose(np.asarray(logits), want, atol=2e-6)
     assert int(cache.length) == SEQ
     # The device's count of experts that got a token is the reference's.
-    assert 0 < int(hits) == hit <= CFG.num_moe_layers * CFG.n_routed_experts
+    assert 0 < int(counts[0]) == hit <= CFG.num_moe_layers * CFG.n_routed_experts
 
 
 def test_chunked_prefill_then_ragged_decode_equals_the_full_forward(params, toks, want):
@@ -115,12 +116,12 @@ def test_chunked_prefill_then_ragged_decode_equals_the_full_forward(params, toks
     for pos in range(prompt, SEQ):
         step = np.zeros((4, 1), np.int32)
         step[2, 0] = row[pos]
-        logits, cache, hits = mla_moe.decode_ragged(
+        logits, cache, counts = mla_moe.decode_ragged(
             params, jnp.asarray(step), cache, CFG, active=active,
             dtype=jnp.float32, window=32,
         )
         got.append(np.asarray(logits[2]))
-        assert 1 <= int(hits) <= CFG.num_moe_layers * CFG.num_experts_per_tok
+        assert 1 <= int(counts[0]) <= CFG.num_moe_layers * CFG.num_experts_per_tok
     np.testing.assert_allclose(np.concatenate(got), want[0], atol=5e-6)
     assert cache.lengths.tolist() == [0, 0, SEQ, 0]
 
@@ -164,25 +165,97 @@ def test_absorbed_decode_equals_expanded_attention(params, toks):
 
 def test_padding_rows_change_neither_output_nor_counters(params, toks):
     row = toks[0]
-    exact, _, hits_exact = mla_moe.prefill(
+    exact, _, counts_exact = mla_moe.prefill(
         params, jnp.asarray(row[None, :5]), CFG, jnp.float32)
     padded = np.full((1, 16), -1, np.int32)
     padded[0, :5] = row[:5]
-    got, _, hits_padded = mla_moe.prefill(params, jnp.asarray(padded), CFG, jnp.float32)
+    got, _, counts_padded = mla_moe.prefill(params, jnp.asarray(padded), CFG, jnp.float32)
     np.testing.assert_allclose(np.asarray(got[0, :5]), np.asarray(exact[0]), atol=2e-6)
-    assert int(hits_padded) == int(hits_exact) <= 5 * 2 * CFG.num_moe_layers
+    assert int(counts_padded[0]) == int(counts_exact[0]) <= 5 * 2 * CFG.num_moe_layers
     # Decode: an inactive slot's row is not routed and not written.
     cache = mla_moe.RaggedKVCache.create(CFG, 4, jnp.float32)
     one = jnp.asarray([True, False, False, False])
     toks4 = jnp.asarray([[7], [9], [11], [13]], jnp.int32)
-    _, cache1, hits1 = mla_moe.decode_ragged(
+    _, cache1, counts1 = mla_moe.decode_ragged(
         params, toks4, cache, CFG, active=one, dtype=jnp.float32)
-    assert int(hits1) <= CFG.num_experts_per_tok * CFG.num_moe_layers
+    assert int(counts1[0]) <= CFG.num_experts_per_tok * CFG.num_moe_layers
     assert cache1.lengths.tolist() == [1, 0, 0, 0]
     assert not np.asarray(cache1.v[:, 1:]).any()
-    _, _, hits4 = mla_moe.decode_ragged(
+    _, _, counts4 = mla_moe.decode_ragged(
         params, toks4, cache, CFG, active=None, dtype=jnp.float32)
-    assert int(hits4) > int(hits1)
+    assert int(counts4[0]) > int(counts1[0])
+
+
+@pytest.fixture
+def kernel_interpreted(monkeypatch):
+    """Steer ``moe_ffn``'s grouped matmuls to the Pallas kernel, in
+    interpret mode, where the CPU would take ``lax.ragged_dot``."""
+    from tpumlops.ops.grouped_matmul import grouped_matmul
+
+    monkeypatch.setattr(
+        mla_moe, "grouped_matmul", functools.partial(grouped_matmul, interpret=True))
+    # ``moe_ffn`` is jitted: a trace made without the patch must not answer
+    # for these shapes, nor one made with it for a later test's.
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def numpy_visits(sizes, tm):
+    """(group, row tile) pairs that share a row, from the group sizes."""
+    ends = np.cumsum(sizes)
+    return sum((e - 1) // tm - (e - n) // tm + 1 for e, n in zip(ends, sizes) if n)
+
+
+@pytest.mark.parametrize("rows,seq,real", [(3, 16, (16, 11, 0)), (1, 8, (5,)), (4, 1, (1, 0, 1, 1))])
+def test_expert_layer_through_the_kernel_equals_the_reference(
+        ref_mod, params, kernel_interpreted, rows, seq, real):
+    """One expert layer's FFN with its grouped matmuls in the kernel
+    against the reference's, padded rows included (a prompt chunk's
+    padded tail, an all-padding row, a decode step's idle slot); and the
+    program's counts against numpy counts from the same group sizes."""
+    from tpumlops.ops.grouped_matmul import row_tile
+
+    lp = params["layers"][CFG.num_dense_layers]
+    x = jax.random.normal(jax.random.key(rows * seq), (rows, seq, CFG.hidden_size))
+    valid = np.arange(seq)[None, :] < np.asarray(real)[:, None]
+    ffn = lambda x, v: mla_moe._ffn(x, lp, v, CFG)
+    assert "pallas_call" in str(jax.make_jaxpr(ffn)(x, jnp.asarray(valid)))
+    got, counts = jax.jit(ffn)(x, jnp.asarray(valid))
+    want = ref_mod.build(geometry(CFG), seq).moe_ffn(x, moe_weights(lp))
+    np.testing.assert_allclose(np.asarray(got)[valid], np.asarray(want)[valid], atol=2e-6)
+    assert np.isfinite(np.asarray(got)).all()
+    xn = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + CFG.rms_eps)
+    idx, _ = mla_moe.route(
+        xn.reshape(-1, CFG.hidden_size), lp["router"], lp["router_bias"], CFG)
+    sizes = np.bincount(
+        np.asarray(idx)[valid.reshape(-1)].reshape(-1), minlength=CFG.n_routed_experts)
+    copies = rows * seq * CFG.num_experts_per_tok
+    tm = row_tile(copies, CFG.n_routed_experts)
+    assert tm == mla_moe.moe_row_tile(CFG, rows * seq)
+    assert counts.tolist() == [np.count_nonzero(sizes), numpy_visits(sizes, tm)]
+
+
+def test_programs_through_the_kernel_equal_the_full_forward(
+        params, toks, want, kernel_interpreted):
+    """Prefill and a teacher-forced ragged decode step with every expert
+    matmul in the kernel: the reference's logits at the tolerances the
+    fallback meets."""
+    prompt = 13
+    logits, seq, counts = mla_moe.prefill(
+        params, jnp.asarray(toks[:1, :prompt]), CFG, jnp.float32)
+    np.testing.assert_allclose(np.asarray(logits[0]), want[0, :prompt], atol=2e-6)
+    assert 0 < int(counts[0]) <= int(counts[1])  # an expert hit costs a visit or more
+    cache = mla_moe.insert_sequence(
+        mla_moe.RaggedKVCache.create(CFG, 2, jnp.float32), seq, 1, prompt)
+    step = jnp.asarray([[0], [int(toks[0, prompt])]], jnp.int32)
+    logits, _, counts = mla_moe.decode_ragged(
+        params, step, cache, CFG, active=jnp.asarray([False, True]),
+        dtype=jnp.float32, window=32)
+    np.testing.assert_allclose(np.asarray(logits[1, 0]), want[0, prompt], atol=5e-6)
+    # One live token: top-2 experts a layer, each one visit of one tile.
+    fan = CFG.num_experts_per_tok * CFG.num_moe_layers
+    assert counts.tolist() == [fan, fan]
 
 
 def fake_int8(tree):

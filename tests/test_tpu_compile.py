@@ -481,7 +481,7 @@ def test_sparse_expert_programs_read_the_experts_where_they_lie(one_chip, progra
     """The latent-attention, sparse-expert family at the benchmark's
     published widths (5 of 40 layers, 8 slots x 2048, chunk 512): both
     serving programs fit the chip beside 11.1 GB of bf16 weights, the
-    donated cache aliases, every expert matmul is the grouped custom call,
+    donated cache aliases, every expert matmul is the grouped-matmul kernel,
     and NO expert tensor is copied — stacked over layers, a dynamic slice
     of [256, 2048, 768] was materialised for each custom-call operand,
     0.8 GB three times a layer every step; the per-layer tree avoids it."""
@@ -495,10 +495,10 @@ def test_sparse_expert_programs_read_the_experts_where_they_lie(one_chip, progra
             lambda: mla_moe.RaggedKVCache.create(cfg, 8)))
 
         def fn(params, toks, k, v, lengths, active):
-            logits, c, hits = mla_moe.decode_ragged(
+            logits, c, counts = mla_moe.decode_ragged(
                 params, toks, mla_moe.RaggedKVCache(k, v, lengths), cfg,
                 active=active, window=1536)
-            return jnp.argmax(logits[:, -1], -1), c.k, c.v, c.lengths, hits
+            return jnp.argmax(logits[:, -1], -1), c.k, c.v, c.lengths, counts
 
         args = (params, _sds(one_chip, (8, 1), jnp.int32), cache.k, cache.v,
                 cache.lengths, _sds(one_chip, (8,), jnp.bool_))
@@ -506,9 +506,9 @@ def test_sparse_expert_programs_read_the_experts_where_they_lie(one_chip, progra
         seq = _on(one_chip, jax.eval_shape(lambda: mla_moe.KVCache.create(cfg, 1)))
 
         def fn(params, ids, sk, sv, slen):
-            logits, s, hits = mla_moe.forward(
+            logits, s, counts = mla_moe.forward(
                 params, ids, mla_moe.KVCache(sk, sv, slen), cfg)
-            return logits[0], s.k, s.v, s.length, hits
+            return logits[0], s.k, s.v, s.length, counts
 
         args = (params, _sds(one_chip, (1, 512), jnp.int32), seq.k, seq.v,
                 seq.length)
@@ -522,6 +522,32 @@ def test_sparse_expert_programs_read_the_experts_where_they_lie(one_chip, progra
     cache_bytes = sum(a.size * a.dtype.itemsize for a in args[2:4])
     assert mem.alias_size_in_bytes >= cache_bytes
     text = compiled.as_text()
-    # Three grouped matmuls in each of the four expert layers.
-    assert len(re.findall(r'op_name="ragged-dot-none"', text)) == 12
+    # Three grouped matmuls in each of the four expert layers, each the
+    # repo's own kernel (ops/grouped_matmul.py) under the expert layer's
+    # scope, and none left to XLA's 512-row lowering of ragged_dot.
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 12
+    assert all("layer.moe_experts" in l and "grouped_matmul" in l for l in calls)
+    assert "ragged-dot" not in text
     assert not re.search(r"bf16\[256,(2048,768|768,2048)\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("rows", [4096, 64, 8])
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
+def test_grouped_matmul_lowers_at_the_published_expert_widths(one_chip, rows, k, n):
+    """Mosaic takes the kernel at a 512-token chunk's 4096 token copies, a
+    decode step's 64 and one token's 8, over 256 experts of 2048 x 768
+    (gate, up) and 768 x 2048 (down), with a whole expert matrix a block:
+    inside the 16 MiB of scoped VMEM, or the compile raises."""
+    from tpumlops.ops import grouped_matmul as gm
+
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        _sds(one_chip, (rows, k), jnp.bfloat16),
+        _sds(one_chip, (256, k, n), jnp.bfloat16),
+        _sds(one_chip, (256,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    tm = gm.row_tile(rows, 256)
+    assert gm._col_tile(k, n, tm, 2) == n  # the matrix is not split
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
