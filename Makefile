@@ -13,8 +13,7 @@ DOCKER   ?= docker
 
 .PHONY: images operator-image server-image router-image router-bin \
         install uninstall test test-fast test-e2e test-all lint \
-        bench-contract metrics-contract compile-budget plan-contract \
-        bench-history metrics-catalog verify bench
+        metrics-contract compile-budget plan-contract metrics-catalog verify
 
 images: operator-image server-image router-image
 
@@ -74,26 +73,12 @@ lint:
 	  echo "lint: ruff not installed; skipping (pip install ruff)"; \
 	fi
 
-# Bench driver-contract gate: a --dry-run invocation (validates the
-# scenario registry and prints the schema contract without touching a
-# device) plus the contract tests that pin it — scenario schema drift
-# fails HERE, locally, instead of surfacing as a missing field in a
-# round's official record.
-bench-contract:
-	python bench.py --dry-run > /dev/null
-	python -m pytest tests/test_bench_contract.py -q
-
-# Metric-identity contract gate (SURVEY §7 hard part 4): the promotion
+# Metric-identity contract (SURVEY §7 hard part 4): the promotion
 # gate's PromQL — and every dashboard/alert — reads these exact family
-# names and label sets.  An accidental rename must fail HERE, locally,
-# not as a gate query silently reading 0 through its vector(0) fallback.
+# names and label sets.  A tier-1 test file; this is its own command.
 metrics-contract:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_metrics_contract.py -q
 
-# The EXACT tier-1 command from ROADMAP.md (the driver's acceptance
-# gate) chained behind lint + the bench contract: not-slow tranche,
-# collection errors tolerated, 870 s wall cap, DOTS_PASSED echoed from
-# the captured dot lines.
 # Compile-budget regression gate (ISSUE 16): the unified super-step
 # engine's whole point is a small program space.  Runs both warmup
 # sweeps on the tiny model with the compile observatory attached and
@@ -103,36 +88,36 @@ metrics-contract:
 compile-budget:
 	env JAX_PLATFORMS=cpu python scripts/check_compile_budget.py
 
-# Plan-contract gate (ISSUE 18): the offline SLO planner's output is a
-# pure function of (trace, objective, cost model, grid) — re-planning
-# the committed fixture trace must reproduce the committed plan JSON
-# byte-for-byte.  Cost-model drift fails HERE, locally, instead of
-# silently re-shaping fleets the next time a CR's planner runs.
+# Plan contract (ISSUE 18): the offline SLO planner's output is a pure
+# function of (trace, objective, cost model, grid) — re-planning the
+# committed fixture trace must reproduce the committed plan JSON
+# byte-for-byte.  tests/test_planner.py holds the same in tier-1; this
+# is its command-line form.
 plan-contract:
 	env JAX_PLATFORMS=cpu python scripts/plan.py --dry-run \
 	  --expect tests/fixtures/journey_plan.json > /dev/null
 
-# Bench regression sentinel (ISSUE 20): every committed BENCH_*.json's
-# headline keys versus their last BENCH_HISTORY.jsonl revision — a
-# silent tok/s or collapse-ratio regression fails here, in the diff.
-bench-history:
-	python scripts/check_bench_history.py
-
 # Metrics-catalog lint (ISSUE 20): the three OBSERVABILITY.md series
 # tables must enumerate EXACTLY the families the server / operator /
-# router planes export — both directions.
+# router planes export — both directions.  Tier-1 runs the same check
+# (tests/test_metrics_contract.py); this is its command-line form.
 metrics-catalog:
 	env JAX_PLATFORMS=cpu python scripts/check_metrics_catalog.py
 
-verify: lint bench-contract metrics-contract compile-budget plan-contract \
-        bench-history metrics-catalog
-	set -o pipefail; rm -f /tmp/_t1.log; \
-	timeout -k 10 1150 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+# What stands outside tier-1 (lint, and the compile-budget sweep: ROADMAP
+# D12), the catalog's command-line form, then the tier-1 command as the
+# driver runs it: not-slow tranche, six xdist workers a file at a time,
+# collection errors tolerated, 1470 s wall cap, the pass count from the
+# junit file.  (The driver also sets ALLOW_MULTIPLE_LIBTPU_LOAD=1; the
+# suite does not need it: one file describes the TPU, in a fixture.)
+verify: lint compile-budget metrics-catalog
+	set -o pipefail; rm -rf /tmp/_t1.log /tmp/_t1.xml; \
+	timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
 	  -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-	  -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; \
+	  -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml \
+	  -p no:randomly 2>&1 | tee /tmp/_t1.log; \
 	rc=$${PIPESTATUS[0]}; \
-	echo DOTS_PASSED=$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c); \
+	said=$$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null \
+	  | head -n 1 | awk '{n=$$1-$$2-$$3-$$4; print (n<0 ? 0 : n)}'); \
+	echo DOTS_PASSED=$${said:-$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c)}; \
 	exit $$rc
-
-bench:
-	python bench.py

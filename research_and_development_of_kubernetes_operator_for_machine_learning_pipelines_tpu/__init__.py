@@ -15,8 +15,8 @@ Kubernetes operator (see SURVEY.md), designed TPU-first:
   metrics.
 - ``models``    -- the model zoo backing the baseline configs: linear/iris,
   tabular, ResNet-50, BERT-base, Llama-2 (tensor-parallel over v5e-8).
-- ``ops``       -- Pallas TPU kernels (flash attention, rmsnorm, ring
-  attention) with XLA fallbacks.
+- ``ops``       -- ring attention over the ``sp`` mesh axis and the
+  sparse-expert grouped matmul (Pallas on the TPU, XLA off it).
 - ``parallel``  -- device meshes, sharding rules, collectives, multi-host
   initialization.
 - ``clients``   -- protocol interfaces + real REST clients + in-memory fakes

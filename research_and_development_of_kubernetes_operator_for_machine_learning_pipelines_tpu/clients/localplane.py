@@ -1,10 +1,9 @@
 """Local data-plane harness: real servers + native router, no cluster.
 
-One implementation shared by the e2e tests (tests/test_e2e_localplane.py)
-and the benchmark of record (bench.py) — both drive a full unscripted
-canary where the predictors are live aiohttp/JAX servers, traffic flows
-through the compiled ``native/router.cc`` split, and the gate reads the
-router's real histograms.  The pieces map to the reference's production
+The harness of the e2e tests (tests/test_e2e_localplane.py): a full
+unscripted canary where the predictors are live aiohttp/JAX servers,
+traffic flows through the compiled ``native/router.cc`` split, and the
+gate reads the router's real histograms.  The pieces map to the reference's production
 loop (``mlflow_operator.py:56-361``):
 
     reference            here

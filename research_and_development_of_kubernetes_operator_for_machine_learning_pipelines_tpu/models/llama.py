@@ -40,53 +40,6 @@ PAD_ID = 0  # the id a short prompt chunk is padded with
 UNSUPPORTED: dict[str, str] = {}  # serving mechanisms these programs lack
 
 
-# Decode attention dispatch: "xla" (einsum chain), "pallas" (fused
-# ops/decode_attention kernels), "pallas_single" (one program per
-# (slot, head)), or "auto".  "auto" resolves to XLA: measured on a v5e
-# chip at 1.35B geometry (scripts/ab_attention.py, in-process A/B), the
-# einsum chain beats both pallas kernels at every slot count — 2.80 vs
-# 6.99 ms/step at 8 slots, 14.42 vs 34.2 (batched) / 36.4 (per-slot) at
-# 32.  The reason is structural, not kernel overhead: with
-# num_heads == num_kv_heads (llama-1.35B/7B), G = 1 and each head's
-# score/ctx dot is a 1-row matvec, so the MXU's 8-sublane tiling floor
-# (~512 cycles per [1,W]x[W,D] pass) dominates — a cost XLA's batched
-# dot emitter already sits at, which the extra pallas dispatch and
-# VMEM conversions only add to.  The kernels stay selectable for A/B;
-# grouped-query geometry does NOT flip the result — measured at G=8
-# (nh=16/nkv=2), XLA still wins: 1.99 vs 2.22 ms/step at 8 slots, 3.79
-# vs 5.13 at 32 — and GQA decode is near-streaming-bound there
-# (8437 tok/s @ 32 slots, ~0.51 bw_util; docs/PERF.md round 5).
-# NOTE (pallas_vpu + 1.5x window buckets): the engine's intermediate
-# decode windows (96, 192, 384, 768, ... — generation.decode_window_
-# bucket) are not all multiples of 128, and the VPU kernel requires
-# W % 128 == 0 — under that opt-in config a bucket that is not a
-# 128-multiple RAISES (_block_decode_deferred): an A/B run labeled
-# "pallas_vpu" pins a 128-multiple window, it never measures XLA under
-# the kernel's name.
-# NOTE (speculative verify): the multi-token verify layer
-# (_block_verify_deferred) always uses the XLA einsum chain — the
-# Pallas kernels are single-query formulations.  No cost under the
-# measured default (auto -> xla everywhere), but an opt-in pallas*
-# config combined with spec.tpu.speculative runs verify ticks on XLA
-# while plain ticks run the kernel; pin one or the other for A/B runs.
-_DECODE_ATTN = "auto"
-
-_DECODE_ATTN_IMPLS = ("auto", "xla", "pallas", "pallas_single", "pallas_vpu")
-
-
-def _decode_attn_impl() -> str:
-    if _DECODE_ATTN not in _DECODE_ATTN_IMPLS:
-        # Reject, don't reroute: a typo'd variant silently running a
-        # DIFFERENT implementation would mislabel A/B benchmark rows.
-        raise ValueError(
-            f"unknown _DECODE_ATTN {_DECODE_ATTN!r}; "
-            f"expected one of {_DECODE_ATTN_IMPLS}"
-        )
-    if _DECODE_ATTN != "auto":
-        return _DECODE_ATTN
-    return "xla"
-
-
 def _mat(w, dtype):
     """Weight leaf -> matmul operand: raw array or int8 {"q8","scale"}.
 
@@ -109,7 +62,7 @@ def _qmatmul(x, w):
     performs reliably), instead of relying on it fusing a broadcast
     multiply — when that fusion declines, a bf16 copy of every weight
     matrix hits HBM and decode pays ~3x the weight traffic (round-4
-    profile, scripts/profile_decode.py).  int8 values are exact in bf16,
+    profile).  int8 values are exact in bf16,
     and the f32 scale multiplies the f32 accumulator, so numerics are at
     least as good as dequantize-then-matmul.
     """
@@ -270,9 +223,8 @@ class QuantRaggedKVCache(NamedTuple):
     ``_block_decode_deferred``).  With the round-3 deferred-write decode
     (v5e chip, 1.35B shape, int8 weights, window=512) the int8 cache is
     part of the 1938 tok/s @ 8 slots / 2240 @ 16 ladder (docs/PERF.md);
-    numerics are gated by bench.py's teacher-forced logit-parity fixture
-    (~3% max rel err, argmax agreement 1.0).  Opt-in:
-    ``spec.tpu.quantize: int8kv``.
+    numerics are held beside the full-precision cache by
+    ``tests/test_quantization.py``.  Opt-in: ``spec.tpu.quantize: int8kv``.
     """
 
     k8: jax.Array  # int8 [L, B, T, NKV, D], RaggedKVCache's layout
@@ -622,51 +574,9 @@ def _block_decode_deferred(
     group = nh // nkv
     qg = q.reshape(b, s, nkv, group, hd)
     quant_cache = isinstance(cache_k, tuple)
-    impl = _decode_attn_impl()
-    window = mask_bias.shape[-1]
-    if impl == "pallas_vpu" and (group != 1 or window % 128 != 0):
-        # The VPU kernel is the G == 1 formulation over [W/128, 128]
-        # lane tiles.  Reject, don't reroute: a run labeled
-        # "pallas_vpu" that measured XLA would produce a false "VPU has
-        # no benefit" row.
-        raise ValueError(
-            f"pallas_vpu requires G == 1 and window % 128 == 0 "
-            f"(got G={group}, window={window})"
-        )
-    if quant_cache and impl.startswith("pallas"):
-        with jax.named_scope("layer.attn_core"):
-            # Fused Pallas path: program(s) over (slot-block, kv-head) do both
-            # MXU dots over the VMEM-resident int8 window with scales folded
-            # into score/prob rows and the self-term joined in-softmax —
-            # replacing the ~15-op XLA chain below (ops/decode_attention.py;
-            # dispatch measured by scripts/ab_attention.py).  "pallas" is the
-            # slot-batched kernel (grid divided by the slot block — the
-            # per-program overhead was a ~1 ms/slot linear term at 1.35B);
-            # "pallas_single" keeps one program per (slot, head) for A/B.
-            from ..ops.decode_attention import (
-                decode_attention, decode_attention_batched, decode_attention_vpu)
-
-            attn_fn = {
-                "pallas_single": decode_attention,
-                "pallas_vpu": decode_attention_vpu,
-            }.get(impl, decode_attention_batched)
-            # The kernels' blocks are one (slot, kv-head)'s contiguous
-            # [W, D] window: hand them a transposed view of the slab.
-            k8, ks, v8, vs = (
-                jnp.swapaxes(a, 1, 2) for a in (*cache_k, *cache_v)
-            )
-            ctx4 = attn_fn(
-                qg[:, 0],                                   # [B, NKV, G, D]
-                k8,
-                ks,                                         # [B, NKV, W, 1]
-                v8,
-                vs,
-                k[:, 0][:, :, None, :],                     # [B, NKV, 1, D]
-                v[:, 0][:, :, None, :],
-                mask_bias[:, 0],                            # [B, 1, W]
-            )
-            ctx = ctx4[:, None].astype(x.dtype).reshape(b, s, nh * hd)
-        return _attn_out_mlp(x, ctx, lp, cfg), k, v
+    # Plain XLA, no kernel: at G = 1 each head's dot is a one-row matvec at
+    # the MXU's 8-sublane floor; on the chip this chain won at every slot
+    # count, and a fused window passes 16 MiB of scoped VMEM at 7B geometry.
     with jax.named_scope("layer.attn_core"):
         if quant_cache:
             k8, ks = cache_k

@@ -2,8 +2,8 @@
 
 Why: autoregressive decode is HBM-bandwidth-bound — every generated token
 re-reads every weight matrix, so at serving batch sizes the time-per-token
-floor is ``bytes(weights) / HBM_bandwidth``, not FLOPs (the bench's BERT
-prefill path is the opposite: compute-bound at ~55% MXU, see bench.py).
+floor is ``bytes(weights) / HBM_bandwidth``, not FLOPs (a BERT forward
+pass is the opposite: compute-bound).
 Storing the matmul weights as int8 halves the bytes read per token, which
 halves the decode floor; the dequantize (int8 → bf16 multiply by a
 per-channel scale) is elementwise work XLA fuses into the matmul's operand
@@ -98,7 +98,7 @@ def dense_q8(x: jax.Array, qw: dict, b: jax.Array | None = None) -> jax.Array:
     token): symmetric, scale = max|x| / 127 over the contraction axis,
     computed on the fly — XLA fuses it into the matmul read (round-3
     ablation: the dynamic-quant GEMM ladder runs at 188 TFLOP/s, ~0 cost
-    over pre-quantized operands; scripts/profile_bert_int8.py).  The
+    over pre-quantized operands).  The
     int32 accumulator rescales by (a_scale x w_scale) in f32, so the
     only approximation is the two roundings to int8.  End to end the
     int8 path pairs with tanh-GELU (loader default under quantize: int8

@@ -62,8 +62,8 @@ HOST_DISPATCH_S = 300e-6
 
 # Credit speculative decode an assumed draft-acceptance rate: the trace
 # records arrivals, not text, so the real rate is unknowable offline.
-# 0.3 is conservative for chat workloads (bench.py measures the real
-# curve); docs/PLANNER.md carries the caveat.
+# 0.3 is conservative for chat workloads; docs/PLANNER.md carries the
+# caveat.
 SPEC_ASSUMED_ACCEPTANCE = 0.3
 SPEC_DRAFT_TOKENS = 4
 

@@ -1907,7 +1907,7 @@ def make_gen_engine(
         predictor.causal_lm["cfg"],
         # Default stays latency-first; spec.tpu.maxSlots raises it for
         # throughput (decode re-reads all weights per step — slots
-        # amortize that; see bench.py slot ladder).
+        # amortize that).
         max_slots=config.tpu.max_slots or min(config.tpu.max_batch_size, 8),
         eos_id=predictor.causal_lm.get("eos_id"),
         on_step=_fan(

@@ -132,7 +132,7 @@ class UnknownDeviceKind(LookupError):
         )
 
 
-# THE peaks table (bench.py reads it too), per chip, keyed by the exact
+# THE peaks table, per chip, keyed by the exact
 # ``jax.Device.device_kind`` (a v5e reports "TPU v5 lite").  Source:
 # Google Cloud TPU documentation, "TPU v5e" / "TPU v4" system
 # architecture pages.

@@ -167,13 +167,7 @@ def decode_window_bucket(length: int, capacity: int) -> int:
     384, 768, ...) cap the overshoot at 33% for one more compiled
     variant per octave — measured on chip at 1.35B/32 slots, window
     384 vs 512 is 1.085x the step rate (15.10 -> 13.92 ms/step; the
-    weight-stream constant dilutes the linear attention term).
-
-    Interaction with ``_DECODE_ATTN='pallas_vpu'``: the 3/4 steps are
-    not all multiples of 128, and the VPU kernel requires W % 128 == 0,
-    so that opt-in config runs the VPU kernel only on the W%128==0
-    buckets and warn-falls-back to XLA on the others (see the
-    ``_DECODE_ATTN`` note in models/llama.py)."""
+    weight-stream constant dilutes the linear attention term)."""
     w = prefill_bucket(length, capacity)
     # The 3/4 step applies only to an UNCAPPED power-of-two bucket: when
     # next_bucket was clamped to a non-power capacity, 3*(w//4) is an
@@ -768,7 +762,7 @@ class GenerationEngine:
         # Engine device dispatches by tick kind (the amortization series:
         # a fused K-step tick is ONE dispatch where the plain loop paid
         # K) — mirrored to tpumlops_engine_dispatches_total{op} via
-        # on_dispatch and read by bench.py's multistep scenario.
+        # on_dispatch; tests/test_multistep.py counts it.
         self.dispatches_total: dict[str, int] = {}
         self._reset_device_state()
 
@@ -1424,10 +1418,10 @@ class GenerationEngine:
         self._inflight_reqs = 0  # submitted futures not yet done
         self._draining = False
         self._on_shed = on_shed
-        self.shed_total = 0  # sheds by any reason (bench/metrics mirror)
+        self.shed_total = 0  # sheds by any reason (metrics mirror)
         self.tokens_generated = 0
-        # Prefix-cache observability (also read by bench.py's shared-prefix
-        # scenario and the Prometheus hookups in app.make_gen_engine).
+        # Prefix-cache observability (read by tests/test_prefix_cache.py
+        # and the Prometheus hookups in app.make_gen_engine).
         self.prefix_hits = 0
         self.prefix_cached_tokens = 0
         self.prefix_evictions = 0
@@ -1436,13 +1430,13 @@ class GenerationEngine:
         # wrote; seeded (cached) tokens are prefix_cached_tokens'.
         self.prefill_tokens = 0
         # Weight-streaming prefill dispatches (fused prefills, serial
-        # chunk forwards, packed batched calls each count 1): the
-        # packed_prefill_serving bench reads the packed-vs-serial drop
+        # chunk forwards, packed batched calls each count 1):
+        # tests/test_packed_prefill.py reads the packed-vs-serial drop
         # here — every dispatch avoided is a full HBM weight stream
         # the admissions shared instead of re-paying.
         self.prefill_forwards = 0
-        # Speculative/fused observability (also read by bench.py's
-        # speculative_serving and multistep_serving scenarios):
+        # Speculative/fused observability (read by tests/test_speculative.py
+        # and tests/test_multistep.py):
         # decode_forwards counts every decode/verify/multistep DISPATCH,
         # decode_tokens every token those dispatches emitted.  In the
         # single-step loop a dispatch is one weight stream and the ratio

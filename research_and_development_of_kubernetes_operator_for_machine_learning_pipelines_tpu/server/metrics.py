@@ -128,9 +128,8 @@ class ServerMetrics:
         )
         # Device dispatch wall per batch: with queue_seconds and the
         # request histogram this decomposes server-observed latency into
-        # queue wait + device run + server overhead (JSON, HTTP, glue) —
-        # the overhead term is environment-independent and benched
-        # (bench.py serve_path server_overhead_ms, VERDICT r2 #7).
+        # queue wait + device run + server overhead (JSON, HTTP, glue);
+        # the overhead term does not depend on the device (VERDICT r2 #7).
         self.batch_run_seconds = Histogram(
             "tpumlops_batch_run_seconds",
             "run_batch (device dispatch) wall time per executed batch",

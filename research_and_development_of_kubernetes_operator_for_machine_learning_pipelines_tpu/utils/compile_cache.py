@@ -60,7 +60,7 @@ DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_compile_cach
 
 def resolve_compile_cache_dir(requested: str | None = None) -> str:
     """THE answer to "where does this process keep its compile cache",
-    for every entry point (server CLI, bench.py, chip_smoke.py, scripts).
+    for every entry point (server CLI, chip_smoke.py, scripts).
 
     ``JAX_COMPILATION_CACHE_DIR`` set → that directory and no other:
     whoever runs the program placed the cache, and a flag or a default

@@ -1379,8 +1379,7 @@ class TpuSpec:
     # Continuous-batching decode slots.  None = min(max_batch_size, 8), a
     # conservative latency-first default; throughput deployments should
     # raise it — decode streams the full weights per step, so tok/s rises
-    # near-linearly with slots until the KV cache dominates HBM traffic
-    # (measured curve in bench.py llama_decode.slot_ladder).
+    # near-linearly with slots until the KV cache dominates HBM traffic.
     max_slots: int | None = None
     # Batches allowed in flight on the device at once (async dispatch
     # double-buffering): while batch N executes, batch N+1 is stacked and

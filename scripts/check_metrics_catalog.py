@@ -1,4 +1,5 @@
-"""Metrics-catalog lint (``make verify`` -> ``metrics-catalog``).
+"""Metrics-catalog lint (``make metrics-catalog``; tier-1 runs the same
+check through ``tests/test_metrics_contract.py``).
 
 docs/OBSERVABILITY.md carries a "Prometheus series catalog" — three
 tables (Server / Operator / Router) that are supposed to enumerate
@@ -109,7 +110,8 @@ def router_cc_families() -> set[str]:
     return names
 
 
-def main() -> int:
+def exported_families() -> dict[str, set[str]]:
+    """What each plane exports -> {"server"|"operator"|"router": names}."""
     from research_and_development_of_kubernetes_operator_for_machine_learning_pipelines_tpu.operator.telemetry import (  # noqa: E501
         OperatorTelemetry,
     )
@@ -117,13 +119,17 @@ def main() -> int:
         ServerMetrics,
     )
 
-    exported = {
+    return {
         "server": registry_families(
             ServerMetrics("d", "p", "ns", device_telemetry=True).registry
         ),
         "operator": registry_families(OperatorTelemetry().registry),
         "router": router_cc_families(),
     }
+
+
+def main() -> int:
+    exported = exported_families()
     documented = doc_families()
 
     problems: list[str] = []

@@ -52,8 +52,7 @@ def main() -> int:
     ap.add_argument(
         "--dry-run", action="store_true",
         help="plan and print only — never touches a cluster (currently "
-        "the only mode; the flag exists for CLI-contract parity with "
-        "bench.py)",
+        "the only mode)",
     )
     ap.add_argument(
         "--expect",

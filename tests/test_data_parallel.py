@@ -133,7 +133,6 @@ def _engine(params, cfg, mesh_shape=None, max_slots=4, **kw):
     )
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("dp", [2, 4])
 def test_dp_greedy_parity_with_slot_churn(tiny, dp):
     """f64 token-for-token: dp-sharded greedy decode across staggered

@@ -10,7 +10,8 @@ these tests validate the recipes as far as possible without one:
   the slim operator image);
 - every Dockerfile COPY source exists in the build context, and the image
   names the Dockerfiles document match what the manifests/builder expect;
-- the Makefile exposes the documented targets.
+- the Makefile exposes the documented targets;
+- the documents and the Makefile name no file that is not in the tree.
 """
 
 import os
@@ -184,10 +185,61 @@ def test_crd_printer_columns_surface_rollout_state():
 def test_makefile_targets_present():
     mk = (REPO / "Makefile").read_text()
     for target in ("images:", "operator-image:", "server-image:",
-                   "router-image:", "install:", "uninstall:", "test:", "bench:"):
+                   "router-image:", "install:", "uninstall:", "test:", "verify:"):
         assert target in mk, f"Makefile missing target {target}"
     # install applies the three manifests in the reference's order
     # (README.md:44-58): CRD, RBAC, Deployment.
     order = [mk.index("crd.yaml"), mk.index("rbac.yaml"),
              mk.index("operator-deployment.yaml")]
     assert order == sorted(order)
+
+
+# ---------------------------------------------------------------------------
+# The documents describe the tree as it is
+# ---------------------------------------------------------------------------
+
+_FILE_WORD = re.compile(r"^[\w./*-]+\.(?:py|json|jsonl|md|cc|yaml|toml)$")
+# The reference operator's one source file, cited by line throughout.
+_NOT_OURS = {"mlflow_operator.py"}
+
+
+def _named_files(words):
+    """Those of ``words`` that name a file of this repository (or a glob
+    of them): a path (``tests/x.py::test_y`` and ``x.py:12`` count as
+    ``x.py``), a bare ``*.py``, or a bare record spelled as the root's are
+    (``COMPILE_BUDGET.json``, ``PERF_LEDGER.jsonl``).  Placeholders
+    (``<cell>.json``) and a reader's own outputs (``trace.json``) are not."""
+    for word in words:
+        word = re.split(r"::|:\d", word)[0]
+        bare_ok = word.endswith(".py") or word[:1].isupper()
+        if _FILE_WORD.match(word) and ("/" in word or bare_ok):
+            if word not in _NOT_OURS:
+                yield word
+
+
+def _in_tree(word):
+    # A path is taken from the repository root or from the package (the
+    # documents write `server/app.py`); a bare name may lie anywhere.
+    if "/" in word:
+        return any(REPO.glob(word)) or any(PKG.glob(word))
+    return any(REPO.glob(word)) or any(
+        hit
+        for top in (PKG, *(REPO / d for d in ("tests", "scripts", "docs", "benchmarks")))
+        for hit in top.rglob(word)
+    )
+
+
+@pytest.mark.parametrize(
+    "document",
+    ["README.md", "docs/SCALE.md", "docs/PLANNER.md", "docs/RESILIENCE.md",
+     "docs/OBSERVABILITY.md", "Makefile"],
+)
+def test_documents_name_only_files_that_exist(document):
+    text = (REPO / document).read_text()
+    if document == "Makefile":
+        # `$(PKG)/deploy/crd.yaml` splits into a package-relative path.
+        words = [w.lstrip("/") for w in re.split(r"[\s()=;,`]+", text)]
+    else:  # prose: only what is set in back quotes is a name
+        words = [w for q in re.findall(r"`([^`\n]+)`", text) for w in q.split()]
+    missing = sorted({w for w in _named_files(words) if not _in_tree(w)})
+    assert not missing, f"{document} names files that are not in the tree: {missing}"

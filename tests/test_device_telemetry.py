@@ -243,6 +243,14 @@ def test_engine_ticks_carry_utilization_with_telemetry(tiny, cpu_peaks):
         assert out.size == 5
     finally:
         engine.shutdown()
+    # Observation changes no token: the same engine with no ledger, no
+    # cost model, no wrapped jits and no recorder emits the same five.
+    plain = GenerationEngine(params, cfg, max_slots=2, prefill_chunk=16)
+    plain.start(warmup=False)
+    try:
+        assert plain.generate([1, 2, 3], 5).tolist() == out.tolist()
+    finally:
+        plain.shutdown()
     # Ledger + cost model attached with the engine's real geometry.
     assert telemetry.ledger is not None
     assert telemetry.ledger.max_slots == 2

@@ -40,9 +40,8 @@ def x64():
 
 
 def _dense_causal_f64(q, k, v, scale=None):
-    """Dense causal attention, fully f64 — unlike ops.flash_attention.
-    attention_reference, which pins its score accumulation to f32 and
-    would put an f32 noise floor under an exactness claim."""
+    """Dense causal attention, fully f64: an oracle that accumulated its
+    scores in f32 would put an f32 noise floor under an exactness claim."""
     import jax
     import jax.numpy as jnp
 
@@ -229,7 +228,6 @@ def _long_prompt(n, seed=3):
     return rng.integers(1, 200, size=n).tolist()
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("sp", [2, 4])
 def test_sp_prefill_parity_and_routing(tiny, sp):
     """A cold prompt >= spPrefillThreshold routes through the ring
@@ -336,7 +334,6 @@ def test_sp_tp_composed_mesh_parity(tiny):
     assert out_short == _ref(params, cfg, short_p, 6)
 
 
-@pytest.mark.slow
 def test_sp1_dispatch_ledger_byte_for_byte(tiny):
     """{"sp": 1} (and the absent mesh) serve the same requests with the
     IDENTICAL per-kind dispatch ledger — no new programs, no sp-prefill

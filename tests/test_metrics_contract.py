@@ -6,8 +6,9 @@ label sets.  prometheus_client would happily accept a rename and the
 gate would then read 0 through its ``or on() vector(0)`` fallback, which
 is the worst failure mode: green dashboards over a blind gate.  This
 test snapshots the full inventory of both registries so an accidental
-rename (or label drop) fails HERE; ``make verify`` runs it as the
-``metrics-contract`` step alongside ``bench-contract``.
+rename (or label drop) fails HERE, in tier-1.  The catalog test at the
+end holds docs/OBSERVABILITY.md's series tables to the same inventory
+(``make metrics-catalog`` is its command-line form).
 
 Names below are prometheus_client *family* names (``describe()``):
 Counters declared with a ``_total`` suffix appear stripped here and
@@ -19,6 +20,10 @@ reads the series (operator/judge.py, docs/OBSERVABILITY.md) in the same
 commit.
 """
 
+import importlib.util
+from pathlib import Path
+
+import pytest
 from prometheus_client.metrics import MetricWrapperBase
 
 from tpumlops.operator.telemetry import OperatorTelemetry
@@ -416,3 +421,31 @@ def test_router_mux_family_pinned_when_mux_on():
         )
     finally:
         router.stop()
+
+
+# ---------------------------------------------------------------------------
+# docs/OBSERVABILITY.md's series catalog against what each plane exports
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def catalog():
+    """scripts/check_metrics_catalog.py's own readers: (exported, documented)."""
+    path = Path(__file__).resolve().parent.parent / "scripts" / "check_metrics_catalog.py"
+    spec = importlib.util.spec_from_file_location("check_metrics_catalog", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.exported_families(), module.doc_families()
+
+
+@pytest.mark.parametrize(
+    "direction", ["exported_not_documented", "documented_not_exported"]
+)
+@pytest.mark.parametrize("plane", ["server", "operator", "router"])
+def test_series_catalog_matches_the_exporters(catalog, plane, direction):
+    exported, documented = catalog
+    if direction == "exported_not_documented":
+        stray = exported[plane] - documented[plane]
+    else:
+        stray = documented[plane] - exported[plane]
+    assert not stray, f"{plane}, {direction.replace('_', ' ')}: {sorted(stray)}"

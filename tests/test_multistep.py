@@ -356,7 +356,6 @@ def test_engine_fused_eos_mid_scan_and_short_budgets(tiny):
     assert len(short) == 3  # never over-emits past the budget
 
 
-@pytest.mark.slow
 def test_engine_fused_amortizes_dispatches(tiny):
     """One long request: decode dispatches collapse ~K-fold (ceil((n-1)/K)
     fused ticks for n-1 decode-emitted tokens) — the series the

@@ -20,8 +20,8 @@ import pytest
 from tpumlops.models import llama
 from tpumlops.server.generation import GenerationEngine
 
-# XLA compiles on the virtual CPU mesh: excluded from the fast core.
-pytestmark = pytest.mark.slow
+# XLA compiles on the virtual CPU mesh: excluded from the fast core, but
+# for the chunk-call count (several admissions share one weight stream).
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -50,6 +50,7 @@ def _ref(params, cfg, prompt, n):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.slow
 def test_prefill_chunks_ragged_matches_fused_forward_logits(tiny):
     """Two sequences' chunks packed into one call must reproduce the
     fused whole-prompt forward's logits at every position.
@@ -101,6 +102,7 @@ def test_prefill_chunks_ragged_matches_fused_forward_logits(tiny):
         )
 
 
+@pytest.mark.slow
 def test_prefill_chunks_ragged_parked_rows_write_nothing(tiny):
     """A pad row (offset == capacity) must leave the cache bit-identical
     — that is what lets a packed call pad up to a power-of-two bucket."""
@@ -171,6 +173,7 @@ def test_packed_engine_matches_reference_ragged_chunk_counts(tiny):
     assert packed_calls <= 3, packed_calls
 
 
+@pytest.mark.slow
 def test_packed_engine_bucket_boundaries(tiny):
     """1, 2, 3, and 4 concurrent admissions exercise the B_p buckets
     (1, 2, 4) including the padded 3-in-bucket-4 case; every wave must
@@ -190,6 +193,7 @@ def test_packed_engine_bucket_boundaries(tiny):
         engine.shutdown()
 
 
+@pytest.mark.slow
 def test_packed_engine_matches_sequential_engine_first_tokens(tiny):
     """Packed vs sequential single-admission engines: same tokens from
     the same prompts (the first sampled token included — it comes from
@@ -209,6 +213,7 @@ def test_packed_engine_matches_sequential_engine_first_tokens(tiny):
     assert run(4) == run(1)
 
 
+@pytest.mark.slow
 def test_packed_engine_seeded_sampling_parity(tiny):
     """A seeded sampled request admitted through the packed call must
     reproduce the sequential engine's stream exactly: the batched
@@ -230,6 +235,7 @@ def test_packed_engine_seeded_sampling_parity(tiny):
     assert run(4) == run(1)
 
 
+@pytest.mark.slow
 def test_packed_engine_prefix_cache_hits(tiny):
     """Prefix-cache composition: warm admissions seed the cached prefix
     straight into their reserved slot and only the suffix chunks run —
@@ -271,6 +277,7 @@ def test_packed_engine_prefix_cache_hits(tiny):
     assert warm_calls <= 3, warm_calls
 
 
+@pytest.mark.slow
 def test_packed_engine_speculative_composition(tiny):
     """Packed admission + self-speculative decode in one engine: both
     amortizations compose and output stays exact."""
@@ -295,6 +302,7 @@ def test_packed_engine_speculative_composition(tiny):
     assert outs == [_ref(params, cfg, p, n) for p, n in prompts]
 
 
+@pytest.mark.slow
 def test_packed_engine_validation():
     cfg = llama.LlamaConfig.tiny(max_seq=32)
     params = llama.init(jax.random.key(1), cfg, dtype=jnp.float64)
@@ -311,6 +319,7 @@ def test_packed_engine_validation():
         )
 
 
+@pytest.mark.slow
 def test_packed_token_budget_caps_chunks_per_call(tiny):
     """prefillTokenBudget caps the chunks one packed call may carry:
     budget 16 at chunk 8 packs at most 2 admissions per tick, and the
@@ -331,6 +340,7 @@ def test_packed_token_budget_caps_chunks_per_call(tiny):
     assert fills and max(fills) <= 2, fills
 
 
+@pytest.mark.slow
 def test_packed_admission_metrics_fire(tiny):
     """on_prefill_batch / on_admission_wait / on_ttft fire per admission
     with sane values (waits and TTFTs positive, fill counts the real
@@ -363,6 +373,7 @@ def test_packed_admission_metrics_fire(tiny):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.slow
 def test_multihost_replay_of_packed_prefill(tiny):
     """A packed-admission burst on a 2-'host' unit must leave leader and
     follower device state identical: followers replay OP_GEN_CHUNKS (and
@@ -445,6 +456,7 @@ def test_multihost_replay_of_packed_prefill(tiny):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.slow
 def test_warmup_compiles_every_pack_bucket(tiny):
     """No live burst may pay a packed-call compile: after warmup every
     B_p bucket variant is already compiled."""

@@ -111,7 +111,6 @@ def _run_clean(engine, n_be=20, **submit_kw):
         engine.shutdown()
 
 
-@pytest.mark.slow
 def test_preempt_greedy_no_lost_work(tiny):
     """The headline invariant: the evicted-and-restored best-effort
     stream equals the pure-model greedy reference token for token, and

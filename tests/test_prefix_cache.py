@@ -277,7 +277,6 @@ def _engine(params, cfg, budget_bytes=1 << 22, **kw):
     )
 
 
-@pytest.mark.slow
 def test_cached_prefix_bit_identical_to_cold_prefill(tiny):
     """The acceptance bar: a warm (cached-prefix) admission must produce
     BIT-identical final-position logits and tokens to the cold one."""

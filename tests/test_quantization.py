@@ -150,41 +150,75 @@ def test_dequantize_bf16_single_rounding():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
-def test_quant_kv_cache_decode_close_to_full_precision():
+# name: (num_heads, num_kv_heads, capacity, row lengths, window, steps).
+# The int8 cache through the XLA chain of ``_block_decode_deferred`` (the
+# one decode attention there is) beside the full-precision cache: the
+# shapes of a batch that a kernel would have to get right as well.
+_KV_DECODE_CASES = {
+    "g2_whole_capacity_six_steps": (4, 2, 32, (4, 0), None, 6),
+    "zero_length_row_attends_only_its_self_term": (4, 2, 64, (0, 9), 32, 2),
+    "three_lengths_in_one_batch": (4, 2, 64, (7, 23, 30), 32, 2),
+    "g1": (4, 4, 64, (5, 17, 30), 32, 2),
+    "g8": (8, 1, 64, (5, 17, 30), 32, 2),
+    "window_96": (4, 2, 128, (3, 40, 93), 96, 2),
+    "window_384": (4, 2, 512, (1, 200, 381), 384, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KV_DECODE_CASES))
+def test_quant_kv_cache_decode_close_to_full_precision(case):
     from tpumlops.models.llama import QuantRaggedKVCache, RaggedKVCache
 
-    cfg = llama.LlamaConfig.tiny(max_seq=32)
+    heads, kv_heads, capacity, lengths, window, steps = _KV_DECODE_CASES[case]
+    cfg = llama.LlamaConfig.tiny(
+        num_heads=heads, num_kv_heads=kv_heads, max_seq=capacity
+    )
     params = llama.init(jax.random.key(0), cfg, dtype=jnp.float32)
-    prompt = jnp.asarray([[5, 9, 2, 11]], jnp.int32)
-    logits, seq = llama.prefill(params, prompt, cfg, dtype=jnp.float32)
-    tok = jnp.tile(jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32), (2, 1))
+    prefill = jax.jit(
+        lambda ids: llama.prefill(params, ids, cfg, dtype=jnp.float32)
+    )
+    step = jax.jit(
+        lambda toks, cache: llama.decode_ragged(
+            params, toks, cache, cfg, dtype=jnp.float32, window=window
+        )
+    )
 
-    full = llama.insert_sequence(
-        RaggedKVCache.create(cfg, 2, jnp.float32), seq, jnp.int32(0), jnp.int32(4)
-    )
-    quant = llama.insert_sequence(
-        QuantRaggedKVCache.create(cfg, 2), seq, jnp.int32(0), jnp.int32(4)
-    )
-    active = jnp.asarray([True, False])
-    for _ in range(6):
-        lf, full = llama.decode_ragged(
-            params, tok, full, cfg, active, dtype=jnp.float32
+    full = RaggedKVCache.create(cfg, len(lengths), jnp.float32)
+    quant = QuantRaggedKVCache.create(cfg, len(lengths))
+    first = []
+    for row, n in enumerate(lengths):
+        # One prompt length a case: causal attention keeps the first n
+        # positions blind to the padding behind them.
+        ids = jax.random.randint(
+            jax.random.key(10 + row), (1, max(lengths)), 1, cfg.vocab_size
         )
-        lq, quant = llama.decode_ragged(
-            params, tok, quant, cfg, active, dtype=jnp.float32
-        )
-        cos = float(
-            jnp.sum(lq[0, -1] * lf[0, -1])
-            / (jnp.linalg.norm(lq[0, -1]) * jnp.linalg.norm(lf[0, -1]))
-        )
-        assert cos > 0.995, cos
-        tok = jnp.tile(
-            jnp.argmax(lf[0:1, -1:], axis=-1).astype(jnp.int32), (2, 1)
-        )
+        logits, seq = prefill(ids)
+        # An empty row holds the whole prompt as junk in the int8 cache and
+        # zeros in the other: neither may be attended.
+        quant = llama.insert_sequence(quant, seq, jnp.int32(row), jnp.int32(n))
+        if n:
+            full = llama.insert_sequence(full, seq, jnp.int32(row), jnp.int32(n))
+        first.append(int(jnp.argmax(logits[0, n - 1])) if n else 1)
+    tok = jnp.asarray(first, jnp.int32)[:, None]
+
+    for i in range(steps):
+        lf, full = step(tok, full)
+        lq, quant = step(tok, quant)
+        for row, n in enumerate(lengths):
+            a, b = lq[row, -1], lf[row, -1]
+            if n == 0 and i == 0:
+                # Only the exact self-term is attended: no int8 value
+                # reaches the logits, so the two agree to rounding.
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5
+                )
+            cos = float(jnp.sum(a * b) / (jnp.linalg.norm(a) * jnp.linalg.norm(b)))
+            assert cos > 0.995, (row, i, cos)
+        tok = jnp.argmax(lf[:, -1:], axis=-1).astype(jnp.int32)
     # storage really is int8
     assert quant.k8.dtype == jnp.int8
-    assert quant.lengths[0] == full.lengths[0]
+    assert quant.lengths.tolist() == full.lengths.tolist()
+    assert quant.lengths.tolist() == [n + steps for n in lengths]
 
 
 @pytest.mark.slow

@@ -329,7 +329,6 @@ def test_engine_speculative_matches_reference_with_slot_churn(tiny):
     assert engine.spec_verify_ticks > 0  # the verify path actually ran
 
 
-@pytest.mark.slow
 def test_engine_oracle_drafter_amortizes_forwards(tiny):
     """With a perfect drafter every draft is accepted: the engine must
     emit multiple tokens per decode forward and still match greedy."""

@@ -187,7 +187,6 @@ def _engine(params, cfg, tp=1, **kw):
     )
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("tp", [2, 4])
 def test_greedy_parity_with_slot_churn(tiny, tp):
     """f64 token-for-token: tp-sharded greedy decode across staggered

@@ -2,8 +2,9 @@
 
 The chip's compiler is installed beside jax; it compiles for a topology
 that is described, not present.  That shows what interpret mode and the
-CPU backend cannot: Mosaic lowering of the Pallas kernels (tiling, VMEM),
-and HBM fit of the full-width serving programs.  Nothing runs, so these
+CPU backend cannot: Mosaic lowering of the one Pallas kernel (tiling, VMEM),
+HBM fit of the full-width serving programs, and what the chip's compiler
+does with their donated caches.  Nothing runs, so these
 say nothing about results or times — a pass here is not a chip run.
 
 Rules this file keeps (on-chip-measurement guide §2): the topology is
@@ -92,71 +93,6 @@ def _resident_bytes(compiled):
         m.argument_size_in_bytes + m.temp_size_in_bytes
         - m.alias_size_in_bytes
     )
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernels at 7B geometry
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "kernel", ["decode_attention", "decode_attention_batched",
-               "decode_attention_vpu"],
-)
-def test_decode_attention_kernels_lower_at_7b_geometry(one_chip, kernel):
-    from tpumlops.ops import decode_attention as da
-
-    slots, w = 16, 1024
-    s = functools.partial(_sds, one_chip)
-    args = (
-        s((slots, NKV, 1, HD), jnp.bfloat16),   # q
-        s((slots, NKV, w, HD), jnp.int8),       # k8
-        s((slots, NKV, w, 1), jnp.float32),     # k scale
-        s((slots, NKV, w, HD), jnp.int8),       # v8
-        s((slots, NKV, w, 1), jnp.float32),     # v scale
-        s((slots, NKV, 1, HD), jnp.bfloat16),   # k_self
-        s((slots, NKV, 1, HD), jnp.bfloat16),   # v_self
-        s((slots, 1, w), jnp.float32),          # mask bias
-    )
-    compiled = jax.jit(getattr(da, kernel)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_rmsnorm_lowers_at_hidden_4096(one_chip):
-    from tpumlops.ops import rmsnorm
-
-    compiled = jax.jit(rmsnorm).lower(
-        _sds(one_chip, (16, 1024, H), jnp.bfloat16),
-        _sds(one_chip, (H,), jnp.bfloat16),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("seq", [2048, 8192])
-def test_flash_attention_lowers_at_long_prefill(one_chip, seq):
-    from tpumlops.ops import flash_attention
-
-    x = _sds(one_chip, (1, NH, seq, HD), jnp.bfloat16)
-    compiled = jax.jit(
-        functools.partial(flash_attention, causal=True)
-    ).lower(x, x, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_flash_attention_32k_raises_the_typed_vmem_error(one_chip):
-    """The compiler refuses this size (RESOURCE_EXHAUSTED in memory space
-    vmem: each program keeps the whole padded K and V resident); the
-    kernel says so itself, naming the limit, before reaching it."""
-    from tpumlops.ops.flash_attention import (
-        KV_VMEM_BUDGET_BYTES,
-        FlashAttentionVmemError,
-        flash_attention,
-    )
-
-    x = _sds(one_chip, (1, NH, 32768, HD), jnp.bfloat16)
-    with pytest.raises(FlashAttentionVmemError, match="32.0 MiB") as exc:
-        jax.jit(functools.partial(flash_attention, causal=True)).lower(x, x, x)
-    assert f"{KV_VMEM_BUDGET_BYTES / 2**20:.0f} MiB" in str(exc.value)
 
 
 # ---------------------------------------------------------------------------
@@ -283,6 +219,25 @@ def _array_instructions(hlo_text):
             yield name, [int(d) for d in dims.split(",") if d], opcode
 
 
+def _assert_buffer_left_in_place(compiled, buffer_elements, cfg, windowed):
+    """No ``copy``/``transpose`` of a whole ``[L, B, T, NKV, D]`` buffer of
+    ``buffer_elements`` and, where the program takes a window, no
+    capacity-sized slab of one layer of it either."""
+    slab_elements = buffer_elements // cfg.num_layers
+    for name, dims, opcode in _array_instructions(compiled.as_text()):
+        n = math.prod(dims)
+        relayout = opcode in ("copy", "copy-start", "transpose") or (
+            opcode == "fusion" and ("copy" in name or "transpose" in name)
+        )
+        assert not (relayout and n == buffer_elements), (
+            f"{name}: a whole-cache {opcode} of {dims}"
+        )
+        if windowed:
+            assert not (
+                cfg.max_seq in dims and slab_elements <= n < buffer_elements
+            ), f"{name}: a capacity-sized slab {dims} from {opcode}"
+
+
 def _ragged_program(program, cfg, window, slots, one_chip):
     """``(fn, args)``: the engine's jit bodies over the ragged cache
     (server/generation.py), ``k``/``v`` at argument positions 2 and 3."""
@@ -390,22 +345,50 @@ def test_ragged_programs_leave_the_cache_in_place(one_chip, geometry, program):
 
     buffer_elements = math.prod(cache.k.shape)
     buffer_bytes = buffer_elements * cache.k.dtype.itemsize
-    slab_elements = buffer_elements // cfg.num_layers
-    for name, dims, opcode in _array_instructions(compiled.as_text()):
-        n = math.prod(dims)
-        relayout = opcode in ("copy", "copy-start", "transpose") or (
-            opcode == "fusion" and ("copy" in name or "transpose" in name)
-        )
-        assert not (relayout and n == buffer_elements), (
-            f"{name}: a whole-cache {opcode} of {dims}"
-        )
-        if windowed:
-            assert not (
-                cfg.max_seq in dims and slab_elements <= n < buffer_elements
-            ), f"{name}: a capacity-sized slab {dims} from {opcode}"
+    _assert_buffer_left_in_place(compiled, buffer_elements, cfg, windowed)
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 2 * buffer_bytes, "k/v donation not credited"
     assert m.temp_size_in_bytes < (64 * 2**20 if windowed else buffer_bytes)
+
+
+@pytest.mark.parametrize("geometry", sorted(_CELL_GEOMETRY))
+def test_int8kv_decode_step_leaves_the_cache_values_in_place(one_chip, geometry):
+    """The same for ``spec.tpu.quantize: int8kv``: the greedy decode step
+    over ``QuantRaggedKVCache`` aliases all four donated buffers, copies
+    neither int8 value buffer whole, and reads a window-sized slab of
+    them a layer.  Not held, and so not asserted: the four float32 scale
+    planes ``[L, B, T, NKV, 1]`` (16-30 MiB each) are relaid whole on the
+    way in and out of every step (PERF.md section 7; no cell runs int8kv)."""
+    kw, window = _CELL_GEOMETRY[geometry]
+    cfg = llama.LlamaConfig(**kw)
+    slots = 8
+    params = _on(one_chip, _int8_params(cfg))
+    cache = _on(
+        one_chip,
+        jax.eval_shape(lambda: llama.QuantRaggedKVCache.create(cfg, slots)),
+    )
+
+    def fn(params, toks, k8, ks, v8, vs, lengths, active):
+        logits, c = llama.decode_ragged(
+            params, toks, llama.QuantRaggedKVCache(k8, ks, v8, vs, lengths),
+            cfg, active=active, window=window,
+        )
+        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        return (jnp.where(active, nxt, toks[:, 0])[:, None],
+                c.k8, c.k_scale, c.v8, c.v_scale, c.lengths)
+
+    compiled = jax.jit(fn, donate_argnums=(2, 3, 4, 5)).lower(
+        params, _sds(one_chip, (slots, 1), jnp.int32), cache.k8,
+        cache.k_scale, cache.v8, cache.v_scale, cache.lengths,
+        _sds(one_chip, (slots,), jnp.bool_),
+    ).compile()
+
+    values = math.prod(cache.k8.shape)  # int8: elements are bytes
+    _assert_buffer_left_in_place(compiled, values, cfg, windowed=True)
+    scales = math.prod(cache.k_scale.shape) * cache.k_scale.dtype.itemsize
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * (values + scales), "donation not credited"
+    assert m.temp_size_in_bytes < values
 
 
 def test_batch_generate_program_does_not_reserve_a_full_capacity_cache(one_chip):
