@@ -1934,6 +1934,7 @@ def make_gen_engine(
         unified_step=config.tpu.unified_step,
         on_dispatch=metrics.inc_dispatch if metrics else None,
         on_prefill_tokens=metrics.inc_prefill_tokens if metrics else None,
+        on_prefill_wait=metrics.inc_prefill_wait if metrics else None,
         family=family,
         on_moe=metrics.inc_moe if metrics else None,
         tracer=metrics.tracer if metrics else None,

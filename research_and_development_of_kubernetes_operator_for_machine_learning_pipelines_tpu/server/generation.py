@@ -278,6 +278,33 @@ class _PrefillProgress:
     slot: int = -1  # packed mode: reserved cache row (-1 = scratch path)
 
 
+@dataclass(eq=False)
+class _OpenTick:
+    """A prefill-side program (seed, chunk, insert, fused admit,
+    sp-prefill, packed chunks) that has been dispatched and whose tick is
+    not journaled yet: the engine thread waits for it at the pass's next
+    blocking read, behind whatever it dispatched meanwhile
+    (``GenerationEngine._close_ticks``)."""
+
+    kind: str
+    t0: float  # perf_counter before its dispatch
+    # An output of the program that nothing dispatched before the wait
+    # donates (a donated buffer cannot be waited on).
+    wait_on: object
+    owners: tuple  # the _Requests it serves: a device error fails these
+    fields: dict  # _record_tick's keyword fields
+
+
+class _TickFailed(RuntimeError):
+    """The wait for an open tick raised (``__cause__`` holds the device
+    error): the failure is that admission's, wherever the loop took the
+    wait."""
+
+    def __init__(self, tick: _OpenTick):
+        super().__init__(f"{tick.kind} tick failed at its wait")
+        self.tick = tick
+
+
 @dataclass
 class _Request:
     prompt: np.ndarray  # int32 [L]
@@ -393,6 +420,7 @@ class GenerationEngine:
         tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
         family=None,  # the causal-LM family's module (None: models.llama)
         on_moe: Callable[[str, int, int, int, int], None] | None = None,
+        on_prefill_wait: Callable[[str], None] | None = None,  # "step"|"none"
     ):
         import jax
         import jax.numpy as jnp
@@ -599,20 +627,17 @@ class GenerationEngine:
         # MFU/bandwidth; spec.tpu.observability.deviceTelemetry).  None
         # — the default — wraps nothing and computes nothing per tick.
         self._telemetry = telemetry
-        # JAX dispatch is async: a prefill/seed call returns before the
-        # device finishes, and the wait would otherwise be absorbed into
-        # the NEXT decode tick's wall — the exact mis-attribution the
-        # flight recorder exists to prevent.  With the RECORDER on,
-        # non-decode ticks block on their outputs before the wall is
-        # read (decode/verify/packed already sync via their np.asarray
-        # result reads).  Gated on the recorder ONLY — on_tick (the
-        # always-wired tpumlops_tick_seconds metric) must not arm device
-        # syncs in the default deployment, or traceRing=0 would no
-        # longer be the byte-for-byte unobserved engine loop; without
-        # the recorder, non-decode tick-metric walls are dispatch-only.
-        # Device telemetry also syncs: a dispatch-only prefill wall would
-        # read as an absurd MFU.
-        self._sync_ticks = recorder is not None or telemetry is not None
+        # JAX dispatch is async: a prefill-side call returns before the
+        # device finishes.  The engine thread does not wait for it there:
+        # it registers an open tick (``_open_tick``) and takes the wait at
+        # the pass's next blocking read (``_close_ticks``), behind the
+        # decode step it has dispatched meanwhile, so the chip never
+        # idles while the host assembles that step.  The tick's wall runs
+        # to the stamp of that wait, in completion order, and does not
+        # absorb the step's device time nor lend it its own.  No option
+        # arms or disarms this: watching the engine (recorder, telemetry)
+        # does not change the order of its dispatches.
+        self._on_prefill_wait = on_prefill_wait
         self._on_prefix_l2 = on_prefix_l2
         if prefix_enabled:
             from .prefix_cache import RadixPrefixCache
@@ -1501,6 +1526,11 @@ class GenerationEngine:
         self._ms_remaining = None
         self._ms_eos = None
         self._moe_pending = []  # device scalars of the programs just lost
+        # Prefill-side programs dispatched and not waited for yet (their
+        # arrays are lost with the rest), and the stamp of the last
+        # completion the engine thread saw.
+        self._open_ticks: list[_OpenTick] = []
+        self._done_at = 0.0
 
     def _put_seq(self, buf):
         """Commit a fresh batch-1 prefill scratch buffer to the seq-cache
@@ -2610,16 +2640,12 @@ class GenerationEngine:
         if not self._in_warmup:
             self.prefill_forwards += 1
             self._note_prefill_tokens(L)
-            if self._sync_ticks:
-                with span("engine.prefill_sync"):
-                    first = int(first)  # the wall must cover device time
-            with span("engine.journal"):
-                self._record_tick(
-                    "prefill", t0, time.perf_counter() - t0,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=1, tokens=1,
-                    cost=self._cost_prefill(1, bucket),
-                )
+            self._open_tick(
+                "prefill", t0, first, (req,),
+                active_slots=sum(s is not None for s in self._slots),
+                batch_fill=1, tokens=1,
+                cost=self._cost_prefill(1, bucket),
+            )
         if req.trace is not None:
             req.trace.slot = slot_idx
             req.trace.prefill_chunks += 1  # fused: the whole prompt at once
@@ -2639,14 +2665,59 @@ class GenerationEngine:
         self._slots[slot_idx] = slot
         self._emit_first(slot_idx, req, first)
 
-    def _sync_seq_state(self) -> None:
-        """Journaling only: wait for the in-flight scratch-cache op so
-        the tick wall about to be recorded covers the device time, not
-        just the async dispatch (see ``_sync_ticks``)."""
-        if self._sync_ticks and self._seq_state is not None:
-            import jax
+    def _open_tick(
+        self, kind: str, t0: float, wait_on, owners=(), **fields
+    ) -> None:
+        """Register a prefill-side program just dispatched.  Nothing
+        waits here: :meth:`_close_ticks` journals it (``fields`` are
+        :meth:`_record_tick`'s) at the next blocking read."""
+        self._open_ticks.append(
+            _OpenTick(kind, t0, wait_on, tuple(owners), fields)
+        )
 
-            jax.block_until_ready(self._seq_state[1])
+    def _close_ticks(self, behind: str | None = None) -> None:
+        """A blocking point: wait for the open ticks in device order and
+        journal each with the wall the host saw for it alone.
+
+        ``behind`` is the heartbeat kind of a decode-side dispatch that
+        is already queued behind them (the chip goes straight on to it);
+        None where nothing is.  Every wait is ``engine.prefill_sync``
+        (the benchmark's ``loop_host_ms`` takes that span as time blocked
+        on the device, wherever the loop takes it).  A device error
+        raises :class:`_TickFailed`: it is the admission's, not the
+        step's."""
+        if not self._open_ticks:
+            return
+        import jax
+
+        span = self._span
+        ticks, self._open_ticks = self._open_ticks, []
+        for tick in ticks:
+            self._beat(tick.kind)
+            try:
+                with span("engine.prefill_sync"):
+                    jax.block_until_ready(tick.wait_on)
+            except Exception as exc:
+                raise _TickFailed(tick) from exc
+            start, wall = self._tick_done(tick.t0)
+            with span("engine.journal"):
+                if self._on_prefill_wait is not None:
+                    self._on_prefill_wait("step" if behind else "none")
+                self._record_tick(tick.kind, start, wall, **tick.fields)
+        if behind:
+            self._beat(behind)
+
+    def _tick_done(self, t0: float) -> tuple[float, float]:
+        """Stamp a completion the engine thread has just seen; returns
+        the tick's (start, wall).  The device runs programs in dispatch
+        order, so a program dispatched at ``t0`` cannot have started
+        before the one ahead of it was seen to end: walls taken in
+        completion order never overlap, and a pass's walls sum to no
+        more than the pass."""
+        now = time.perf_counter()
+        start = max(t0, self._done_at)
+        self._done_at = now
+        return start, now - start
 
     def _record_tick(
         self, kind: str, t0: float, wall_s: float, *,
@@ -2740,9 +2811,11 @@ class GenerationEngine:
             self._on_ttft(time.perf_counter() - req.t_submit)
 
     def _emit_first(self, slot_idx: int, req: _Request, first) -> None:
-        """A fresh admission's first token: TTFT, then the read of the
-        sampled token (which waits for the prefill's work unless a
-        journaling sync already did) and its emission."""
+        """A fresh admission's first token.  The host needs its value
+        now (to stream it, and to know whether the slot is done already),
+        so this is a blocking point: the open ticks (the last chunk, the
+        insert) close here, then TTFT, the read and the emission."""
+        self._close_ticks()
         self._note_ttft(req)
         with self._span("engine.prefill_sync"):
             token = int(first)
@@ -3084,11 +3157,12 @@ class GenerationEngine:
         full real-token chunks only (a padded tail carries pad-garbage
         K/V that must never be reused).
 
-        The ``np.asarray`` is a device sync: the scheduler waits for the
-        chunk's forward pass before dispatching the next decode tick, so
-        it is paid at most ONCE per unique chunk — ``has_chunk`` skips
-        both the transfer and the sync for chunks already cached (the
-        steady state for shared-prefix traffic)."""
+        The ``np.asarray`` is a blocking point: the scheduler waits for
+        the chunk's forward pass (its open tick closes first) before
+        dispatching the next decode tick, so it is paid at most ONCE per
+        unique chunk — ``has_chunk`` skips both the transfer and the
+        wait for chunks already cached (the steady state for
+        shared-prefix traffic)."""
         if self._prefix_cache is None or self._in_warmup:
             return
         import jax.numpy as jnp
@@ -3103,6 +3177,7 @@ class GenerationEngine:
             return
         _, sk, sv, _slen = self._seq_state
         ck, cv = self._read_chunk(sk, sv, jnp.int32(start))
+        self._close_ticks()
         with self._span("engine.prefill_sync"):
             ck, cv = np.asarray(ck), np.asarray(cv)
         self._prefix_cache.insert_chunk(prog.req.prompt, chunk_idx, ck, cv)
@@ -3281,16 +3356,13 @@ class GenerationEngine:
         if not self._in_warmup:
             self.prefill_forwards += 1
             self._note_prefill_tokens(L)
-            with span("engine.prefill_sync"):
-                self._sync_seq_state()
-            with span("engine.journal"):
-                self._record_tick(
-                    "sp-prefill", ts, time.perf_counter() - ts,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=1,
-                    cost=self._cost_sp_prefill(bucket),
-                )
-                self._trace_event(req.trace, "sp_prefill")
+            self._open_tick(
+                "sp-prefill", ts, self._seq_state[0], (req,),
+                active_slots=sum(s is not None for s in self._slots),
+                batch_fill=1,
+                cost=self._cost_sp_prefill(bucket),
+            )
+            self._trace_event(req.trace, "sp_prefill")
         self._cache_sp_chunks(req)
         slot_key = self._slot_key_for(req)
         t0 = time.perf_counter()
@@ -3302,15 +3374,11 @@ class GenerationEngine:
                 last_idx=0,
             )
         if not self._in_warmup:
-            if self._sync_ticks:
-                with span("engine.prefill_sync"):
-                    first = int(first)
-            with span("engine.journal"):
-                self._record_tick(
-                    "prefill", t0, time.perf_counter() - t0,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=1, tokens=1,
-                )
+            self._open_tick(
+                "prefill", t0, first, (req,),
+                active_slots=sum(s is not None for s in self._slots),
+                batch_fill=1, tokens=1,
+            )
         if req.trace is not None:
             req.trace.slot = slot_idx
         self._slots[slot_idx] = _Slot(
@@ -3345,6 +3413,7 @@ class GenerationEngine:
         for chunk_idx in range(L // C):
             if self._prefix_cache.has_chunk(req.prompt, chunk_idx):
                 continue
+            self._close_ticks()  # the reads below wait for the ring pass
             ck, cv = self._read_chunk(sk, sv, jnp.int32(chunk_idx * C))
             self._prefix_cache.insert_chunk(
                 req.prompt, chunk_idx, np.asarray(ck), np.asarray(cv)
@@ -3506,13 +3575,15 @@ class GenerationEngine:
             attended = (
                 sum(float(offsets[i]) for i in range(n)) / n + C / 2
             )
-            with span("engine.journal"):
-                self._record_tick(
-                    "packed-prefill", t0, time.perf_counter() - t0,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=n, tokens=finals,
-                    cost=self._cost_prefill(bucket, C, attended=attended),
-                )
+            # ``_device_chunks`` has read the first tokens back: the
+            # call is over, and its tick closes at once.
+            self._open_tick(
+                "packed-prefill", t0, None, [p.req for p in chunk_progs],
+                active_slots=sum(s is not None for s in self._slots),
+                batch_fill=n, tokens=finals,
+                cost=self._cost_prefill(bucket, C, attended=attended),
+            )
+            self._close_ticks()
         for i, prog in enumerate(chunk_progs):
             if prog.req.trace is not None:
                 prog.req.trace.slot = prog.slot
@@ -3562,18 +3633,15 @@ class GenerationEngine:
             return
         if self._on_prefix_hit is not None:
             self._on_prefix_hit(prog.cached_tokens)
-        if self._sync_ticks:
-            import jax
-
-            with span("engine.prefill_sync"):
-                jax.block_until_ready(self._cache_k)
-        with span("engine.journal"):
-            self._record_tick(
-                "seed", ts, time.perf_counter() - ts,
-                active_slots=active, batch_fill=1,
-                cost=self._cost_seed(prog.cached_tokens),
-            )
-            self._trace_event(prog.req.trace, "seed", slot=prog.slot)
+        # Waited for at once: the seed's only outputs are the cache
+        # buffers, which the very next program donates.
+        self._open_tick(
+            "seed", ts, self._cache_k, (prog.req,),
+            active_slots=active, batch_fill=1,
+            cost=self._cost_seed(prog.cached_tokens),
+        )
+        self._close_ticks()
+        self._trace_event(prog.req.trace, "seed", slot=prog.slot)
 
     def _chunk_tokens(self, chunk_progs: list) -> int:
         """Real prompt tokens in the next chunk of each admission."""
@@ -3759,18 +3827,15 @@ class GenerationEngine:
             if not self._in_warmup:
                 if self._on_prefix_hit is not None:
                     self._on_prefix_hit(prog.cached_tokens)
-                with span("engine.prefill_sync"):
-                    self._sync_seq_state()
-                with span("engine.journal"):
-                    self._record_tick(
-                        "seed", ts, time.perf_counter() - ts,
-                        active_slots=sum(
-                            s is not None for s in self._slots
-                        ),
-                        batch_fill=1,
-                        cost=self._cost_seed(prog.cached_tokens),
-                    )
-                    self._trace_event(prog.req.trace, "seed")
+                # The seeded scratch itself: the next chunk donates it,
+                # and no pass ends with a tick open.
+                self._open_tick(
+                    "seed", ts, self._seq_state[1], (prog.req,),
+                    active_slots=sum(s is not None for s in self._slots),
+                    batch_fill=1,
+                    cost=self._cost_seed(prog.cached_tokens),
+                )
+                self._trace_event(prog.req.trace, "seed")
             return  # suffix chunks start next tick (decode cadence kept)
         ids = prog.chunks[prog.next_idx]
         C = self._prefill_chunk_size
@@ -3784,15 +3849,14 @@ class GenerationEngine:
             self.prefill_chunks_dispatched += 1
             self.prefill_forwards += 1
             self._note_prefill_tokens(self._chunk_tokens([prog]))
-            with span("engine.prefill_sync"):
-                self._sync_seq_state()
-            with span("engine.journal"):
-                self._record_tick(
-                    "prefill", ts, time.perf_counter() - ts,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=1,
-                    cost=self._cost_prefill(1, C, attended=offset + C / 2),
-                )
+            # The chunk's logits: the next chunk donates the scratch, not
+            # these.
+            self._open_tick(
+                "prefill", ts, self._seq_state[0], (prog.req,),
+                active_slots=sum(s is not None for s in self._slots),
+                batch_fill=1,
+                cost=self._cost_prefill(1, C, attended=offset + C / 2),
+            )
         if prog.req.trace is not None:
             prog.req.trace.prefill_chunks += 1
             self._trace_event(prog.req.trace, "prefill_chunk")
@@ -3814,15 +3878,11 @@ class GenerationEngine:
                 - C * (len(prog.chunks) - 1),
             )
         if not self._in_warmup:
-            if self._sync_ticks:
-                with span("engine.prefill_sync"):
-                    first = int(first)  # the wall must cover device time
-            with span("engine.journal"):
-                self._record_tick(
-                    "prefill", t0, time.perf_counter() - t0,
-                    active_slots=sum(s is not None for s in self._slots),
-                    batch_fill=1, tokens=1,
-                )
+            self._open_tick(
+                "prefill", t0, first, (req,),
+                active_slots=sum(s is not None for s in self._slots),
+                batch_fill=1, tokens=1,
+            )
         if req.trace is not None:
             req.trace.slot = slot_idx
         self._slots[slot_idx] = _Slot(
@@ -3985,12 +4045,16 @@ class GenerationEngine:
         self._beat("decode")
         with span("engine.decode_dispatch"):
             self._dispatch_step(active_np, window, sampling)
+        # The step is queued behind the pass's chunk: now wait for the
+        # chunk, then for the step, in the order the device runs them.
+        self._close_ticks(behind="decode")
         with span("engine.decode_readback"):
             toks = np.asarray(self._tokens)[:, 0]
+            done = self._tick_done(t0)
             self._read_experts()
         with span("engine.journal"):
             self._note_tick(
-                active_np, t0, tokens=int(active_np.sum()),
+                active_np, *done, tokens=int(active_np.sum()),
                 cost=self._cost_decode(window),
             )
         with span("engine.emit"):
@@ -4001,13 +4065,14 @@ class GenerationEngine:
                         self.decode_tokens += 1
 
     def _note_tick(
-        self, active_np, t0: float, kind: str = "decode",
+        self, active_np, t0: float, wall: float, kind: str = "decode",
         tokens: int = 0, spec_accepted: int = 0, cost=None,
     ) -> None:
+        """Journal a decode or verify tick; ``t0`` and ``wall`` are
+        :meth:`_tick_done`'s, taken where its tokens were read back."""
         if self._in_warmup:
             return
         self.decode_forwards += 1
-        wall = time.perf_counter() - t0
         self._record_tick(
             kind, t0, wall,
             active_slots=int(active_np.sum()),
@@ -4140,11 +4205,12 @@ class GenerationEngine:
         valid tokens across the tick wall (clamped monotone against the
         row's previous token): K tokens on one instant would zero every
         ITL observation and stack the Perfetto instants."""
+        self._close_ticks(behind="multistep")
         with self._span("engine.decode_readback"):
             toks = np.asarray(tok_block_dev)  # the deferred device sync
             valid = np.asarray(valid_dev)
-        end = time.perf_counter()
-        wall = end - t0
+        t0, wall = self._tick_done(t0)
+        end = t0 + wall
         K = self._decode_steps
         active_slots = int((valid > 0).sum())
         total = int(valid.sum())
@@ -4397,7 +4463,8 @@ class GenerationEngine:
                     r_temps, r_tks, r_tps, window, sampling,
                 )
             )
-        end = time.perf_counter()
+        t0, wall = self._tick_done(t0)
+        end = t0 + wall
         finals = sum(
             1 for prog in chunk_progs
             if prog.next_idx == len(prog.chunks) - 1
@@ -4413,7 +4480,6 @@ class GenerationEngine:
                     self._on_prefill_batch(n_pre)
             if n_ver:
                 self.spec_verify_ticks += 1
-            wall = end - t0
             with span("engine.journal"):
                 self._record_tick(
                     "superstep", t0, wall,
@@ -4600,6 +4666,7 @@ class GenerationEngine:
             self._decode_steps,
             bool(sampling),
         )
+        self._close_ticks(behind="superstep")
         with self._span("engine.decode_readback"):
             return (
                 np.asarray(tok_block), np.asarray(valid), np.asarray(greedy),
@@ -4678,10 +4745,11 @@ class GenerationEngine:
             greedy, accepted = self._dispatch_verify(
                 toks, active_np, draft_len, window
             )
+        done = self._tick_done(t0)
         with span("engine.journal"):
             acc_total = int(np.asarray(accepted)[active_np].sum())
             self._note_tick(
-                active_np, t0, kind="verify",
+                active_np, *done, kind="verify",
                 tokens=int(active_np.sum()) + acc_total,
                 spec_accepted=acc_total,
                 cost=self._cost_decode(window, s_draft + 1),
@@ -4753,6 +4821,7 @@ class GenerationEngine:
             jnp.asarray(draft_len),
             int(window),
         )
+        self._close_ticks(behind="verify")
         with self._span("engine.decode_readback"):
             return np.asarray(greedy), np.asarray(accepted)
 
@@ -4836,6 +4905,13 @@ class GenerationEngine:
                     return  # shutdown sentinel
                 try:
                     self._step()
+                    # A pass that read nothing back (no slot active)
+                    # waits for its chunk here: none ends with a tick
+                    # open, so at most a chunk and a step are ever
+                    # queued un-waited.
+                    self._close_ticks()
+                except _TickFailed as failed:
+                    self._admission_failed(failed.tick.owners, failed)
                 except Exception:
                     _log.exception("decode step failed")
                     self._fail_all_and_recover()
@@ -4869,13 +4945,7 @@ class GenerationEngine:
             try:
                 self._chunk_tick()
             except Exception as exc:
-                _log.exception("chunked prefill failed")
-                self._note_admission_crash([prog.req])
-                self._pending = []
-                self._seq_state = None
-                if not prog.req.future.done():
-                    _safe_fail(prog.req.future, exc)
-                self._fail_all_and_recover()
+                self._admission_failed([prog.req], exc)
             return True
         while self._free_slot() is not None:
             try:
@@ -4924,12 +4994,7 @@ class GenerationEngine:
                     try:
                         self._admit_sp(req)
                     except Exception as exc:
-                        _log.exception("sp prefill failed")
-                        self._note_admission_crash([req])
-                        self._seq_state = None
-                        if not req.future.done():
-                            _safe_fail(req.future, exc)
-                        self._fail_all_and_recover()
+                        self._admission_failed([req], exc)
                     continue
                 self._pending.append(prog)
                 return True  # first chunk runs next iteration's admit phase
@@ -4939,12 +5004,7 @@ class GenerationEngine:
                 else:
                     self._admit(req)
             except Exception as exc:  # keep the scheduler alive
-                _log.exception("admit failed")
-                self._note_admission_crash([req])
-                self._seq_state = None  # a failed sp pass left it stale
-                if not req.future.done():
-                    _safe_fail(req.future, exc)
-                self._fail_all_and_recover()
+                self._admission_failed([req], exc)
         return True
 
     def _admit_phase_packed(self) -> bool:
@@ -4996,12 +5056,7 @@ class GenerationEngine:
                 try:
                     self._admit_sp(req)
                 except Exception as exc:
-                    _log.exception("sp prefill failed")
-                    self._note_admission_crash([req])
-                    self._seq_state = None
-                    if not req.future.done():
-                        _safe_fail(req.future, exc)
-                    self._fail_all_and_recover()
+                    self._admission_failed([req], exc)
                 popped = True
                 continue
             prog.slot = slot
@@ -5019,15 +5074,28 @@ class GenerationEngine:
         try:
             self._packed_tick()
         except Exception as exc:
-            _log.exception("packed prefill failed")
-            self._note_admission_crash([p.req for p in self._pending])
-            for prog in self._pending:
-                if not prog.req.future.done():
-                    _safe_fail(prog.req.future, exc)
-            self._pending = []
-            self._reserved.clear()
-            self._fail_all_and_recover()
+            self._admission_failed([p.req for p in self._pending], exc)
         return True
+
+    def _admission_failed(self, reqs, exc: Exception) -> None:
+        """A prefill-side program of ``reqs``' admission raised, at its
+        dispatch or at the wait for it, wherever the loop took that wait
+        (a :class:`_TickFailed` names the tick's own requests): count the
+        crash against their prompts, fail their futures with the device
+        error, drop their progress and recover the device state."""
+        if isinstance(exc, _TickFailed):
+            reqs, exc = exc.tick.owners or reqs, exc.__cause__
+        _log.error(
+            "admission failed: a prefill-side program raised", exc_info=exc
+        )
+        self._note_admission_crash(reqs)
+        failed = {id(req) for req in reqs}
+        self._pending = [p for p in self._pending if id(p.req) not in failed]
+        self._seq_state = None  # the scratch is that admission's
+        for req in reqs:
+            if not req.future.done():
+                _safe_fail(req.future, exc)
+        self._fail_all_and_recover()
 
     def _fail_all_and_recover(self) -> None:
         """Fail every in-flight sequence and reallocate device state.

@@ -186,6 +186,19 @@ class ServerMetrics:
             ident_labels,
             registry=self.registry,
         )
+        # How often the engine thread's wait for a prefill-side program
+        # was hidden behind work for the chip: step / (step + none) is
+        # the share of such waits taken with a decode step already
+        # queued behind the program.
+        self.prefill_waits = Counter(
+            "tpumlops_prefill_waits_total",
+            "Non-decode engine ticks (seed, chunk, insert, sp-prefill, "
+            "packed chunks) by whether a decode dispatch was already "
+            "queued behind the program when the engine thread waited "
+            "for it",
+            ident_labels + ["queued_behind"],
+            registry=self.registry,
+        )
         # Routed-expert traffic of a sparse-expert family, by program
         # (prefill | decode): assignments / activations is the mean
         # number of tokens an expert that was read got to work on;
@@ -688,6 +701,11 @@ class ServerMetrics:
 
     def inc_prefill_tokens(self, n: int):
         self.prefill_tokens.labels(**self.identity).inc(n)
+
+    def inc_prefill_wait(self, queued_behind: str):
+        self.prefill_waits.labels(
+            **self.identity, queued_behind=queued_behind
+        ).inc()
 
     def inc_moe(self, program: str, assignments: int, activations: int,
                 row_tile_visits: int, row_tile: int):

@@ -410,7 +410,7 @@ def test_engine_without_telemetry_has_no_cost_hooks(tiny):
         assert engine._cost_decode(64) is None
         assert engine._cost_prefill(1, 16) is None
         assert engine._cost_seed(16) is None
-        assert engine._sync_ticks is False
+        assert not hasattr(engine, "_sync_ticks")
     finally:
         engine.shutdown()
 

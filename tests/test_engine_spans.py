@@ -1,7 +1,8 @@
 """Host-time spans (utils/tracing.py) and the engine loop's ``engine.*``
 phases: nesting and self time, the nine phases over every tick kind, the
-profiler sink on the capture's clock, the prefill-token counter, and the
-operator staying off jax.
+profiler sink on the capture's clock, the prefill-token counter, where the
+loop takes the wait for a prefill chunk (behind the step's dispatch, with
+or without a recorder watching), and the operator staying off jax.
 
 Engines here are tiny and start WITHOUT the warm-up sweep (each program
 compiles on first use, a few seconds a mode), so the cases run in the
@@ -12,9 +13,11 @@ import glob
 import subprocess
 import sys
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from tpumlops.models import llama
@@ -31,8 +34,6 @@ PROMPTS = [list(range(3, 3 + n)) for n in (5, 11, 19, 8, 25)]
 
 
 def _spin(seconds: float) -> None:
-    import time
-
     end = time.perf_counter() + seconds
     while time.perf_counter() < end:
         pass
@@ -104,6 +105,17 @@ def _engine(tiny, **kw):
     return eng
 
 
+def _watchers(cpu_peaks) -> dict:
+    """What ``traceRing`` and ``deviceTelemetry`` hand the engine."""
+    from tpumlops.server.device_telemetry import DeviceTelemetry
+    from tpumlops.server.flight_recorder import FlightRecorder
+
+    return dict(
+        recorder=FlightRecorder(4096),
+        telemetry=DeviceTelemetry(peaks=cpu_peaks),
+    )
+
+
 def _serve(eng, prompts=PROMPTS, new=12):
     futs = [eng.submit(p, new) for p in prompts]
     return [f.result(timeout=300) for f in futs]
@@ -137,10 +149,11 @@ MODES = {
 }
 
 
+@pytest.mark.parametrize("watched", [False, True], ids=["bare", "watched"])
 @pytest.mark.parametrize("mode", list(MODES))
-def test_engine_phases_cover_the_loop(tiny, mode):
+def test_engine_phases_cover_the_loop(tiny, cpu_peaks, mode, watched):
     kw, step_kinds, absent = MODES[mode]
-    eng = _engine(tiny, **kw)
+    eng = _engine(tiny, **kw, **(_watchers(cpu_peaks) if watched else {}))
     uncovered = []
     try:
         _serve(eng)  # compiles every program the later batches will use
@@ -176,6 +189,173 @@ def test_engine_phases_cover_the_loop(tiny, mode):
     # batch a few percent, and the best of three is taken.
     assert d[ROOT][0] > 0 and min(uncovered) < 0.05, uncovered
     assert prefilled == 3 * sum(len(p) for p in PROMPTS)
+
+
+class _LoggedSpan:
+    def __init__(self, inner, name, log):
+        self.inner, self.name, self.log = inner, name, log
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self.inner.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        out = self.inner.__exit__(*exc)
+        self.log.append((self.name, self.t0, time.perf_counter()))
+        return out
+
+
+class _LoggingTracer(Tracer):
+    """A tracer that also keeps every span's (name, open, close), in the
+    order they closed."""
+
+    def __init__(self):
+        super().__init__()
+        self.log = []
+
+    def span(self, name):
+        return _LoggedSpan(super().span(name), name, self.log)
+
+
+def _spy_ticks(eng) -> list:
+    """Every journaled tick as (kind, start, wall), on perf_counter."""
+    ticks, record = [], eng._record_tick
+
+    def spy(kind, t0, wall_s, **fields):
+        ticks.append((kind, t0, wall_s))
+        return record(kind, t0, wall_s, **fields)
+
+    eng._record_tick = spy
+    return ticks
+
+
+def _first_token_then(eng, prompt, new):
+    """Submit and return (future, event set at the first token)."""
+    started = threading.Event()
+    return eng.submit(prompt, new, on_token=lambda _t: started.set()), started
+
+
+LONG = list(range(3, 43))  # five chunks of 8
+
+
+def test_the_wait_for_a_chunk_is_taken_behind_the_steps_dispatch(
+    tiny, cpu_peaks
+):
+    """A pass with an active slot and a chunk that is not the prompt's
+    last: chunk dispatch, step dispatch, THEN the wait for the chunk and
+    the step's read-back.  The journaled walls are taken in completion
+    order, so they do not overlap and fit inside the pass."""
+    tracer, waits = _LoggingTracer(), []
+    watchers = _watchers(cpu_peaks)
+    eng = _engine(
+        tiny, prefill_chunk=8, tracer=tracer, on_prefill_wait=waits.append,
+        **watchers,
+    )
+    try:
+        _serve(eng, [LONG[:20], PROMPTS[0]], new=8)  # compiles the programs
+        ticks = _spy_ticks(eng)
+        del tracer.log[:], waits[:]
+        ticks_before = watchers["recorder"].ticks_recorded
+        steps_before = eng.dispatches_total["decode"]
+        rider, started = _first_token_then(eng, PROMPTS[0], 40)
+        assert started.wait(timeout=120)
+        doc = eng.submit(LONG, 4)
+        doc.result(timeout=300)
+        rider.result(timeout=300)
+        steps = eng.dispatches_total["decode"] - steps_before
+    finally:
+        eng.shutdown()
+    log = tracer.log
+    passes = [(a, b) for name, a, b in log if name == ROOT]
+    hidden = 0
+    for a, b in passes:
+        inside = [e for e in log if e[0] != ROOT and a <= e[1] and e[2] <= b]
+        opened = lambda name: [e for e in inside if e[0] == name]
+        chunk, step = opened("engine.prefill_dispatch"), opened(
+            "engine.decode_dispatch")
+        walls = sorted(
+            (t0, t0 + wall) for _k, t0, wall in ticks if a <= t0 <= b
+        )
+        for (_s0, e0), (s1, _e1) in zip(walls, walls[1:]):
+            assert e0 <= s1 + 1e-9, (walls, "walls overlap")
+        assert sum(e - s for s, e in walls) <= (b - a) + 1e-9
+        assert all(e <= b + 1e-9 for _s, e in walls)
+        if len(chunk) != 1 or len(step) != 1:
+            continue  # no chunk, its last chunk (+ the insert), or no step
+        hidden += 1
+        (sync,) = opened("engine.prefill_sync")
+        (readback,) = opened("engine.decode_readback")
+        assert step[0][2] <= sync[1], "waited before dispatching the step"
+        assert sync[2] <= readback[1]
+    # LONG's four non-final chunks all rode with the rider's steps.
+    assert hidden == 4
+    assert waits.count("step") == 4
+    # ... its last chunk and the insert, and the rider's own chunk and
+    # insert, are read at once: nothing was queued behind them.
+    assert waits.count("none") == 4
+    # The same ticks the parent journals for these requests: one a chunk,
+    # one an insert, one a step; one read-back a step.
+    kinds = [k for k, _t0, _w in ticks]
+    assert kinds.count("prefill") == (5 + 1) + (1 + 1)
+    assert kinds.count("decode") == steps
+    assert set(kinds) == {"prefill", "decode"}
+    assert watchers["recorder"].ticks_recorded - ticks_before == len(kinds)
+    assert sum(1 for e in log if e[0] == "engine.decode_readback") == steps
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_watching_the_engine_does_not_change_its_tokens(tiny, cpu_peaks, mode):
+    """A recorder and telemetry are sinks: request for request the tokens
+    are those of the engine nobody watches."""
+    kw = MODES[mode][0]
+    served = []
+    for watchers in ({}, _watchers(cpu_peaks)):
+        eng = _engine(tiny, **kw, **watchers)
+        try:
+            served.append([list(map(int, out)) for out in _serve(eng)])
+        finally:
+            eng.shutdown()
+    assert served[0] == served[1]
+    assert all(len(out) == 12 for out in served[0])
+
+
+class _Wedged:
+    """Stands for a chunk's result whose program failed on the device:
+    the error surfaces where the host waits for it."""
+
+    def block_until_ready(self):
+        raise RuntimeError("injected device error")
+
+
+def test_a_chunk_failing_at_its_deferred_wait_fails_its_own_admission(tiny):
+    eng = _engine(tiny, prefill_chunk=8)
+    try:
+        (reference,) = _serve(eng, [PROMPTS[1]], new=6)
+        rider, started = _first_token_then(eng, PROMPTS[0], 40)
+        assert started.wait(timeout=120)
+        chunk_program = eng._prefill_one_chunk
+
+        def failing_once(*args):
+            eng._prefill_one_chunk = chunk_program
+            _logits, *rest = chunk_program(*args)
+            return (_Wedged(), *rest)
+
+        eng._prefill_one_chunk = failing_once
+        doc = eng.submit(LONG, 4)
+        with pytest.raises(RuntimeError, match="injected device error"):
+            doc.result(timeout=300)
+        # The step was already queued behind the chunk, so the failure
+        # surfaced inside ``_step``: the admission owns it all the same,
+        # and the slots go the way of any lost device state.
+        with pytest.raises(RuntimeError, match="generation step failed"):
+            rider.result(timeout=300)
+        assert eng._poison_counts == {eng._fingerprint(np.asarray(LONG)): 1}
+        (again,) = _serve(eng, [PROMPTS[1]], new=6)
+        assert not eng._pending and not eng._open_ticks
+    finally:
+        eng.shutdown()
+    assert list(again) == list(reference)
 
 
 def test_prefill_tokens_exclude_cached_prefix_tokens(tiny):

@@ -83,6 +83,9 @@ EXPECTED_SERVER = {
     # Real prompt tokens prefilled (cached-prefix tokens excluded);
     # exported as tpumlops_prefill_tokens_total.
     "tpumlops_prefill_tokens": ("counter", _IDENT),
+    # Non-decode ticks by whether a decode dispatch was queued behind the
+    # program when the engine thread waited for it ("step" | "none").
+    "tpumlops_prefill_waits": ("counter", _IDENT + ("queued_behind",)),
     # Engine on_token stamp -> the SSE event's write returning.
     "tpumlops_emit_lag_seconds": ("histogram", _IDENT),
     # Routed-expert traffic of a sparse-expert family by program (prefill

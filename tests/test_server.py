@@ -683,6 +683,32 @@ def test_streaming_observes_one_emit_lag_per_token(llm_server):
     assert p1 - p0 == 4
 
 
+def test_prefill_waits_count_one_a_non_decode_tick(llm_server):
+    """``tpumlops_prefill_waits_total{queued_behind}``: every prefill-side
+    dispatch is waited for once, behind a queued step or with nothing
+    behind it."""
+    def scrape():
+        text = httpx.get(llm_server.base + "/metrics", timeout=10).text
+        waits = [ln for ln in text.splitlines()
+                 if ln.startswith("tpumlops_prefill_waits_total{")]
+        assert all('queued_behind="step"' in ln or 'queued_behind="none"' in ln
+                   for ln in waits)
+        prefills = sum(
+            float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+            if ln.startswith("tpumlops_engine_dispatches_total{")
+            and 'op="decode"' not in ln)
+        return sum(float(ln.rsplit(" ", 1)[1]) for ln in waits), prefills
+
+    w0, p0 = scrape()
+    resp = httpx.post(
+        llm_server.base + "/v2/models/llm/generate",
+        json={"prompt_ids": [5, 9, 2, 7, 1], "max_new_tokens": 3}, timeout=60,
+    )
+    assert resp.status_code == 200
+    w1, p1 = scrape()
+    assert w1 - w0 == p1 - p0 >= 1
+
+
 def test_generate_streaming_rejects_multi_prompt(llm_server):
     resp = httpx.post(
         llm_server.base + "/v2/models/llm/generate",
