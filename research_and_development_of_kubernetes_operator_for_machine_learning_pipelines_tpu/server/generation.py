@@ -419,7 +419,7 @@ class GenerationEngine:
         on_prefill_tokens: Callable[[int], None] | None = None,
         tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
         family=None,  # the causal-LM family's module (None: models.llama)
-        on_moe: Callable[[str, int, int, int, int], None] | None = None,
+        on_moe: Callable[[str, dict, int, int], None] | None = None,
         on_prefill_wait: Callable[[str], None] | None = None,  # "step"|"none"
     ):
         import jax
@@ -450,8 +450,10 @@ class GenerationEngine:
         )
         # A family with routed experts pads a prompt chunk with an id it
         # does not route, and its programs return, behind llama's outputs,
-        # int32 [2]: the experts that got a real token and the row-tile
-        # visits of the grouped matmuls.
+        # its ``COUNTS`` as one int32 vector: the experts that got a real
+        # token, the row-tile visits of the grouped matmuls, the
+        # assignments that landed on an expert held here, the positions
+        # an indexer scored and kept.
         # ``_moe_pending`` holds (program, real tokens, token rows, device
         # counts) until a read-back the loop makes anyway.
         self._pad_id = lm.PAD_ID
@@ -2826,25 +2828,27 @@ class GenerationEngine:
     def _note_experts(self, program: str, tokens: int, rows: int, aux) -> None:
         """A routed family's program call over ``rows`` token rows, of
         which ``tokens`` were real; ``aux`` holds its on-device counts
-        (experts hit, row-tile visits).  Kept as a device value until
+        (the family's ``COUNTS``).  Kept as a device value until
         :meth:`_read_experts`."""
         if aux and self._on_moe is not None and not self._in_warmup:
             self._moe_pending.append((program, tokens, rows, aux[0]))
 
     def _read_experts(self) -> None:
-        """Hand the pending expert counts to ``on_moe(program,
-        assignments, activations, row_tile_visits, row_tile)``.  Called
-        where the loop has just read a later program's result back, so
-        every count here is already computed: no synchronisation of its
-        own."""
+        """Hand the pending counts to ``on_moe(program, counts, routed,
+        row_tile)``: the family's ``COUNTS`` by name, every (token,
+        expert) pair of the call's real tokens, the grouped matmuls' row
+        tile.  Called where the loop has just read a later program's
+        result back, so every count here is already computed: no
+        synchronisation of its own."""
         if not self._moe_pending:
             return
         pending, self._moe_pending = self._moe_pending, []
         for program, tokens, rows, counts in pending:
-            hit, visits = np.asarray(counts).tolist()
             self._on_moe(
-                program, self._lm.routed_assignments(self._cfg, tokens), hit,
-                visits, self._lm.moe_row_tile(self._cfg, rows),
+                program,
+                dict(zip(self._lm.COUNTS, np.asarray(counts).tolist())),
+                self._lm.routed_assignments(self._cfg, tokens),
+                self._lm.moe_row_tile(self._cfg, rows),
             )
 
     def _note_prefill_tokens(self, n: int) -> None:

@@ -200,7 +200,16 @@ def _build_config(flavor: str, config_dict: dict) -> Any:
         return None
     cls = getattr(_model_module(flavor), _CONFIG_CLASSES[flavor][1])
     known = {f for f in cls.__dataclass_fields__}
-    return cls(**{k: v for k, v in config_dict.items() if k in known})
+    unknown = sorted(set(config_dict) - known)
+    if unknown:
+        # A key this program does not know names a variant of the model
+        # it does not implement: dropped, the artifact would be served as
+        # another model.
+        raise ValueError(
+            f"{flavor} artifact config has keys this program does not "
+            f"know: {unknown}; it would be served as another model"
+        )
+    return cls(**config_dict)
 
 
 def _shard_for_flavor(flavor: str, params: Any, cfg: Any, mesh_shape: dict) -> Any:

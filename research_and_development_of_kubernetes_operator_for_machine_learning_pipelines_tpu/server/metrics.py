@@ -206,8 +206,34 @@ class ServerMetrics:
         # row tiles are that the grouped matmuls multiply.
         self.moe_assignments = Counter(
             "tpumlops_moe_assignments_total",
-            "(token, expert) pairs routed: real tokens x experts per "
-            "token x expert layers",
+            "(token, expert) pairs routed to an expert this replica "
+            "holds (every pair where it holds them all: real tokens x "
+            "experts per token x expert layers), counted on the device",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.moe_assignments_routed_away = Counter(
+            "tpumlops_moe_assignments_routed_away_total",
+            "(token, expert) pairs routed to an expert held elsewhere "
+            "(an expert share): left out of this replica's result",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        # Indexed sparse attention: selected / scored is how sparse the
+        # traffic made the attention of the full-attention layers.
+        self.dsa_keys_scored = Counter(
+            "tpumlops_dsa_keys_scored_total",
+            "Cached positions the indexers scored, summed over real "
+            "query rows and indexed layers (the finite index scores, "
+            "counted on the device)",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.dsa_keys_selected = Counter(
+            "tpumlops_dsa_keys_selected_total",
+            "Cached positions the selection kept for the softmax, "
+            "counted on the device from what it kept (min(position + 1, "
+            "index_topk) a query a layer while it works)",
             ident_labels + ["program"],
             registry=self.registry,
         )
@@ -707,13 +733,20 @@ class ServerMetrics:
             **self.identity, queued_behind=queued_behind
         ).inc()
 
-    def inc_moe(self, program: str, assignments: int, activations: int,
-                row_tile_visits: int, row_tile: int):
+    def inc_moe(self, program: str, counts: dict, routed: int, row_tile: int):
+        """One call of a routed family's ``program``: ``counts`` is what
+        the device counted, by the family's ``COUNTS`` names; ``routed``
+        every (token, expert) pair of the call's real tokens, wherever
+        the expert is held."""
         labels = dict(self.identity, program=program)
-        self.moe_assignments.labels(**labels).inc(assignments)
-        self.moe_expert_activations.labels(**labels).inc(activations)
-        self.moe_row_tile_visits.labels(**labels).inc(row_tile_visits)
+        local = counts["local_assignments"]
+        self.moe_assignments.labels(**labels).inc(local)
+        self.moe_assignments_routed_away.labels(**labels).inc(routed - local)
+        self.moe_expert_activations.labels(**labels).inc(counts["experts_hit"])
+        self.moe_row_tile_visits.labels(**labels).inc(counts["row_tile_visits"])
         self.moe_row_tile_rows.labels(**labels).set(row_tile)
+        self.dsa_keys_scored.labels(**labels).inc(counts["dsa_keys_scored"])
+        self.dsa_keys_selected.labels(**labels).inc(counts["dsa_keys_selected"])
 
     def observe_emit_lag(self, seconds: float):
         self.emit_lag.labels(**self.identity).observe(seconds)

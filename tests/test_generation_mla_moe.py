@@ -97,7 +97,11 @@ def test_engine_tokens_equal_the_reference_as_requests_join_and_leave(
     # and warm-up counted nothing.
     fan = CFG.num_experts_per_tok * CFG.num_moe_layers
     by = {"prefill": [0, 0], "decode": [0, 0]}
-    for program, assignments, activations, visits, tile in seen:
+    for program, counts, routed, tile in seen:
+        assert tuple(counts) == mla_moe.COUNTS
+        assignments, activations, visits = (
+            counts["local_assignments"], counts["experts_hit"], counts["row_tile_visits"])
+        assert assignments == routed  # every expert is held here
         by[program][0] += assignments
         by[program][1] += activations
         assert 0 < activations <= min(assignments, CFG.num_moe_layers * CFG.n_routed_experts)
@@ -209,7 +213,8 @@ def test_ledger_counts_experts_rest_and_latent_cache(params, cpu_peaks):
         kv_cache_bytes_per_row,
     )
 
-    row = CFG.num_layers * CFG.max_seq * (CFG.kv_lora_rank + CFG.qk_rope_head_dim) * 2
+    # The RoPE key's row is padded to the chip's 128 lanes.
+    row = CFG.num_layers * CFG.max_seq * (CFG.kv_lora_rank + mla_moe.LANES) * 2
     assert kv_cache_bytes_per_row(CFG, kv_quant=False, family=mla_moe) == row
     ledger = build_hbm_ledger(params, CFG, max_slots=4, family=mla_moe)
     comps = ledger.components
@@ -221,7 +226,7 @@ def test_ledger_counts_experts_rest_and_latent_cache(params, cpu_peaks):
     assert comps["kv_cache"] == 4 * row
     # What the engine allocates for the cache is what the ledger says.
     cache = mla_moe.RaggedKVCache.create(CFG, 4)
-    assert cache.k.nbytes + cache.v.nbytes == comps["kv_cache"]
+    assert sum(buf.nbytes for buf in jax.tree.leaves((cache.k, cache.v))) == comps["kv_cache"]
     assert ledger.device_total() == tree + 4 * row + comps["sampling_state"]
 
     active, total = mla_moe.param_counts(CFG)
@@ -233,7 +238,9 @@ def test_ledger_counts_experts_rest_and_latent_cache(params, cpu_peaks):
     _, b_all = cost.decode(4096, 16)
     one = cost.moe_layers * CFG.num_experts_per_tok * cost.expert_bytes
     assert cost.unrouted_bytes + one <= b1 < cost.unrouted_bytes + one + 4096
-    assert b_all - cost.num_layers * cost.cache_row_bytes * 4096 * 17 == pytest.approx(
+    (layers, _pair_flops, row_bytes, most), = cost.attn  # one layer kind, no limit
+    assert (layers, most) == (CFG.num_layers, 0) and cost.index == (0, 0, 0)
+    assert b_all - layers * row_bytes * 4096 * 17 == pytest.approx(
         cost.unrouted_bytes + routed)
     assert f1 > 2 * active
     flops, nbytes = cost.prefill(1, 8, attended=12)
@@ -254,9 +261,12 @@ def test_moe_counter_families_on_the_registry():
     from tpumlops.server.metrics import ServerMetrics
 
     m = ServerMetrics(deployment_name="d", predictor_name="p", namespace="n")
-    m.inc_moe("prefill", 4096 * 4, 1024, 1140, 128)
-    m.inc_moe("decode", 64 * 4, 228, 230, 16)
-    m.inc_moe("decode", 64 * 4, 226, 226, 16)
+    def counts(hit, visits, local):
+        return dict(zip(mla_moe.COUNTS, (hit, visits, local, 0, 0)))
+
+    m.inc_moe("prefill", counts(1024, 1140, 4096 * 4), 4096 * 4, 128)
+    m.inc_moe("decode", counts(228, 230, 64 * 4), 64 * 4, 16)
+    m.inc_moe("decode", counts(226, 226, 64 * 4), 64 * 4, 16)
     text = generate_latest(m.registry).decode()
     for family, program, value in (
         ("tpumlops_moe_assignments_total", "prefill", 16384.0),

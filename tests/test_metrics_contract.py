@@ -92,6 +92,11 @@ EXPECTED_SERVER = {
     # | decode): (token, expert) pairs, and experts that got a real token.
     "tpumlops_moe_assignments": ("counter", _IDENT + ("program",)),
     "tpumlops_moe_expert_activations": ("counter", _IDENT + ("program",)),
+    # An expert share: the pairs routed to experts held elsewhere.
+    "tpumlops_moe_assignments_routed_away": ("counter", _IDENT + ("program",)),
+    # Indexed sparse attention: positions scored and kept, by program.
+    "tpumlops_dsa_keys_scored": ("counter", _IDENT + ("program",)),
+    "tpumlops_dsa_keys_selected": ("counter", _IDENT + ("program",)),
     # (expert, row tile) visits of the grouped matmuls' schedule, and the
     # static rows a visit multiplies: assignments / (visits x rows) is how
     # full the tiles are.

@@ -502,9 +502,19 @@ def test_sparse_expert_programs_read_the_experts_where_they_lie(one_chip, progra
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 0.8 * HBM
     # The largest expert tensor is 805 MB: a copy of one would show here.
     assert mem.temp_size_in_bytes < 400 * 2**20, mem.temp_size_in_bytes
-    cache_bytes = sum(a.size * a.dtype.itemsize for a in args[2:4])
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(args[2:4]))
     assert mem.alias_size_in_bytes >= cache_bytes
     text = compiled.as_text()
+    # PERF.md 7.8(f), closed in PR 33: no buffer of the latent cache (one
+    # a layer) is copied or relaid whole on the way in or out.
+    # (The step's slot cache: a chunk's [512, 2048] activations have the
+    # element count of the batch-1 scratch's [1, 2048, 512] latents.)
+    whole = {math.prod(a.shape) for a in jax.tree.leaves(args[2:4])}
+    for name, dims, opcode in _array_instructions(text if program == "decode" else ""):
+        relayout = opcode in ("copy", "copy-start", "transpose") or (
+            opcode == "fusion" and ("copy" in name or "transpose" in name))
+        assert not (relayout and math.prod(dims) in whole), (
+            f"{name}: a whole-buffer {opcode} of {dims}")
     # Three grouped matmuls in each of the four expert layers, each the
     # repo's own kernel (ops/grouped_matmul.py) under the expert layer's
     # scope, and none left to XLA's 512-row lowering of ragged_dot.
@@ -534,3 +544,96 @@ def test_grouped_matmul_lowers_at_the_published_expert_widths(one_chip, rows, k,
     tm = gm.row_tile(rows, 256)
     assert gm._col_tile(k, n, tm, 2) == n  # the matrix is not split
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def _dots3_cfg():
+    """``benchmarks/configs/dots3-note-prev-bf16.json`` as the program
+    reads it: published widths, 5 of 46 layers (full, full, sliding x 3),
+    32 of 256 experts, 19008 of 152064 vocabulary rows, 8704 positions."""
+    from tpumlops.models import mla_moe
+
+    f, s = mla_moe.FULL, mla_moe.SLIDING
+    return mla_moe.MlaMoeConfig(
+        vocab_size=19008, hidden_size=5120, num_layers=5, num_heads=128,
+        q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=13824,
+        moe_intermediate_size=1536, n_routed_experts=256, n_shared_experts=1,
+        num_experts_per_tok=8, first_k_dense_replace=1,
+        routed_scaling_factor=1.0, max_seq=8704, rope_theta=8e7, rms_eps=1e-5,
+        layer_types=(f, f, s, s, s), sliding_window=513, swa_num_heads=64,
+        swa_q_lora_rank=1024, swa_kv_lora_rank=1024, swa_qk_nope_head_dim=192,
+        swa_qk_rope_head_dim=64, swa_v_head_dim=128, swa_rope_theta=5e4,
+        index_n_heads=64, index_head_dim=128, index_topk=2048,
+        attention_gate="headwise", lora_rescale=True,
+        n_local_experts=32, local_expert_start=0,
+    )
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_two_kind_programs_leave_every_row_kind_in_place(one_chip, program):
+    """The indexed / sliding configuration at the benchmark's published
+    widths (8 slots x 8704 positions, chunk 512): the decode step at its
+    widest window and the prefill chunk fit the chip beside 8.18 GB of
+    bf16 weights; every donated cache buffer of every row kind (the full
+    layers' RoPE key, latent and index key, the sliding layers' ring)
+    aliases and none is copied or relaid whole; the chunk program holds no
+    ``[heads, chunk, capacity]`` float32 scores (2.2 GB at 128 heads) nor
+    anything that size; the expert matmuls are the kernel at 32 groups."""
+    from tpumlops.models import mla_moe
+
+    cfg = _dots3_cfg()
+    params = _on(one_chip, jax.eval_shape(
+        lambda: mla_moe.init(jax.random.key(0), cfg, jnp.bfloat16)))
+    if program == "decode":
+        cache = _on(one_chip, jax.eval_shape(
+            lambda: mla_moe.RaggedKVCache.create(cfg, 8)))
+
+        def fn(params, toks, k, v, lengths, active):
+            logits, c, counts = mla_moe.decode_ragged(
+                params, toks, mla_moe.RaggedKVCache(k, v, lengths), cfg,
+                active=active, window=8704)
+            return jnp.argmax(logits[:, -1], -1), c.k, c.v, c.lengths, counts
+
+        args = (params, _sds(one_chip, (8, 1), jnp.int32), cache.k, cache.v,
+                cache.lengths, _sds(one_chip, (8,), jnp.bool_))
+    else:
+        seq = _on(one_chip, jax.eval_shape(lambda: mla_moe.KVCache.create(cfg, 1)))
+
+        def fn(params, ids, sk, sv, slen):
+            logits, s, counts = mla_moe.forward(
+                params, ids, mla_moe.KVCache(sk, sv, slen), cfg)
+            return logits[0], s.k, s.v, s.length, counts
+
+        args = (params, _sds(one_chip, (1, 512), jnp.int32), seq.k, seq.v,
+                seq.length)
+    compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    weights = 2 * mla_moe.param_counts(cfg)[1]
+    assert weights == 8_174_174_208  # ISSUE 33's table: 8.18e9 bytes (47.6 % of 16 GiB)
+    # A position of the two full layers: latent 512 + RoPE key 64 (held
+    # in a row of 128 lanes) + index key 128, bf16; three rings of 640.
+    assert mla_moe.kv_row_bytes(cfg) == 8704 * 2 * 2 * (512 + 128 + 128) + 3 * 640 * 2 * (1024 + 128)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 0.8 * HBM
+    buffers = jax.tree.leaves(args[2:4])
+    assert len(buffers) == 2 * 3 + 3 * 2  # a layer: rope, index, latent; ring rope, ring latent
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in buffers)
+    assert mem.alias_size_in_bytes >= cache_bytes, "a row kind's donation not credited"
+    # 128 heads x 512 queries x 8704 positions of float32 scores would be
+    # 2.28 GB; a key block's are 134 MB, and a few live at once.
+    assert mem.temp_size_in_bytes < 1.5 * 2**30, mem.temp_size_in_bytes
+    whole = {math.prod(a.shape) for a in buffers}
+    for name, dims, opcode in _array_instructions(compiled.as_text()):
+        relayout = opcode in ("copy", "copy-start", "transpose") or (
+            opcode == "fusion" and ("copy" in name or "transpose" in name))
+        assert not (relayout and math.prod(dims) in whole), (
+            f"{name}: a whole-buffer {opcode} of {dims}")
+        assert not (opcode != "parameter" and 8704 in dims
+                    and math.prod(dims) >= 128 * 512 * 8704), (
+            f"{name}: {dims} is a capacity-wide score tensor")
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 12
+    assert all("layer.moe_experts" in l and "grouped_matmul" in l for l in calls)
+    for scope in ("layer.dsa_index", "layer.dsa_select", "layer.attn_gate",
+                  "layer.attn_core"):
+        assert scope in text, scope
