@@ -88,9 +88,15 @@ Design decisions:
   selection where it bites; the selection is exact and is
   ``lax.top_k``'s without its sort: the k-th largest score found by
   bisection on the float's ordered integer image, a tie at it going to
-  the lower position.  A capacity of at most ``ONE_PASS``
-  positions is one block and a plain softmax.  Decode selects with
-  ``lax.top_k`` and gathers the kept rows.
+  the lower position.  On the TPU the core of either layer kind is
+  ``ops.prefill_attention``, a Pallas kernel in which a key block's
+  float32 scores never leave VMEM (XLA wrote and re-read 134 MB of them
+  a block, PERF.md 5), at every shape its tiles take (a chunk, a bucket
+  of 16 queries or more over key blocks of whole lanes); elsewhere, and
+  off the TPU, the einsum body: blocks with a running maximum and sum,
+  or, over a capacity of at most ``ONE_PASS`` positions, one block and a
+  plain softmax.  Decode selects with ``lax.top_k`` and gathers the kept
+  rows.
 - An expert layer may hold a SHARE of the routed experts
   (``n_local_experts`` from ``local_expert_start``): the router scores
   all ``n_routed_experts``, assignments to experts held elsewhere sort
@@ -115,6 +121,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.grouped_matmul import grouped_matmul, row_tile, row_tile_schedule
+from ..ops.prefill_attention import prefill_attention
 from .common import rms_norm
 from .llama import _attended_window, _embed, _head, _qmatmul
 
@@ -885,49 +892,76 @@ def _top_mask(x: jax.Array, k: int) -> jax.Array:
 
 
 def _key_block(keys: int) -> int:
-    """Key positions a block of ``_attn_blocks`` holds: all of them up to
-    ``ONE_PASS``, else the largest divisor of ``keys`` at or under
-    ``KEY_BLOCK`` (the engine keeps a capacity a multiple of the prefill
-    chunk, so a divisor of that size exists where it matters)."""
+    """Key positions a block of ``_attn_blocks``'s einsum body holds: all
+    of them up to ``ONE_PASS``, else the largest divisor of ``keys`` at
+    or under ``KEY_BLOCK`` (the engine keeps a capacity a multiple of the
+    prefill chunk, so a divisor of that size exists where it matters)."""
     if keys <= ONE_PASS:
         return keys
+    return _key_tile(keys)
+
+
+def _key_tile(keys: int) -> int:
+    """The largest divisor of ``keys`` at or under ``KEY_BLOCK``: the
+    keys a grid step of the fused core attends, whatever ``ONE_PASS``
+    says (the kernel walks the written blocks of any capacity)."""
     return max(d for d in range(1, KEY_BLOCK + 1) if keys % d == 0)
 
 
-def _attn_blocks(q_nope, q_rope, block, n_blocks, lp, cfg):
-    """Attention of ``S`` queries over key blocks, keys and values
-    expanded from the latent a block at a time: ``block(j)`` gives block
-    ``j``'s RoPE keys ``[B,K,LANES]`` (as cached), latents ``[B,K,rank]`` and which of
-    its keys each query sees, bool ``[B or 1, S, K]``; ``n_blocks`` (an
-    int or a traced scalar) is how many to walk.  One block is a plain
-    softmax; more keep a running maximum and sum.  A query must see at
-    least one key somewhere.  Returns ctx [B,S,NH*v]."""
+def prefill_key_blocks(cfg: MlaMoeConfig, start: int, tokens: int) -> tuple[int, int]:
+    """``(walked, skipped)``: of the capacity's key blocks, summed over
+    the full-attention layers, those a prefill call of ``tokens`` rows
+    from position ``start`` multiplies (the ones that hold a written
+    position) and those it does not reach.  Host arithmetic for
+    ``tpumlops_prefill_key_blocks_total``; a sliding layer attends its
+    window whole and has no capacity to skip."""
+    kb = _key_tile(cfg.max_seq)
+    walked = min(-(-(int(start) + int(tokens)) // kb), cfg.max_seq // kb)
+    layers = len(cfg.full_layers)
+    return layers * walked, layers * (cfg.max_seq // kb - walked)
+
+
+def _attn_blocks(q_nope, q_rope, keys_kr, keys_c, sees, written, lp, cfg):
+    """Attention of ``S`` queries over the first ``written`` of ``T`` key
+    positions (an int or a traced scalar: no query sees a later one),
+    keys and values expanded from the latent a block at a time:
+    ``keys_kr`` [B,T,LANES] the RoPE keys as cached, ``keys_c``
+    [B,T,rank] the latents, ``sees`` bool [B or 1, S, T] which keys each
+    query sees (at least one).  On the TPU the fused core of
+    ``ops/prefill_attention.py`` at the shapes its tiles take; else, and
+    off it, the einsum body here: one block is a plain softmax, more
+    keep a running maximum and sum.  Returns ctx [B,S,NH*v]."""
     b, s = q_nope.shape[:2]
     dt = q_nope.dtype
     nh, vd = cfg.num_heads, cfg.v_head_dim
-    scale = 1.0 / math.sqrt(cfg.qk_head_dim)
-    w_kvb = _kv_b(lp, cfg, dt)
+    t = keys_c.shape[1]
+    kb = _key_block(t)
+    z = jnp.zeros((), jnp.int32)
 
-    def scores_of(j):
-        kr, c, sees = block(j)
-        kr = kr[..., :cfg.qk_rope_head_dim]
-        kv = jnp.einsum(
-            "btc,cnd->btnd", c.astype(dt), w_kvb,
-            preferred_element_type=jnp.float32,
-        ).astype(dt)
-        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
-        sc = jnp.einsum(
-            "bqnd,bknd->bnqk", q_nope, k_nope, preferred_element_type=jnp.float32
-        ) + jnp.einsum(
-            "bqnd,bkd->bnqk", q_rope, kr.astype(dt),
-            preferred_element_type=jnp.float32,
-        )
-        return sc * scale, sees[:, None], v
+    def einsums(q_nope, q_rope, keys_kr, keys_c, w_kvb, sees, written):
+        w_kvb = _kv_b({"kv_b": w_kvb}, cfg, dt)
 
-    with jax.named_scope("layer.attn_core"):
-        if isinstance(n_blocks, int) and n_blocks == 1:
-            sc, sees, v = scores_of(0)
-            probs = jax.nn.softmax(jnp.where(sees, sc, -1e9), axis=-1).astype(dt)
+        def scores_of(j):
+            lo = j * kb
+            kr = _layer_rows(keys_kr, lo, kb)[..., :cfg.qk_rope_head_dim]
+            c = _layer_rows(keys_c, lo, kb)
+            see = lax.dynamic_slice(sees, (z, z, lo), (*sees.shape[:2], kb))
+            kv = jnp.einsum(
+                "btc,cnd->btnd", c.astype(dt), w_kvb,
+                preferred_element_type=jnp.float32,
+            ).astype(dt)
+            k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+            sc = jnp.einsum(
+                "bqnd,bknd->bnqk", q_nope, k_nope, preferred_element_type=jnp.float32
+            ) + jnp.einsum(
+                "bqnd,bkd->bnqk", q_rope, kr.astype(dt),
+                preferred_element_type=jnp.float32,
+            )
+            return sc * scale, see[:, None], v
+
+        if kb == t:
+            sc, see, v = scores_of(0)
+            probs = jax.nn.softmax(jnp.where(see, sc, -1e9), axis=-1).astype(dt)
             ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v)
             return ctx.reshape(b, s, nh * vd)
 
@@ -935,9 +969,9 @@ def _attn_blocks(q_nope, q_rope, block, n_blocks, lp, cfg):
 
         def step(j, carry):
             top, total, acc = carry
-            sc, sees, v = scores_of(j)
-            top2 = jnp.maximum(top, jnp.max(jnp.where(sees, sc, low), axis=-1))
-            p = jnp.where(sees, jnp.exp(sc - top2[..., None]), 0.0)
+            sc, see, v = scores_of(j)
+            top2 = jnp.maximum(top, jnp.max(jnp.where(see, sc, low), axis=-1))
+            p = jnp.where(see, jnp.exp(sc - top2[..., None]), 0.0)
             keep = jnp.exp(top - top2)
             acc = acc * keep[..., None] + jnp.einsum(
                 "bnqk,bknd->bnqd", p.astype(dt), v,
@@ -946,12 +980,18 @@ def _attn_blocks(q_nope, q_rope, block, n_blocks, lp, cfg):
             return top2, total * keep + p.sum(-1), acc
 
         _top, total, acc = lax.fori_loop(
-            0, n_blocks, step,
+            0, (written + kb - 1) // kb, step,
             (jnp.full((b, nh, s), low), jnp.zeros((b, nh, s), jnp.float32),
              jnp.zeros((b, nh, s, vd), jnp.float32)),
         )
         ctx = acc / jnp.maximum(total, 1e-30)[..., None]
         return ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * vd).astype(dt)
+
+    scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+    with jax.named_scope("layer.attn_core"):
+        return prefill_attention(
+            q_nope, q_rope, keys_kr, keys_c, lp["kv_b"], sees, written,
+            key_block=_key_tile(t), scale=scale, fallback=einsums)
 
 
 def _attn_absorbed(q_nope, q_rope, kr_new, c_new, ck, cv, mask_bias, lp, cfg,
@@ -1184,15 +1224,11 @@ def forward(
                     picked = _dsa_all_kept(positions[None, :], valid)
                 dsa = dsa + picked
 
-            def block(j, i=i, kept=kept):
-                lo = j * kb
-                sees = ((lo + jnp.arange(kb))[None, :] <= positions[:, None])[None]
-                if kept is not None:
-                    sees = sees & lax.dynamic_slice(kept, (z, z, lo), (b, s, kb))
-                return (_layer_rows(k["rope"][i], lo, kb),
-                        _layer_rows(v["latent"][i], lo, kb), sees)
-
-            ctx = _attn_blocks(q_nope, q_rope, block, n_blocks, lp, kc)
+            sees = (jnp.arange(capacity)[None, :] <= positions[:, None])[None]
+            if kept is not None:
+                sees = sees & kept
+            ctx = _attn_blocks(q_nope, q_rope, k["rope"][i], v["latent"][i],
+                               sees, start + s, lp, kc)
         else:
             # The ring's rows of the window - 1 positions before the
             # chunk, then the chunk's own.
@@ -1203,19 +1239,10 @@ def forward(
             keys_c = jnp.concatenate(
                 [take(v["ring_latent"]).astype(c.dtype), c], axis=1)
             key_pos = jnp.concatenate([before, positions])
-            n_keys = window - 1 + s
-            skb = _key_block(n_keys)
-
-            def block(j, keys_kr=keys_kr, keys_c=keys_c, key_pos=key_pos, skb=skb):
-                lo = j * skb
-                kp = lax.dynamic_slice(key_pos, (lo,), (skb,))[None, :]
-                qp = positions[:, None]
-                sees = (kp >= 0) & (kp <= qp) & (qp - kp < window)
-                return (lax.dynamic_slice_in_dim(keys_kr, lo, skb, axis=1),
-                        lax.dynamic_slice_in_dim(keys_c, lo, skb, axis=1),
-                        sees[None])
-
-            ctx = _attn_blocks(q_nope, q_rope, block, n_keys // skb, lp, kc)
+            kp, qp = key_pos[None, :], positions[:, None]
+            sees = ((kp >= 0) & (kp <= qp) & (qp - kp < window))[None]
+            ctx = _attn_blocks(q_nope, q_rope, keys_kr, keys_c, sees,
+                               window - 1 + s, lp, kc)
             # The ring takes the chunk's rows when the layer has read it.
             rows = jnp.arange(b)[:, None]
             with jax.named_scope("kv_commit"):

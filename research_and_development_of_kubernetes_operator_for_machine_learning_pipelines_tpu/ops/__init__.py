@@ -10,11 +10,17 @@ plane that plain XLA does not give:
 - ``grouped_matmul``   — the sparse-expert FFN's matmuls over token copies
   sorted by expert: a row tile that follows from the call's static shapes,
   only the (expert, row tile) pairs that share a row visited, each
-  expert's matrix read once (the one Pallas kernel on a family's default
-  path; import it from ``ops.grouped_matmul``, whose module also holds the
-  tile choice and the visit schedule).  Off the TPU it is
-  ``lax.ragged_dot``, which is also its oracle in tests (the kernel runs
-  in interpret mode on CPU).
+  expert's matrix read once (import it from ``ops.grouped_matmul``, whose
+  module also holds the tile choice and the visit schedule).  Off the TPU
+  it is ``lax.ragged_dot``, which is also its oracle in tests (the kernel
+  runs in interpret mode on CPU).
+- ``prefill_attention`` — the softmax core of the latent-attention
+  family's prefill: keys and values expanded from the latent, scores,
+  mask, running maximum, sum and accumulator of a key block in VMEM, only
+  the written key blocks walked (``ops.prefill_attention``).  Off the TPU,
+  and at shapes its tiles do not take, it is the caller's einsum body,
+  its oracle in tests.  These two are the Pallas kernels on a family's
+  default path.
 """
 
 from .ring_attention import ring_attention, ring_attention_sharded

@@ -421,6 +421,7 @@ class GenerationEngine:
         family=None,  # the causal-LM family's module (None: models.llama)
         on_moe: Callable[[str, dict, int, int], None] | None = None,
         on_prefill_wait: Callable[[str], None] | None = None,  # "step"|"none"
+        on_key_blocks: Callable[[int, int], None] | None = None,  # walked, skipped
     ):
         import jax
         import jax.numpy as jnp
@@ -458,6 +459,11 @@ class GenerationEngine:
         # counts) until a read-back the loop makes anyway.
         self._pad_id = lm.PAD_ID
         self._on_moe = on_moe
+        # A family whose prefill core walks the written key blocks of its
+        # capacity says how many a chunk walks and skips
+        # (``prefill_key_blocks``): host arithmetic at the dispatch.
+        self._key_blocks = getattr(lm, "prefill_key_blocks", None)
+        self._on_key_blocks = on_key_blocks
         self._moe_pending: list = []
         self._params = params
         self._cfg = cfg
@@ -3853,6 +3859,8 @@ class GenerationEngine:
             self.prefill_chunks_dispatched += 1
             self.prefill_forwards += 1
             self._note_prefill_tokens(self._chunk_tokens([prog]))
+            if self._key_blocks is not None and self._on_key_blocks is not None:
+                self._on_key_blocks(*self._key_blocks(self._cfg, offset, C))
             # The chunk's logits: the next chunk donates the scratch, not
             # these.
             self._open_tick(

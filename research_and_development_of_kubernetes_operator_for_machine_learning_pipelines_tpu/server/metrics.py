@@ -199,6 +199,16 @@ class ServerMetrics:
             ident_labels + ["queued_behind"],
             registry=self.registry,
         )
+        self.prefill_key_blocks = Counter(
+            "tpumlops_prefill_key_blocks_total",
+            "Key blocks of the cache's capacity, summed over the "
+            "full-attention layers of a family whose prefill core walks "
+            "the written ones: those a dispatched chunk multiplied "
+            "(walked) and those it did not reach (skipped); host "
+            "arithmetic from the chunk's offset",
+            ident_labels + ["kind"],
+            registry=self.registry,
+        )
         # Routed-expert traffic of a sparse-expert family, by program
         # (prefill | decode): assignments / activations is the mean
         # number of tokens an expert that was read got to work on;
@@ -732,6 +742,10 @@ class ServerMetrics:
         self.prefill_waits.labels(
             **self.identity, queued_behind=queued_behind
         ).inc()
+
+    def inc_prefill_key_blocks(self, walked: int, skipped: int):
+        self.prefill_key_blocks.labels(**self.identity, kind="walked").inc(walked)
+        self.prefill_key_blocks.labels(**self.identity, kind="skipped").inc(skipped)
 
     def inc_moe(self, program: str, counts: dict, routed: int, row_tile: int):
         """One call of a routed family's ``program``: ``counts`` is what

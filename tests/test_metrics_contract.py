@@ -86,6 +86,9 @@ EXPECTED_SERVER = {
     # Non-decode ticks by whether a decode dispatch was queued behind the
     # program when the engine thread waited for it ("step" | "none").
     "tpumlops_prefill_waits": ("counter", _IDENT + ("queued_behind",)),
+    # Key blocks of the capacity a prefill chunk of the latent-attention
+    # family multiplied ("walked") and did not reach ("skipped").
+    "tpumlops_prefill_key_blocks": ("counter", _IDENT + ("kind",)),
     # Engine on_token stamp -> the SSE event's write returning.
     "tpumlops_emit_lag_seconds": ("histogram", _IDENT),
     # Routed-expert traffic of a sparse-expert family by program (prefill

@@ -46,6 +46,23 @@ def small_key_blocks(monkeypatch):
     monkeypatch.setattr(mla_moe, "ONE_PASS", 16)
 
 
+@pytest.fixture(params=["einsums", "fused_core"])
+def attn_core(request, monkeypatch):
+    """The prefill softmax core of both layer kinds: the einsum body the
+    CPU takes, or ``ops/prefill_attention.py``'s kernel in interpret mode
+    (the same key blocks of 16, the selection's mask and the window rule
+    as its ``sees``), where the chip would run it compiled."""
+    if request.param == "fused_core":
+        import functools
+
+        from tpumlops.ops.prefill_attention import prefill_attention
+
+        monkeypatch.setattr(
+            mla_moe, "prefill_attention",
+            functools.partial(prefill_attention, interpret=True))
+    return request.param
+
+
 def _load(name):
     if str(BENCH) not in sys.path:
         sys.path.append(str(BENCH))
@@ -150,7 +167,7 @@ def test_an_artifact_of_another_variant_fails_to_load(flavor):
         loader._build_config(flavor, {"layer_kinds_v2": []})
 
 
-def test_full_forward_logits_equal_the_reference(params, toks, want):
+def test_full_forward_logits_equal_the_reference(params, toks, want, attn_core):
     """One prefill of 48 positions: the indexer drops keys from position
     8 on, the window from position 5 on, and the capacity's four key
     blocks are walked as far as written (three)."""
@@ -208,7 +225,7 @@ def test_kth_largest_is_exact():
 
 @pytest.mark.parametrize("chunk", [8, 16, 32])
 def test_chunked_prefill_then_ragged_decode_equals_the_full_forward(
-        params, toks, want, chunk):
+        params, toks, want, chunk, attn_core):
     """A prompt of 27 tokens in chunks (the last one padded with ids
     < 0) into the batch-1 scratch, inserted into slot 1 of a 3-slot
     cache, then 16 single-token steps: every step's logits are the full

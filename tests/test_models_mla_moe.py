@@ -86,7 +86,23 @@ def want(want_and_hit):
     return want_and_hit[0]
 
 
-def test_full_forward_logits_equal_the_reference(params, toks, want_and_hit):
+@pytest.fixture(params=["einsums", "fused_core"])
+def attn_core(request, monkeypatch):
+    """The prefill softmax core: the einsum body the CPU takes, or
+    ``ops/prefill_attention.py``'s kernel in interpret mode over key
+    blocks of 16 (the capacity of 64 is four of them, walked as far as
+    written), where the chip would run it compiled over blocks of 512."""
+    if request.param == "fused_core":
+        from tpumlops.ops.prefill_attention import prefill_attention
+
+        monkeypatch.setattr(mla_moe, "KEY_BLOCK", 16)
+        monkeypatch.setattr(
+            mla_moe, "prefill_attention",
+            functools.partial(prefill_attention, interpret=True))
+    return request.param
+
+
+def test_full_forward_logits_equal_the_reference(params, toks, want_and_hit, attn_core):
     want, hit = want_and_hit
     logits, cache, counts = mla_moe.prefill(params, jnp.asarray(toks), CFG, jnp.float32)
     np.testing.assert_allclose(np.asarray(logits), want, atol=2e-6)
@@ -95,7 +111,8 @@ def test_full_forward_logits_equal_the_reference(params, toks, want_and_hit):
     assert 0 < int(counts[0]) == hit <= CFG.num_moe_layers * CFG.n_routed_experts
 
 
-def test_chunked_prefill_then_ragged_decode_equals_the_full_forward(params, toks, want):
+def test_chunked_prefill_then_ragged_decode_equals_the_full_forward(
+        params, toks, want, attn_core):
     """A prompt of 13 tokens in chunks of 8 (the last one padded with ids
     < 0) into the batch-1 scratch, inserted into slot 2 of a 4-slot latent
     cache, then teacher-forced decode steps: the logits at every position
@@ -318,3 +335,33 @@ def test_param_counts_at_the_published_widths():
 def test_config_rejects_what_the_layers_cannot_be(bad):
     with pytest.raises(ValueError):
         mla_moe.MlaMoeConfig.tiny(**bad)
+
+
+def test_the_engine_counts_the_key_blocks_a_chunk_walks_and_skips(params, monkeypatch):
+    """``tpumlops_prefill_key_blocks_total`` through the engine: a prompt
+    of 27 tokens in chunks of 8 over a capacity of 64 positions in key
+    blocks of 16, three full-attention layers.  Chunk n (offset 8 n)
+    walks ceil((8 n + 8) / 16) blocks a layer and skips the rest of the
+    four: walked + skipped = layers x blocks of the capacity, a chunk."""
+    from tpumlops.server.generation import GenerationEngine
+    from tpumlops.server.metrics import ServerMetrics
+
+    monkeypatch.setattr(mla_moe, "KEY_BLOCK", 16)
+    assert mla_moe.prefill_key_blocks(CFG, 0, 8) == (3 * 1, 3 * 3)
+    assert mla_moe.prefill_key_blocks(CFG, 56, 8) == (3 * 4, 0)
+    metrics = ServerMetrics(deployment_name="d", predictor_name="p", namespace="n")
+    engine = GenerationEngine(
+        params, CFG, max_slots=1, dtype=jnp.float32, family=mla_moe,
+        prefill_chunk=8, on_key_blocks=metrics.inc_prefill_key_blocks)
+    engine.start()
+    try:
+        prompt = np.arange(27, dtype=np.int32) % CFG.vocab_size
+        engine.submit(prompt, max_new_tokens=2).result(timeout=600)
+    finally:
+        engine.shutdown()
+    read = lambda kind: metrics.registry.get_sample_value(
+        "tpumlops_prefill_key_blocks_total", dict(metrics.identity, kind=kind))
+    walked, skipped = read("walked"), read("skipped")
+    chunks = 4  # offsets 0, 8, 16, 24
+    assert walked == 3 * (1 + 1 + 2 + 2)
+    assert walked + skipped == CFG.num_layers * (CFG.max_seq // 16) * chunks

@@ -1,6 +1,8 @@
 """Ring attention on the virtual sp mesh and the grouped-matmul kernel
 (interpret mode on CPU), each against its plain oracle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -150,3 +152,158 @@ def test_row_tile_is_a_function_of_the_static_rows_and_groups():
     tiles = [row_tile(tokens * 8, 256) for tokens in (1, 8, 32, 64, 128, 512, 2048)]
     assert tiles == sorted(tiles) and set(tiles) <= {16, 32, 64, 128}
     assert row_tile(4096, 8) == 128 and row_tile(4096, 4096) == 16
+
+
+# --- prefill attention: the fused core against the einsum body it replaces ---
+
+from tpumlops.models import mla_moe  # noqa: E402
+from tpumlops.ops import prefill_attention as pa  # noqa: E402
+
+
+def _core_cfg(heads, nope, rope, v, rank):
+    return mla_moe.MlaMoeConfig.tiny(
+        num_heads=heads, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        v_head_dim=v, kv_lora_rank=rank)
+
+
+# The three layer geometries the cells serve (dots3-note full 128 x
+# (128 | 64, v 128) rank 512; sliding 64 x (192 | 64) rank 1024; JoyAI 32
+# x (128 | 64) rank 512) at their published head widths and ranks, scaled
+# down in ROWS only: heads, queries, keys.
+FULL_W = dict(nope=128, rope=64, v=128, rank=512)
+SLIDING_W = dict(nope=192, rope=64, v=128, rank=1024)
+CORE = {
+    # name: (widths, heads, queries, capacity, key block, start, mask)
+    "causal_only_every_block_written": (FULL_W, 4, 32, 128, 32, 96, "causal"),
+    "causal_only_written_short_of_the_capacity": (FULL_W, 4, 32, 256, 32, 64, "causal"),
+    "kept_mask_with_ties_at_the_kth_value": (FULL_W, 4, 32, 256, 32, 160, "kept"),
+    "kept_mask_first_blocks_fully_masked_for_every_query": (
+        FULL_W, 2, 32, 256, 32, 160, "kept_late"),
+    "sliding_window_with_unwritten_negative_positions": (
+        SLIDING_W, 2, 32, 64, 32, 0, "window"),
+    "sliding_window_mid_prompt": (SLIDING_W, 2, 32, 64, 32, 200, "window"),
+    "padding_rows_behind_the_prompts_end": (FULL_W, 4, 32, 128, 32, 64, "padded"),
+    "one_block_is_the_whole_capacity": (FULL_W, 2, 16, 64, 64, 48, "causal"),
+    "two_query_tiles_and_two_batch_rows": (FULL_W, 2, 1024, 2048, 512, 1024, "rows"),
+}
+
+
+def _core_case(case):
+    """The operands of one case, float32 holding bf16-exact values so the
+    kernel's and the oracle's matmuls multiply the same numbers."""
+    widths, nh, s, t, kb, start, mask = CORE[case]
+    cfg = _core_cfg(nh, widths["nope"], widths["rope"], widths["v"], widths["rank"])
+    rng = np.random.default_rng(sorted(CORE).index(case))
+    b = 2 if mask == "rows" else 1
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)
+    q_nope = bf(rng.standard_normal((b, s, nh, widths["nope"])))
+    q_rope = bf(rng.standard_normal((b, s, nh, widths["rope"])))
+    kr = np.zeros((b, t, mla_moe.LANES), np.float32)
+    kr[..., :widths["rope"]] = rng.standard_normal((b, t, widths["rope"]))
+    c = rng.standard_normal((b, t, widths["rank"])).astype(np.float32)
+    w = bf(rng.standard_normal(
+        (widths["rank"], nh * (widths["nope"] + widths["v"]))) / widths["rank"] ** 0.5)
+    positions = start + np.arange(s)
+    written = start + s
+    if mask == "window":
+        # A sliding layer's keys: the window - 1 positions before the
+        # chunk (negative where the prompt has not got that far: rows
+        # nobody wrote), then the chunk's own.
+        window = t - s + 1
+        key_pos = np.concatenate([start - (window - 1) + np.arange(window - 1), positions])
+        sees = ((key_pos[None, :] >= 0) & (key_pos[None, :] <= positions[:, None])
+                & (positions[:, None] - key_pos[None, :] < window))[None]
+        written = t
+    else:
+        sees = (np.arange(t)[None, :] <= positions[:, None])[None]
+    if mask in ("kept", "kept_late"):
+        # The selection's mask: index scores with many ties (as relu
+        # gives), the top 24 of them by ``_top_mask``, a tie at the 24th
+        # value going to the lower position.
+        scores = np.round(rng.standard_normal((1, s, t)) * 2) / 2
+        if mask == "kept_late":
+            scores[..., :3 * kb] = -9.0  # nothing kept in the first three blocks
+        scores = np.where(sees, scores, -np.inf)
+        kept = np.asarray(mla_moe._top_mask(jnp.asarray(scores, jnp.float32), 24))
+        assert (kept.sum(-1) == 24).all() and not (kept & ~sees).any()
+        at = np.sort(np.where(kept, scores, np.inf), -1)[..., :1]
+        assert ((scores == at) & sees & ~kept).any(), "no tie was cut at the k-th value"
+        if mask == "kept_late":
+            assert not kept[..., :3 * kb].any()
+        sees = kept
+    # ("padded": a prompt's last chunk; the rows behind its end are
+    # queries like any other to the core, at the positions they pad.)
+    if mask == "rows":
+        sees = np.broadcast_to(sees, (b, s, t)).copy()
+        sees[1, :, :7] = False  # a row of the batch with a mask of its own
+        sees[1, :, 7] = True
+    return cfg, (q_nope, q_rope, jnp.asarray(kr), jnp.asarray(c), w,
+                 jnp.asarray(sees)), written, kb
+
+
+@pytest.mark.parametrize("case", sorted(CORE))
+def test_prefill_attention_kernel_equals_the_einsum_body(case, monkeypatch):
+    """The fused core (interpret mode) against ``_attn_blocks``'s einsum
+    body on the same operands.  Keys no query sees carry poison: NaN in
+    the blocks behind ``written`` (never walked: a NaN would come out of
+    ``0 * NaN``), 1e4 in the unseen rows of the walked blocks (a masked
+    key's probability is an exact 0, so nothing of it reaches the sum;
+    any leak would be huge).  The oracle gets the clean copy: equal
+    outputs mean the same positions were attended, row for row."""
+    cfg, (q_nope, q_rope, kr, c, w, sees), written, kb = _core_case(case)
+    monkeypatch.setattr(mla_moe, "KEY_BLOCK", kb)
+    monkeypatch.setattr(mla_moe, "ONE_PASS", kb)
+    seen = np.asarray(sees).any(axis=(0, 1)) if sees.shape[0] == 1 else None
+    c_clean, kr_clean = np.array(c), np.array(kr)
+    c_bad, kr_bad = c_clean.copy(), kr_clean.copy()
+    if seen is not None:
+        c_clean[:, ~seen], kr_clean[:, ~seen] = 0.0, 0.0
+        c_bad[:, ~seen] = 1e4
+        kr_bad[:, ~seen, :cfg.qk_rope_head_dim] = 1e4
+    walked = -(-written // kb) * kb
+    c_bad[:, walked:], kr_bad[:, walked:] = np.nan, np.nan
+    lp = {"kv_b": w}
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)
+
+    def run(c, kr, **kw):
+        monkeypatch.setattr(
+            mla_moe, "prefill_attention", functools.partial(pa.prefill_attention, **kw))
+        return np.asarray(mla_moe._attn_blocks(
+            q_nope, q_rope, bf(kr), bf(c), sees, jnp.int32(written), lp, cfg
+        ).astype(jnp.float32))
+
+    want = run(c_clean, kr_clean)  # off the TPU: the einsum body
+    got = run(c_bad, kr_bad, interpret=True)
+    assert got.shape == want.shape == (*q_nope.shape[:2], cfg.num_heads * cfg.v_head_dim)
+    assert np.isfinite(got).all()
+    # bf16 outputs of size ~1 from float32 statistics: the two differ by
+    # the order of the float32 sums and at most an ulp of bf16 (2**-8
+    # relative) where a sum lands on a rounding boundary.
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    assert np.abs(got - want).mean() < 1e-3
+    # The walk: blocks of the kernel's grid = the written ones.
+    tiles = pa.tiles_for(
+        q_nope.shape[1], c.shape[1], cfg.num_heads, cfg.qk_nope_head_dim,
+        cfg.v_head_dim, cfg.kv_lora_rank, mla_moe.LANES, kb, 2, aligned=False)
+    assert tiles.keys == kb and walked // kb <= c.shape[1] // kb
+
+
+def test_prefill_attention_off_the_tpu_is_the_einsum_body():
+    """No kernel in a CPU lowering, and ``tiles_for`` has no tiling for
+    what the chip's layouts do not take (a single-token step, a capacity
+    that is no multiple of a lane-wide key block)."""
+    cfg, (q_nope, q_rope, kr, c, w, sees), written, kb = _core_case(
+        "causal_only_every_block_written")
+    f = lambda *a: mla_moe._attn_blocks(*a, jnp.int32(written), {"kv_b": w}, cfg)
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)
+    text = jax.jit(f).lower(q_nope, q_rope, bf(kr), bf(c), sees).as_text()
+    assert "tpu_custom_call" not in text
+    geo = dict(nh=128, nope=128, v=128, rank=512, rope_row=128, itemsize=2)
+    assert pa.tiles_for(512, 8704, key_block=512, **geo) == pa.Tiles(512, 512, 4, 128, 128)
+    assert pa.tiles_for(1, 8704, key_block=512, **geo) is None
+    assert pa.tiles_for(8, 64, key_block=64, **geo) is None
+    assert pa.tiles_for(512, 8704, key_block=500, **geo) is None
+    # A nope width that is no multiple of the lanes is laid out padded.
+    assert pa.tiles_for(512, 1024, 64, 192, 128, 1024, 128, 512, 2).nope == 256
+    # 2048 queries: four tiles of 512.
+    assert pa.tiles_for(2048, 2048, key_block=512, **geo).queries == 512

@@ -2,7 +2,7 @@
 
 The chip's compiler is installed beside jax; it compiles for a topology
 that is described, not present.  That shows what interpret mode and the
-CPU backend cannot: Mosaic lowering of the one Pallas kernel (tiling, VMEM),
+CPU backend cannot: Mosaic lowering of the two Pallas kernels (tiling, VMEM),
 HBM fit of the full-width serving programs, and what the chip's compiler
 does with their donated caches.  Nothing runs, so these
 say nothing about results or times — a pass here is not a chip run.
@@ -217,6 +217,26 @@ def _array_instructions(hlo_text):
         if m:
             name, dims, opcode = m.groups()
             yield name, [int(d) for d in dims.split(",") if d], opcode
+
+
+def _kernel_calls(hlo_text, kernel):
+    """The lines of the program's custom calls of the repo's Pallas
+    kernel named ``kernel``."""
+    return [l for l in hlo_text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l and kernel in l]
+
+
+def _assert_no_float32_scores(hlo_text, heads, queries):
+    """No instruction of the program is a float32 ``[heads, queries,
+    keys]`` tensor (under any batch axis): the scores of the prefill core
+    stay in the kernel's VMEM."""
+    for line in hlo_text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = f32\[([\d,]*)\]", line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            dims = [d for d in dims if d != 1]
+            assert not (len(dims) == 3 and dims[0] in heads and dims[1] == queries), (
+                f"{m.group(1)}: float32 scores {dims} in HBM")
 
 
 def _assert_buffer_left_in_place(compiled, buffer_elements, cfg, windowed):
@@ -518,11 +538,20 @@ def test_sparse_expert_programs_read_the_experts_where_they_lie(one_chip, progra
     # Three grouped matmuls in each of the four expert layers, each the
     # repo's own kernel (ops/grouped_matmul.py) under the expert layer's
     # scope, and none left to XLA's 512-row lowering of ragged_dot.
-    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    calls = _kernel_calls(text, "grouped_matmul")
     assert len(calls) == 12
-    assert all("layer.moe_experts" in l and "grouped_matmul" in l for l in calls)
+    assert all("layer.moe_experts" in l for l in calls)
     assert "ragged-dot" not in text
     assert not re.search(r"bf16\[256,(2048,768|768,2048)\]\S* copy\(", text)
+    # The chunk's softmax core is the fused kernel, once a layer, and no
+    # float32 [32, 512, keys] scores are left in the program (the one-pass
+    # softmax held [32, 512, 2048], 134 MB); a step has no such core.
+    cores = _kernel_calls(text, "prefill_attention")
+    assert len(cores) == (5 if program == "prefill_chunk" else 0)
+    assert all("layer.attn_core" in l for l in cores)
+    assert len(calls) + len(cores) == text.count('custom_call_target="tpu_custom_call"')
+    if program == "prefill_chunk":
+        _assert_no_float32_scores(text, heads={32}, queries=512)
 
 
 @pytest.mark.parametrize("rows", [4096, 64, 8])
@@ -631,9 +660,67 @@ def test_two_kind_programs_leave_every_row_kind_in_place(one_chip, program):
                     and math.prod(dims) >= 128 * 512 * 8704), (
             f"{name}: {dims} is a capacity-wide score tensor")
     text = compiled.as_text()
-    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    calls = _kernel_calls(text, "grouped_matmul")
     assert len(calls) == 12
-    assert all("layer.moe_experts" in l and "grouped_matmul" in l for l in calls)
+    assert all("layer.moe_experts" in l for l in calls)
+    # The chunk's softmax core of either layer kind is the fused kernel
+    # (two full layers, three sliding), and neither kind's float32
+    # [heads, 512, keys] scores are left in the program.
+    cores = _kernel_calls(text, "prefill_attention")
+    assert len(cores) == (5 if program == "prefill_chunk" else 0)
+    assert all("layer.attn_core" in l for l in cores)
+    assert len(calls) + len(cores) == text.count('custom_call_target="tpu_custom_call"')
+    if program == "prefill_chunk":
+        _assert_no_float32_scores(text, heads={128, 64}, queries=512)
     for scope in ("layer.dsa_index", "layer.dsa_select", "layer.attn_gate",
                   "layer.attn_core"):
         assert scope in text, scope
+
+
+CORE_GEOMETRIES = {
+    # name: (heads, nope, rope, v, rank, keys): the three layer geometries
+    # of the latent family's two configurations, a 512-token chunk.
+    "dots3_note_full": (128, 128, 64, 128, 512, 8704),
+    "dots3_note_sliding": (64, 192, 64, 128, 1024, 1024),
+    "joyai_flash": (32, 128, 64, 128, 512, 2048),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(CORE_GEOMETRIES))
+def test_prefill_attention_lowers_at_the_published_widths(one_chip, geometry):
+    """Mosaic takes the fused prefill core at the published head counts
+    and widths of all three layer geometries, 512 queries over key blocks
+    of 512 with a traced block count, at the head group ``heads_per_step``
+    picks: inside the 16 MiB of scoped VMEM, or the compile raises.  The
+    program around it holds nothing the size of a score tensor."""
+    from tpumlops.models import mla_moe
+    from tpumlops.ops import prefill_attention as pa
+
+    nh, nope, rope, v, rank, keys = CORE_GEOMETRIES[geometry]
+    tiles = pa.tiles_for(512, keys, nh, nope, v, rank, mla_moe.LANES, 512, 2)
+    assert tiles is not None and tiles.queries == tiles.keys == 512
+    assert tiles.heads == (2 if geometry == "dots3_note_sliding" else 4)
+    assert tiles.nope % 128 == 0
+
+    cfg = mla_moe.MlaMoeConfig.tiny(
+        num_heads=nh, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        v_head_dim=v, kv_lora_rank=rank)
+
+    def core(q_nope, q_rope, kr, c, w, sees, written):
+        return mla_moe._attn_blocks(
+            q_nope, q_rope, kr, c, sees, written, {"kv_b": w}, cfg)
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(core).lower(
+        _sds(one_chip, (1, 512, nh, nope), bf), _sds(one_chip, (1, 512, nh, rope), bf),
+        _sds(one_chip, (1, keys, mla_moe.LANES), bf), _sds(one_chip, (1, keys, rank), bf),
+        _sds(one_chip, (rank, nh * (nope + v)), bf),
+        _sds(one_chip, (1, 512, keys), jnp.bool_), _sds(one_chip, (), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "prefill_attention")) == 1
+    _assert_no_float32_scores(text, heads={nh}, queries=512)
+    # Beside the kernel: the queries laid out a head beside the next (and
+    # the sliding kind's padded weights), tens of MB, not a score tensor's
+    # 134 MB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 100 * 2**20
