@@ -364,14 +364,15 @@ def _kind_view(cfg: MlaMoeConfig, kind: str) -> MlaMoeConfig:
     )
 
 
-def _layer_plan(cfg: MlaMoeConfig) -> list:
-    """``(kind, index among the layers of its kind)`` of every layer: the
-    index is the layer's row in its kind's cache buffers."""
-    seen = {FULL: 0, SLIDING: 0}
+def _layer_plan(cfg) -> list:
+    """``(kind, index among the layers of its kind)`` of every layer of a
+    config with ``kinds``: the index is the layer's row in its kind's
+    cache buffers."""
+    seen: dict = {}
     plan = []
     for kind in cfg.kinds:
-        plan.append((kind, seen[kind]))
-        seen[kind] += 1
+        plan.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
     return plan
 
 
@@ -1059,16 +1060,19 @@ def _swiglu(xn, gate, up, down):
 
 def route(xn, router, bias, cfg):
     """Chosen experts ``[N, k]`` (int32) and their weights ``[N, k]``
-    (float32) for normed tokens ``xn`` [N, H]: sigmoid scores, the bias
-    picks and does not weigh, weights renormalised over the chosen and
-    scaled.  All in float32 at ``highest`` precision."""
-    scores = jax.nn.sigmoid(
-        jnp.matmul(
-            xn.astype(jnp.float32), router.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST,
-        )
+    (float32) for normed tokens ``xn`` [N, H]: scores by
+    ``cfg.scoring_func`` (a sigmoid an expert, or a softmax over all of
+    them), ``bias`` (None: no selection bias) picks and does not weigh,
+    weights renormalised over the chosen and scaled.  All in float32 at
+    ``highest`` precision."""
+    logits = jnp.matmul(
+        xn.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
     )
-    _, idx = lax.top_k(scores + bias.astype(jnp.float32), cfg.num_experts_per_tok)
+    scores = (jax.nn.softmax(logits, axis=-1) if cfg.scoring_func == "softmax"
+              else jax.nn.sigmoid(logits))
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = lax.top_k(pick, cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
     return idx, weights * cfg.routed_scaling_factor
@@ -1076,7 +1080,11 @@ def route(xn, router, bias, cfg):
 
 @functools.partial(jax.jit, static_argnums=3)
 def moe_ffn(xn, lp, valid, cfg):
-    """The routed + shared expert FFN of normed tokens ``xn`` [N, H];
+    """The routed + shared expert FFN of normed tokens ``xn`` [N, H], of
+    this family and of ``models/gdn_moe.py`` (``cfg`` is either's: what is
+    read of it is the share, ``num_experts_per_tok`` and ``route``'s
+    keys; a tree without ``router_bias`` is routed without one, one with
+    ``shared_expert_gate`` gates its shared experts);
     ``valid`` bool [N] marks the real ones (padding is not routed and
     yields the shared experts' output alone, which nobody reads).  Of a
     token's chosen experts only those held here (``cfg.local_experts``
@@ -1090,7 +1098,7 @@ def moe_ffn(xn, lp, valid, cfg):
     n, h = xn.shape
     e, k = cfg.local_experts, cfg.num_experts_per_tok
     with jax.named_scope("layer.moe_router"):
-        idx, weights = route(xn, lp["router"], lp["router_bias"], cfg)
+        idx, weights = route(xn, lp["router"], lp.get("router_bias"), cfg)
     with jax.named_scope("layer.moe_experts"):
         # Token copies sorted by held expert; padding and the assignments
         # routed away sort behind every group (expert id E) and belong to
@@ -1119,6 +1127,8 @@ def moe_ffn(xn, lp, valid, cfg):
             [jnp.sum(sizes > 0).astype(jnp.int32), plan.visits, landed])
     with jax.named_scope("layer.moe_shared"):
         shared = _swiglu(xn, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+        if "shared_expert_gate" in lp:  # one sigmoid a token on what they add
+            shared = shared * jax.nn.sigmoid(_qmatmul(xn, lp["shared_expert_gate"]))
     return routed + shared, counts
 
 
@@ -1290,15 +1300,11 @@ def prefill(params, input_ids, cfg, dtype=jnp.bfloat16):
     return forward(params, input_ids, cache, cfg, dtype)
 
 
-def generate_greedy(
-    params: dict,
-    prompt_ids: jax.Array,
-    num_new_tokens: int,
-    cfg: MlaMoeConfig,
-    dtype=jnp.bfloat16,
-) -> jax.Array:
-    """Greedy generation with a scanned decode loop (the ``/infer``
-    path), the cache sized to what this call can reach."""
+def greedy_scan(forward, cache_of, params, prompt_ids, num_new_tokens, cfg, dtype):
+    """Greedy generation with a scanned decode loop over a family's
+    ``forward`` and scratch ``cache_of(cfg, batch, dtype)``, the cache
+    sized to what this call can reach (this family's and
+    ``models/gdn_moe.py``'s ``/infer`` path)."""
     total = prompt_ids.shape[1] + num_new_tokens
     if total > cfg.max_seq:
         raise ValueError(
@@ -1306,7 +1312,8 @@ def generate_greedy(
             f"= {total} exceeds KV-cache capacity max_seq={cfg.max_seq}"
         )
     cfg = dataclasses.replace(cfg, max_seq=min(cfg.max_seq, -(-total // 8) * 8))
-    logits, cache, _ = prefill(params, prompt_ids, cfg, dtype)
+    cache = cache_of(cfg, prompt_ids.shape[0], dtype)
+    logits, cache, _ = forward(params, prompt_ids, cache, cfg, dtype)
     next_tok = jnp.argmax(logits[:, -1:, :], axis=-1)
 
     def body(carry, _):
@@ -1316,6 +1323,19 @@ def generate_greedy(
 
     _, toks = lax.scan(body, (next_tok, cache), None, length=num_new_tokens)
     return jnp.moveaxis(toks[..., 0], 0, 1)
+
+
+def generate_greedy(
+    params: dict,
+    prompt_ids: jax.Array,
+    num_new_tokens: int,
+    cfg: MlaMoeConfig,
+    dtype=jnp.bfloat16,
+) -> jax.Array:
+    """Greedy generation with a scanned decode loop (the ``/infer``
+    path)."""
+    return greedy_scan(forward, KVCache.create, params, prompt_ids,
+                       num_new_tokens, cfg, dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -326,3 +326,19 @@ def _build_mla_moe(
         mla_moe, mla_moe.FLAVOR, params, cfg, max_new_tokens, eos_id,
         family=mla_moe,
     )
+
+
+@register("gdn-moe-generate")
+def _build_gdn_moe(
+    params: Any,
+    cfg: Any,
+    max_new_tokens: int = 64,
+    eos_id: int | None = None,
+    **_kw,
+) -> Predictor:
+    from . import gdn_moe
+
+    return _causal_lm_predictor(
+        gdn_moe, gdn_moe.FLAVOR, params, cfg, max_new_tokens, eos_id,
+        family=gdn_moe,
+    )

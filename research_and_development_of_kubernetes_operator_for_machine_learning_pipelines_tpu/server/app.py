@@ -1873,6 +1873,9 @@ def make_gen_engine(
         validate_serving_for_family(
             family.FLAVOR, family.UNSUPPORTED, fleet_role=config.fleet_role
         )
+        if metrics and hasattr(family, "state_row_bytes"):
+            metrics.set_cache_state_bytes(
+                family.state_row_bytes(predictor.causal_lm["cfg"]))
     ts = timeseries  # per-second ring: fans onto the metric callbacks
 
     prefix_cache = None

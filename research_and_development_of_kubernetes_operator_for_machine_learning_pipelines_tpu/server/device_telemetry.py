@@ -337,6 +337,12 @@ def build_hbm_ledger(
     for dtype, nbytes in weights_bytes_by_dtype(params).items():
         ledger.components[f"weights_{dtype}"] = nbytes
     ledger.components["kv_cache"] = ledger.kv_bytes_per_row * int(max_slots)
+    if hasattr(family, "state_row_bytes"):
+        # A slot's recurrent state is a constant beside its bytes a
+        # position: its own line, out of the rows'.
+        state = family.state_row_bytes(cfg, dtype_bytes) * int(max_slots)
+        ledger.components["cache_state"] = state
+        ledger.components["kv_cache"] -= state
     ledger.components["sampling_state"] = sampling_state_bytes(max_slots)
     if prefix_cache_budget_bytes:
         ledger.host_components["prefix_cache_budget"] = int(

@@ -184,6 +184,7 @@ _CONFIG_CLASSES = {
     "resnet-classifier": ("resnet", "ResNetConfig"),
     "llama-generate": ("llama", "LlamaConfig"),
     "mla-moe-generate": ("mla_moe", "MlaMoeConfig"),
+    "gdn-moe-generate": ("gdn_moe", "GdnMoeConfig"),
 }
 
 

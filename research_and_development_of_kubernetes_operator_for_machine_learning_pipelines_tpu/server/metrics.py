@@ -247,6 +247,33 @@ class ServerMetrics:
             ident_labels + ["program"],
             registry=self.registry,
         )
+        # Recurrent state of a linear-attention family: tokens / passes is
+        # how many real tokens a pass over a row's state folds into it.
+        self.gdn_tokens = Counter(
+            "tpumlops_gdn_tokens_total",
+            "Real tokens folded into a recurrent state, summed over the "
+            "linear-attention layers, counted on the device from the "
+            "call's validity mask",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.gdn_state_passes = Counter(
+            "tpumlops_gdn_state_passes_total",
+            "Rows whose recurrent state a program call read and wrote, "
+            "summed over the linear-attention layers, counted on the "
+            "device from the call's validity mask",
+            ident_labels + ["program"],
+            registry=self.registry,
+        )
+        self.cache_state_bytes = Gauge(
+            "tpumlops_cache_state_bytes",
+            "Bytes of recurrent state one cache slot holds whatever its "
+            "length (a linear-attention family: the float32 state and "
+            "the convolution's carried rows of every such layer), beside "
+            "the bytes a position of the HBM ledger's cache row",
+            ident_labels,
+            registry=self.registry,
+        )
         self.moe_expert_activations = Counter(
             "tpumlops_moe_expert_activations_total",
             "(program call, layer, expert) triples in which the expert "
@@ -759,8 +786,19 @@ class ServerMetrics:
         self.moe_expert_activations.labels(**labels).inc(counts["experts_hit"])
         self.moe_row_tile_visits.labels(**labels).inc(counts["row_tile_visits"])
         self.moe_row_tile_rows.labels(**labels).set(row_tile)
-        self.dsa_keys_scored.labels(**labels).inc(counts["dsa_keys_scored"])
-        self.dsa_keys_selected.labels(**labels).inc(counts["dsa_keys_selected"])
+        # What only some families count: an indexer's keys, a recurrent
+        # state's tokens and passes.
+        for name, counter in (
+            ("dsa_keys_scored", self.dsa_keys_scored),
+            ("dsa_keys_selected", self.dsa_keys_selected),
+            ("gdn_tokens", self.gdn_tokens),
+            ("gdn_state_passes", self.gdn_state_passes),
+        ):
+            if name in counts:
+                counter.labels(**labels).inc(counts[name])
+
+    def set_cache_state_bytes(self, nbytes: int):
+        self.cache_state_bytes.labels(**self.identity).set(nbytes)
 
     def observe_emit_lag(self, seconds: float):
         self.emit_lag.labels(**self.identity).observe(seconds)

@@ -100,6 +100,12 @@ EXPECTED_SERVER = {
     # Indexed sparse attention: positions scored and kept, by program.
     "tpumlops_dsa_keys_scored": ("counter", _IDENT + ("program",)),
     "tpumlops_dsa_keys_selected": ("counter", _IDENT + ("program",)),
+    # A linear-attention family's recurrent state: real tokens folded in
+    # and rows whose state a call read and wrote, by program; the bytes of
+    # state a cache slot holds.
+    "tpumlops_gdn_tokens": ("counter", _IDENT + ("program",)),
+    "tpumlops_gdn_state_passes": ("counter", _IDENT + ("program",)),
+    "tpumlops_cache_state_bytes": ("gauge", _IDENT),
     # (expert, row tile) visits of the grouped matmuls' schedule, and the
     # static rows a visit multiplies: assignments / (visits x rows) is how
     # full the tiles are.

@@ -162,6 +162,38 @@ def test_router_choices_and_weights_equal_and_the_bias_decides_some(ref_mod, par
     assert 0 < changed.sum() < len(changed)
 
 
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_shared_route_under_sigmoid_is_what_it_was(params, scoring):
+    """``route`` serves two families since PR 35 (``models/gdn_moe.py``
+    asks it for softmax scores and no selection bias).  Under ``sigmoid``
+    with a bias it gives, bit for bit, what its body gave before (written
+    out here); under ``softmax`` with none, a softmax over every routed
+    expert, its top-k, renormalised."""
+    from types import SimpleNamespace
+
+    x = jax.random.normal(jax.random.key(4), (64, CFG.hidden_size), jnp.float32)
+    lp = params["layers"][CFG.num_dense_layers]
+    logits = jnp.matmul(x, lp["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    k = CFG.num_experts_per_tok
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, want_idx = jax.lax.top_k(scores + lp["router_bias"], k)
+        cfg, bias, scale = CFG, lp["router_bias"], CFG.routed_scaling_factor
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, want_idx = jax.lax.top_k(scores, k)
+        cfg = SimpleNamespace(scoring_func="softmax", num_experts_per_tok=k,
+                              routed_scaling_factor=1.0)
+        bias, scale = None, 1.0
+    chosen = jnp.take_along_axis(scores, want_idx, axis=-1)
+    want_w = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
+    idx, w = mla_moe.route(x, lp["router"], bias, cfg)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(want_w))
+    np.testing.assert_allclose(np.asarray(w).sum(-1), scale, rtol=1e-5)
+
+
 def test_absorbed_decode_equals_expanded_attention(params, toks):
     """`decode_ragged` absorbs W_kvb; `forward` with one token expands
     keys and values from the latent: the same logits."""

@@ -724,3 +724,94 @@ def test_prefill_attention_lowers_at_the_published_widths(one_chip, geometry):
     # the sliding kind's padded weights), tens of MB, not a score tensor's
     # 134 MB.
     assert compiled.memory_analysis().temp_size_in_bytes < 100 * 2**20
+
+
+def _qwen3_next_cfg():
+    """``benchmarks/configs/qwen3-next-80b-a3b-bf16.json`` as the program
+    runs it: 8 of 48 layers, 128 of 512 experts held, 37984 of 151936
+    vocabulary rows, 8704 positions; every width as published."""
+    from tpumlops.models import gdn_moe
+
+    return gdn_moe.GdnMoeConfig(
+        vocab_size=37984, num_layers=8, n_local_experts=128, max_seq=8704)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_state_programs_leave_rows_and_state_in_place(one_chip, program):
+    """The linear-attention configuration at the benchmark's published
+    widths (8 slots x 8704 positions, chunk 512): the decode step at its
+    widest window and the prefill chunk fit the chip beside 7.33 GB of
+    bf16 weights; every donated buffer of both kinds (the two full
+    layers' K and V rows, the six linear layers' float32 state and
+    convolution tail) aliases, so the state is updated in place, and none
+    is copied or relaid whole; the chunk holds no ``[heads, chunk,
+    capacity]`` float32 scores; the expert matmuls are the kernel at 128
+    groups."""
+    from tpumlops.models import gdn_moe
+
+    cfg = _qwen3_next_cfg()
+    params = _on(one_chip, jax.eval_shape(
+        lambda: gdn_moe.init(jax.random.key(0), cfg, jnp.bfloat16)))
+    if program == "decode":
+        cache = _on(one_chip, jax.eval_shape(
+            lambda: gdn_moe.RaggedKVCache.create(cfg, 8)))
+
+        def fn(params, toks, k, v, lengths, active):
+            logits, c, counts = gdn_moe.decode_ragged(
+                params, toks, gdn_moe.RaggedKVCache(k, v, lengths), cfg,
+                active=active, window=8704)
+            return jnp.argmax(logits[:, -1], -1), c.k, c.v, c.lengths, counts
+
+        args = (params, _sds(one_chip, (8, 1), jnp.int32), cache.k, cache.v,
+                cache.lengths, _sds(one_chip, (8,), jnp.bool_))
+    else:
+        seq = _on(one_chip, jax.eval_shape(lambda: gdn_moe.KVCache.create(cfg, 1)))
+
+        def fn(params, ids, sk, sv, slen):
+            logits, s, counts = gdn_moe.forward(
+                params, ids, gdn_moe.KVCache(sk, sv, slen), cfg)
+            return logits[0], s.k, s.v, s.length, counts
+
+        args = (params, _sds(one_chip, (1, 512), jnp.int32), seq.k, seq.v,
+                seq.length)
+    compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    leaves = sum(math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert leaves == 3_667_251_328  # ISSUE 35's table: 7,334,502,656 bytes, 42.7 % of 16 GiB
+    assert gdn_moe.param_counts(cfg)[1] == leaves - (
+        6 * (64 + 128) + 2 * 512 + 8 * 4096 + 2048)  # the vectors it leaves out
+    # A slot: two full layers' K and V of 512 numbers a position, six
+    # linear layers' float32 state (2 MiB) and three rows of 8192.
+    state = 6 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    assert gdn_moe.state_row_bytes(cfg) == state == 12_877_824
+    assert gdn_moe.kv_row_bytes(cfg) == 2 * 8704 * 2 * 512 * 2 + state
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 0.8 * HBM
+    buffers = jax.tree.leaves(args[2:4])
+    assert len(buffers) == 2 * 2 + 6 * 2  # a full layer: key, value; a linear one: conv, state
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in buffers)
+    assert mem.alias_size_in_bytes >= cache_bytes, "a buffer's donation not credited"
+    assert mem.temp_size_in_bytes < 1.5 * 2**30, mem.temp_size_in_bytes
+    # The rows of either program, and the slots' state (16 MiB a layer; the
+    # batch-1 scratch's 2 MiB is the carry of the chunked rule's loop).
+    whole = {math.prod(a.shape) for a in buffers if a.ndim == 3 and a.shape[1] > 8}
+    if program == "decode":
+        whole |= {math.prod(a.shape) for a in buffers if a.ndim == 4}
+    for name, dims, opcode in _array_instructions(compiled.as_text()):
+        relayout = opcode in ("copy", "copy-start", "transpose") or (
+            opcode == "fusion" and ("copy" in name or "transpose" in name))
+        assert not (relayout and math.prod(dims) in whole), (
+            f"{name}: a whole-buffer {opcode} of {dims}")
+        assert not (opcode != "parameter" and 8704 in dims
+                    and math.prod(dims) >= 16 * 512 * 8704), (
+            f"{name}: {dims} is a capacity-wide score tensor")
+    text = compiled.as_text()
+    calls = _kernel_calls(text, "grouped_matmul")
+    assert len(calls) == 24  # three a layer, eight layers
+    assert all("layer.moe_experts" in l for l in calls)
+    assert "ragged-dot" not in text
+    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    for scope in ("layer.gdn_in", "layer.gdn_conv", "layer.gdn_scan",
+                  "layer.gdn_out", "state_commit", "kv_commit", "layer.attn_qkv",
+                  "layer.attn_core", "layer.attn_gate", "layer.attn_out",
+                  "layer.moe_router", "layer.moe_experts", "layer.moe_shared"):
+        assert scope in text, scope
