@@ -267,11 +267,16 @@ class _PrefillProgress:
     ``chunks`` covers only the UNCACHED suffix when a radix-cached
     prefix was found at admission (``cached_tokens`` > 0): the prefix's
     K/V is seeded straight into the sequence cache (``cached_kv``, one
-    host pair per chunk) and never re-prefilled."""
+    host pair per chunk) and never re-prefilled.
+
+    ``ahead``: chunk ``next_idx - 1`` went out behind the last pass's
+    decode step (``GenerationEngine._send_chunk_ahead``), so the coming
+    pass's admit phase dispatches no other."""
 
     req: _Request
     chunks: list  # padded [1, C] int32 arrays (uncached suffix only)
     next_idx: int = 0
+    ahead: bool = False
     cached_tokens: int = 0
     cached_kv: list = field(default_factory=list)
     seeded: bool = False
@@ -284,7 +289,8 @@ class _OpenTick:
     sp-prefill, packed chunks) that has been dispatched and whose tick is
     not journaled yet: the engine thread waits for it at the pass's next
     blocking read, behind whatever it dispatched meanwhile
-    (``GenerationEngine._close_ticks``)."""
+    (``GenerationEngine._close_ticks``); a chunk sent ahead, behind the
+    pass's step, at the next pass's."""
 
     kind: str
     t0: float  # perf_counter before its dispatch
@@ -293,16 +299,19 @@ class _OpenTick:
     wait_on: object
     owners: tuple  # the _Requests it serves: a device error fails these
     fields: dict  # _record_tick's keyword fields
+    # The ``_moe_pending`` entries its program noted: computed, and so
+    # readable without a wait, only once the tick is closed.
+    counts: list = field(default_factory=list)
 
 
 class _TickFailed(RuntimeError):
-    """The wait for an open tick raised (``__cause__`` holds the device
-    error): the failure is that admission's, wherever the loop took the
-    wait."""
+    """The wait for an open tick raised, or the dispatch of a chunk sent
+    ahead (``__cause__`` holds the error): the failure is that
+    admission's, wherever the loop took the wait."""
 
-    def __init__(self, tick: _OpenTick):
-        super().__init__(f"{tick.kind} tick failed at its wait")
-        self.tick = tick
+    def __init__(self, kind: str, owners: tuple, at: str = "wait"):
+        super().__init__(f"{kind} tick failed at its {at}")
+        self.owners = owners  # the _Requests the program served
 
 
 @dataclass
@@ -421,6 +430,7 @@ class GenerationEngine:
         family=None,  # the causal-LM family's module (None: models.llama)
         on_moe: Callable[[str, dict, int, int], None] | None = None,
         on_prefill_wait: Callable[[str], None] | None = None,  # "step"|"none"
+        on_prefill_dispatch: Callable[[str], None] | None = None,  # "ahead"|"in_turn"
         on_key_blocks: Callable[[int, int], None] | None = None,  # walked, skipped
     ):
         import jax
@@ -456,7 +466,8 @@ class GenerationEngine:
         # assignments that landed on an expert held here, the positions
         # an indexer scored and kept.
         # ``_moe_pending`` holds (program, real tokens, token rows, device
-        # counts) until a read-back the loop makes anyway.
+        # counts) until a read-back the loop makes anyway; a chunk's lie
+        # with its open tick until that is closed.
         self._pad_id = lm.PAD_ID
         self._on_moe = on_moe
         # A family whose prefill core walks the written key blocks of its
@@ -640,12 +651,17 @@ class GenerationEngine:
         # it registers an open tick (``_open_tick``) and takes the wait at
         # the pass's next blocking read (``_close_ticks``), behind the
         # decode step it has dispatched meanwhile, so the chip never
-        # idles while the host assembles that step.  The tick's wall runs
-        # to the stamp of that wait, in completion order, and does not
-        # absorb the step's device time nor lend it its own.  No option
-        # arms or disarms this: watching the engine (recorder, telemetry)
-        # does not change the order of its dispatches.
+        # idles while the host assembles that step.  An admission's next
+        # chunk goes out right behind that step (``_send_chunk_ahead``)
+        # and is waited for behind the NEXT step, so the chip does not
+        # idle through the read-back, the emission and the next admit
+        # phase either.  The tick's wall runs to the stamp of its wait,
+        # in completion order, and does not absorb the step's device
+        # time nor lend it its own.  No option arms or disarms this:
+        # watching the engine (recorder, telemetry) does not change the
+        # order of its dispatches.
         self._on_prefill_wait = on_prefill_wait
+        self._on_prefill_dispatch = on_prefill_dispatch
         self._on_prefix_l2 = on_prefix_l2
         if prefix_enabled:
             from .prefix_cache import RadixPrefixCache
@@ -2390,7 +2406,11 @@ class GenerationEngine:
             if victim is None or key < victim[0]:
                 victim = (key, i)
         if victim is not None:
-            self._evict_slot(victim[1])
+            # The eviction reads the cache: no tick open under it (and a
+            # chunk that failed at its wait has emptied the slots).
+            self._settle_ticks()
+            if self._slots[victim[1]] is not None:
+                self._evict_slot(victim[1])
 
     def _evict_slot(self, idx: int) -> None:
         """Evict one active slot at a tick boundary, losing no work.
@@ -2674,39 +2694,52 @@ class GenerationEngine:
         self._emit_first(slot_idx, req, first)
 
     def _open_tick(
-        self, kind: str, t0: float, wait_on, owners=(), **fields
+        self, kind: str, t0: float, wait_on, owners=(),
+        noted: int | None = None, **fields
     ) -> None:
         """Register a prefill-side program just dispatched.  Nothing
         waits here: :meth:`_close_ticks` journals it (``fields`` are
-        :meth:`_record_tick`'s) at the next blocking read."""
+        :meth:`_record_tick`'s) at the next blocking read.  ``noted`` is
+        the length ``_moe_pending`` had before the dispatch: what the
+        program noted since is the tick's until it closes."""
+        counts = [] if noted is None else self._moe_pending[noted:]
+        if counts:
+            del self._moe_pending[noted:]
         self._open_ticks.append(
-            _OpenTick(kind, t0, wait_on, tuple(owners), fields)
+            _OpenTick(kind, t0, wait_on, tuple(owners), fields, counts)
         )
 
-    def _close_ticks(self, behind: str | None = None) -> None:
+    def _close_ticks(
+        self, behind: str | None = None, first: int | None = None
+    ) -> None:
         """A blocking point: wait for the open ticks in device order and
         journal each with the wall the host saw for it alone.
 
         ``behind`` is the heartbeat kind of a decode-side dispatch that
         is already queued behind them (the chip goes straight on to it);
-        None where nothing is.  Every wait is ``engine.prefill_sync``
-        (the benchmark's ``loop_host_ms`` takes that span as time blocked
-        on the device, wherever the loop takes it).  A device error
-        raises :class:`_TickFailed`: it is the admission's, not the
-        step's."""
+        None where nothing is.  ``first`` closes only that many, the
+        oldest: a step leaves the chunk it sent ahead open.  Every wait
+        is ``engine.prefill_sync`` (the benchmark's ``loop_host_ms``
+        takes that span as time blocked on the device, wherever the loop
+        takes it).  A device error raises :class:`_TickFailed`: it is
+        the admission's, not the step's, and the ticks behind it go with
+        it."""
         if not self._open_ticks:
             return
         import jax
 
         span = self._span
-        ticks, self._open_ticks = self._open_ticks, []
+        n = len(self._open_ticks) if first is None else first
+        ticks, self._open_ticks = self._open_ticks[:n], self._open_ticks[n:]
         for tick in ticks:
             self._beat(tick.kind)
             try:
                 with span("engine.prefill_sync"):
                     jax.block_until_ready(tick.wait_on)
             except Exception as exc:
-                raise _TickFailed(tick) from exc
+                self._open_ticks = []
+                raise _TickFailed(tick.kind, tick.owners) from exc
+            self._moe_pending.extend(tick.counts)
             start, wall = self._tick_done(tick.t0)
             with span("engine.journal"):
                 if self._on_prefill_wait is not None:
@@ -2821,8 +2854,9 @@ class GenerationEngine:
     def _emit_first(self, slot_idx: int, req: _Request, first) -> None:
         """A fresh admission's first token.  The host needs its value
         now (to stream it, and to know whether the slot is done already),
-        so this is a blocking point: the open ticks (the last chunk, the
-        insert) close here, then TTFT, the read and the emission."""
+        so this is a blocking point: the open ticks (the last chunk, sent
+        in turn or ahead, and the insert) close here, then TTFT, the read
+        and the emission."""
         self._close_ticks()
         self._note_ttft(req)
         with self._span("engine.prefill_sync"):
@@ -2843,9 +2877,11 @@ class GenerationEngine:
         """Hand the pending counts to ``on_moe(program, counts, routed,
         row_tile)``: the family's ``COUNTS`` by name, every (token,
         expert) pair of the call's real tokens, the grouped matmuls' row
-        tile.  Called where the loop has just read a later program's
-        result back, so every count here is already computed: no
-        synchronisation of its own."""
+        tile.  Called where the loop has just read a program's result
+        back: a step's counts are that program's own, and a chunk's come
+        here only when its tick is closed (a chunk sent ahead is queued
+        BEHIND the step whose tokens were just read), so every count here
+        is already computed: no synchronisation of its own."""
         if not self._moe_pending:
             return
         pending, self._moe_pending = self._moe_pending, []
@@ -3093,6 +3129,7 @@ class GenerationEngine:
                 fn, fut = self._control_ops.get_nowait()
             except queue.Empty:
                 return
+            self._settle_ticks()  # an op may read device state
             try:
                 _safe_resolve(fut, fn())
             except Exception as exc:
@@ -3161,30 +3198,33 @@ class GenerationEngine:
 
         return int(self.run_control(op).result(timeout))
 
-    def _maybe_cache_chunk(self, prog: _PrefillProgress) -> None:
-        """Write the chunk just prefilled (index ``prog.next_idx``) back
-        into the radix cache — leader-side only (the scheduler thread),
+    def _chunk_to_cache(self, prog: _PrefillProgress) -> int | None:
+        """Where chunk ``prog.next_idx`` starts in its prompt if
+        prefilling it owes the radix cache a write-back, else None:
         full real-token chunks only (a padded tail carries pad-garbage
-        K/V that must never be reused).
+        K/V that must never be reused), and ``has_chunk`` skips chunks
+        already cached (the steady state for shared-prefix traffic)."""
+        if self._prefix_cache is None or self._in_warmup:
+            return None
+        C = self._prefill_chunk_size
+        prompt = prog.req.prompt
+        start = prog.cached_tokens + prog.next_idx * C
+        if start + C > prompt.size or self._prefix_cache.has_chunk(prompt, start // C):
+            return None
+        return start
+
+    def _cache_chunk(self, prog: _PrefillProgress, start: int) -> None:
+        """Write the chunk just prefilled, which starts at ``start``
+        (:meth:`_chunk_to_cache`), back into the radix cache —
+        leader-side only (the scheduler thread).
 
         The ``np.asarray`` is a blocking point: the scheduler waits for
         the chunk's forward pass (its open tick closes first) before
         dispatching the next decode tick, so it is paid at most ONCE per
-        unique chunk — ``has_chunk`` skips both the transfer and the
-        wait for chunks already cached (the steady state for
-        shared-prefix traffic)."""
-        if self._prefix_cache is None or self._in_warmup:
-            return
+        unique chunk, and a chunk that owes it is never sent ahead."""
         import jax.numpy as jnp
 
-        C = self._prefill_chunk_size
-        L = int(prog.req.prompt.size)
-        start = prog.cached_tokens + prog.next_idx * C
-        if start + C > L:
-            return
-        chunk_idx = start // C
-        if self._prefix_cache.has_chunk(prog.req.prompt, chunk_idx):
-            return
+        chunk_idx = start // self._prefill_chunk_size
         _, sk, sv, _slen = self._seq_state
         ck, cv = self._read_chunk(sk, sv, jnp.int32(start))
         self._close_ticks()
@@ -3816,7 +3856,9 @@ class GenerationEngine:
 
     def _chunk_tick(self) -> None:
         """Advance the in-flight chunked admission by ONE device op (a
-        prefix-cache seed or one prefill chunk); on the final chunk,
+        prefix-cache seed or one prefill chunk), unless its next chunk
+        went out behind the last pass's step already
+        (:meth:`_send_chunk_ahead`); once every chunk is dispatched,
         install the sequence into its slot.  Single-admission mode only
         (the batch-1 scratch cache serializes admissions); packed mode
         advances through :meth:`_packed_tick`."""
@@ -3824,7 +3866,9 @@ class GenerationEngine:
         span = self._span
         self._beat("prefill")
         prog = self._pending[0]
-        if prog.cached_tokens and not prog.seeded:
+        if prog.ahead:
+            prog.ahead = False  # this pass's chunk is on the chip already
+        elif prog.cached_tokens and not prog.seeded:
             # Cached-prefix hit: one seed op copies the radix-cached K/V
             # into a fresh sequence cache — those tokens never re-prefill.
             ts = time.perf_counter()
@@ -3838,7 +3882,7 @@ class GenerationEngine:
                 if self._on_prefix_hit is not None:
                     self._on_prefix_hit(prog.cached_tokens)
                 # The seeded scratch itself: the next chunk donates it,
-                # and no pass ends with a tick open.
+                # so that chunk is never sent ahead of this tick's close.
                 self._open_tick(
                     "seed", ts, self._seq_state[1], (prog.req,),
                     active_slots=sum(s is not None for s in self._slots),
@@ -3847,11 +3891,24 @@ class GenerationEngine:
                 )
                 self._trace_event(prog.req.trace, "seed")
             return  # suffix chunks start next tick (decode cadence kept)
+        else:
+            start = self._chunk_to_cache(prog)
+            self._dispatch_next_chunk(prog, "in_turn")
+            if start is not None:
+                self._cache_chunk(prog, start)
+        if prog.next_idx == len(prog.chunks):
+            self._finish_admission(prog)
+
+    def _dispatch_next_chunk(self, prog: _PrefillProgress, when: str) -> None:
+        """Dispatch ``prog``'s next prefill chunk into the batch-1
+        scratch and open its tick.  ``when``: the admit phase sent it
+        ``in_turn``, or the step sent it ``ahead``."""
         ids = prog.chunks[prog.next_idx]
         C = self._prefill_chunk_size
         offset = prog.cached_tokens + prog.next_idx * C
+        noted = len(self._moe_pending)
         ts = time.perf_counter()
-        with span("engine.prefill_dispatch"):
+        with self._span("engine.prefill_dispatch"):
             self._dispatch_chunk(
                 ids, fresh=prog.next_idx == 0 and not prog.seeded
             )
@@ -3859,12 +3916,14 @@ class GenerationEngine:
             self.prefill_chunks_dispatched += 1
             self.prefill_forwards += 1
             self._note_prefill_tokens(self._chunk_tokens([prog]))
+            if self._on_prefill_dispatch is not None:
+                self._on_prefill_dispatch(when)
             if self._key_blocks is not None and self._on_key_blocks is not None:
                 self._on_key_blocks(*self._key_blocks(self._cfg, offset, C))
             # The chunk's logits: the next chunk donates the scratch, not
             # these.
             self._open_tick(
-                "prefill", ts, self._seq_state[0], (prog.req,),
+                "prefill", ts, self._seq_state[0], (prog.req,), noted=noted,
                 active_slots=sum(s is not None for s in self._slots),
                 batch_fill=1,
                 cost=self._cost_prefill(1, C, attended=offset + C / 2),
@@ -3872,18 +3931,21 @@ class GenerationEngine:
         if prog.req.trace is not None:
             prog.req.trace.prefill_chunks += 1
             self._trace_event(prog.req.trace, "prefill_chunk")
-        self._maybe_cache_chunk(prog)
         prog.next_idx += 1
-        if prog.next_idx < len(prog.chunks):
-            return
+
+    def _finish_admission(self, prog: _PrefillProgress) -> None:
+        """Every chunk of ``prog`` is dispatched: insert the scratch into
+        a free slot and emit the first token (a blocking point: the last
+        chunk's tick, sent in turn or ahead, closes there)."""
         req = prog.req
+        C = self._prefill_chunk_size
         self._pending.pop(0)
         slot_idx = self._free_slot()
         assert slot_idx is not None  # reserved by the admission policy
         L = int(req.prompt.size)
         slot_key = self._slot_key_for(req)
         t0 = time.perf_counter()
-        with span("engine.prefill_dispatch"):
+        with self._span("engine.prefill_dispatch"):
             first = self._dispatch_insert(
                 slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
                 last_idx=(L - 1) - prog.cached_tokens
@@ -4057,9 +4119,12 @@ class GenerationEngine:
         self._beat("decode")
         with span("engine.decode_dispatch"):
             self._dispatch_step(active_np, window, sampling)
-        # The step is queued behind the pass's chunk: now wait for the
-        # chunk, then for the step, in the order the device runs them.
-        self._close_ticks(behind="decode")
+        # The step is queued behind the pass's chunk, and the admission's
+        # next chunk goes behind the step: now wait for what went before
+        # the step, then for the step, in the order the device runs them.
+        before = len(self._open_ticks)
+        self._send_chunk_ahead()
+        self._close_ticks(behind="decode", first=before)
         with span("engine.decode_readback"):
             toks = np.asarray(self._tokens)[:, 0]
             done = self._tick_done(t0)
@@ -4900,6 +4965,46 @@ class GenerationEngine:
             )
         self._note_experts("decode", int(np.sum(active_np)), len(active_np), aux)
 
+    def _send_chunk_ahead(self) -> None:
+        """Dispatch the in-flight admission's next chunk right behind the
+        step just dispatched, before the pass reads anything back.  The
+        chunk reads and writes only the batch-1 scratch and needs nothing
+        the step produces, so the chip goes on to it while the host reads
+        the step back, emits, journals and runs the next admit phase,
+        which then sends no other (``prog.ahead``): the device's order
+        stays chunk, step, chunk, step.
+
+        One rule on what the engine observes: an admission whose first
+        chunk is out (a fresh one's waits for the slot a read-back
+        frees; a seed's tick waits on the scratch this would donate) and
+        that has a chunk left, no other program of it un-waited but the
+        one this pass sent, and no prefix-cache write-back to read the
+        chunk back at once.  A dispatch error is the admission's."""
+        if self._packed or not self._pending:
+            return
+        prog = self._pending[0]
+        if (
+            not 0 < prog.next_idx < len(prog.chunks)
+            or len(self._open_ticks) > 1
+            or self._chunk_to_cache(prog) is not None
+        ):
+            return
+        try:
+            self._dispatch_next_chunk(prog, "ahead")
+        except Exception as exc:
+            raise _TickFailed("prefill", (prog.req,), at="dispatch") from exc
+        prog.ahead = True
+
+    def _settle_ticks(self) -> None:
+        """Close the open ticks where the admit phase is about to read
+        device state (a control op, an eviction), outside ``_loop``'s
+        handler: a wait that fails there is its admission's all the
+        same."""
+        try:
+            self._close_ticks()
+        except _TickFailed as failed:
+            self._admission_failed(failed.owners, failed)
+
     def _loop(self) -> None:
         span = self._span
         while not self._stop.is_set():
@@ -4914,19 +5019,25 @@ class GenerationEngine:
                 with span("engine.admit"):
                     alive = self._admit_phase()
                 if not alive:
-                    return  # shutdown sentinel
+                    break  # shutdown sentinel
                 try:
                     self._step()
                     # A pass that read nothing back (no slot active)
-                    # waits for its chunk here: none ends with a tick
-                    # open, so at most a chunk and a step are ever
-                    # queued un-waited.
-                    self._close_ticks()
+                    # waits for its chunk here.  Only a chunk sent ahead
+                    # stays open across the end of its pass, and closes
+                    # behind the next step's dispatch: at most a step
+                    # and two chunks are ever queued un-waited.
+                    if not (self._pending and self._pending[0].ahead):
+                        self._close_ticks()
                 except _TickFailed as failed:
-                    self._admission_failed(failed.tick.owners, failed)
+                    self._admission_failed(failed.owners, failed)
                 except Exception:
                     _log.exception("decode step failed")
                     self._fail_all_and_recover()
+        try:
+            self._close_ticks()  # shutdown with a chunk in flight
+        except _TickFailed:
+            _log.exception("a prefill-side program failed at shutdown")
 
     def _dequeue_or_wait(self, block: bool):
         """:meth:`_dequeue`; blocking (no slot active, nothing pending)
@@ -4940,7 +5051,8 @@ class GenerationEngine:
         """Admission work for one scheduler iteration.
 
         Fused mode drains every free slot; single-admission chunked mode
-        advances the in-flight admission by ONE chunk (or starts a new
+        advances the in-flight admission by ONE chunk unless the last
+        pass's step sent that chunk ahead already (or starts a new
         one); packed mode tops up the admission queue (one reserved cache
         row each) and advances up to ``prefill_batch`` of them with ONE
         batched call.  In every mode the decode tick that follows is
@@ -5095,8 +5207,15 @@ class GenerationEngine:
         (a :class:`_TickFailed` names the tick's own requests): count the
         crash against their prompts, fail their futures with the device
         error, drop their progress and recover the device state."""
+        self._drop_admission(reqs, exc)
+        self._open_ticks = []  # that admission's: lost with the device state
+        self._fail_all_and_recover()
+
+    def _drop_admission(self, reqs, exc: Exception) -> None:
+        """:meth:`_admission_failed` less the recovery of the device
+        state (which may be what found the failure)."""
         if isinstance(exc, _TickFailed):
-            reqs, exc = exc.tick.owners or reqs, exc.__cause__
+            reqs, exc = exc.owners or reqs, exc.__cause__
         _log.error(
             "admission failed: a prefill-side program raised", exc_info=exc
         )
@@ -5107,7 +5226,6 @@ class GenerationEngine:
         for req in reqs:
             if not req.future.done():
                 _safe_fail(req.future, exc)
-        self._fail_all_and_recover()
 
     def _fail_all_and_recover(self) -> None:
         """Fail every in-flight sequence and reallocate device state.
@@ -5116,7 +5234,14 @@ class GenerationEngine:
         the donated buffers), and donation has ALREADY invalidated those
         buffers — reusing them would raise "Array has been deleted" on every
         later request, bricking the engine while /ready stays green.  Fresh
-        buffers restore service for subsequent requests."""
+        buffers restore service for subsequent requests.  A chunk sent
+        ahead of the failure is waited for first: journaled if it ran (the
+        scratch is not the slots'), its admission failed with the rest if
+        it did not."""
+        try:
+            self._close_ticks()
+        except _TickFailed as failed:
+            self._drop_admission(failed.owners, failed)
         for i, slot in enumerate(self._slots):
             if slot is not None and not slot.future.done():
                 self._abort_trace(slot.trace, "error")

@@ -199,6 +199,18 @@ class ServerMetrics:
             ident_labels + ["queued_behind"],
             registry=self.registry,
         )
+        # How often an admission's chunk went out behind the pass's
+        # decode step, before anything was read back: ahead / (ahead +
+        # in_turn) is the share of chunks the chip did not idle in front of.
+        self.prefill_dispatch = Counter(
+            "tpumlops_prefill_dispatch_total",
+            "Prefill chunk programs of the single-admission path by where "
+            "the engine dispatched them: ahead (right behind the pass's "
+            "decode step, before its read-back) or in_turn (in the admit "
+            "phase)",
+            ident_labels + ["when"],
+            registry=self.registry,
+        )
         self.prefill_key_blocks = Counter(
             "tpumlops_prefill_key_blocks_total",
             "Key blocks of the cache's capacity, summed over the "
@@ -769,6 +781,9 @@ class ServerMetrics:
         self.prefill_waits.labels(
             **self.identity, queued_behind=queued_behind
         ).inc()
+
+    def inc_prefill_dispatch(self, when: str):
+        self.prefill_dispatch.labels(**self.identity, when=when).inc()
 
     def inc_prefill_key_blocks(self, walked: int, skipped: int):
         self.prefill_key_blocks.labels(**self.identity, kind="walked").inc(walked)

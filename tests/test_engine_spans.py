@@ -2,7 +2,9 @@
 phases: nesting and self time, the nine phases over every tick kind, the
 profiler sink on the capture's clock, the prefill-token counter, where the
 loop takes the wait for a prefill chunk (behind the step's dispatch, with
-or without a recorder watching), and the operator staying off jax.
+or without a recorder watching) and sends the next one (right behind that
+dispatch, before the pass reads anything back), and the operator staying
+off jax.
 
 Engines here are tiny and start WITHOUT the warm-up sweep (each program
 compiles on first use, a few seconds a mode), so the cases run in the
@@ -10,6 +12,7 @@ fast tranche; every wait has its own timeout.
 """
 
 import glob
+import queue
 import subprocess
 import sys
 import threading
@@ -239,61 +242,78 @@ def _first_token_then(eng, prompt, new):
 LONG = list(range(3, 43))  # five chunks of 8
 
 
-def test_the_wait_for_a_chunk_is_taken_behind_the_steps_dispatch(
+def _rider_then_doc(eng, doc=LONG, rider_new=40, doc_new=4):
+    """A stream that is decoding when ``doc`` arrives: (rider, doc)."""
+    rider, started = _first_token_then(eng, PROMPTS[0], rider_new)
+    assert started.wait(timeout=120)
+    return rider, eng.submit(doc, doc_new)
+
+
+def test_the_next_chunk_is_dispatched_right_behind_the_steps_dispatch(
     tiny, cpu_peaks
 ):
-    """A pass with an active slot and a chunk that is not the prompt's
-    last: chunk dispatch, step dispatch, THEN the wait for the chunk and
-    the step's read-back.  The journaled walls are taken in completion
-    order, so they do not overlap and fit inside the pass."""
-    tracer, waits = _LoggingTracer(), []
+    """A pass with an active slot and an admission whose first chunk is
+    out: step dispatch, the NEXT chunk's dispatch, and only then the wait
+    for the chunk before the step and the step's read-back; the chunk sent
+    ahead is waited for behind the next pass's step.  The journaled walls
+    are taken in completion order, so they do not overlap, and each ends
+    in a pass and starts no earlier than the pass before that."""
+    tracer, waits, sent = _LoggingTracer(), [], []
     watchers = _watchers(cpu_peaks)
     eng = _engine(
         tiny, prefill_chunk=8, tracer=tracer, on_prefill_wait=waits.append,
-        **watchers,
+        on_prefill_dispatch=sent.append, **watchers,
     )
     try:
         _serve(eng, [LONG[:20], PROMPTS[0]], new=8)  # compiles the programs
         ticks = _spy_ticks(eng)
-        del tracer.log[:], waits[:]
+        del tracer.log[:], waits[:], sent[:]
         ticks_before = watchers["recorder"].ticks_recorded
         steps_before = eng.dispatches_total["decode"]
-        rider, started = _first_token_then(eng, PROMPTS[0], 40)
-        assert started.wait(timeout=120)
-        doc = eng.submit(LONG, 4)
+        chunks_before = eng.prefill_chunks_dispatched
+        rider, doc = _rider_then_doc(eng)
         doc.result(timeout=300)
         rider.result(timeout=300)
         steps = eng.dispatches_total["decode"] - steps_before
+        chunks = eng.prefill_chunks_dispatched - chunks_before
     finally:
         eng.shutdown()
     log = tracer.log
     passes = [(a, b) for name, a, b in log if name == ROOT]
-    hidden = 0
+    ahead = 0
     for a, b in passes:
         inside = [e for e in log if e[0] != ROOT and a <= e[1] and e[2] <= b]
         opened = lambda name: [e for e in inside if e[0] == name]
-        chunk, step = opened("engine.prefill_dispatch"), opened(
-            "engine.decode_dispatch")
-        walls = sorted(
-            (t0, t0 + wall) for _k, t0, wall in ticks if a <= t0 <= b
-        )
-        for (_s0, e0), (s1, _e1) in zip(walls, walls[1:]):
-            assert e0 <= s1 + 1e-9, (walls, "walls overlap")
-        assert sum(e - s for s, e in walls) <= (b - a) + 1e-9
-        assert all(e <= b + 1e-9 for _s, e in walls)
-        if len(chunk) != 1 or len(step) != 1:
-            continue  # no chunk, its last chunk (+ the insert), or no step
-        hidden += 1
+        step = opened("engine.decode_dispatch")
+        if len(step) != 1:
+            continue
+        behind = [e for e in opened("engine.prefill_dispatch")
+                  if e[1] >= step[0][2]]
+        if not behind:
+            continue
+        ahead += 1
+        (chunk,) = behind
         (sync,) = opened("engine.prefill_sync")
         (readback,) = opened("engine.decode_readback")
-        assert step[0][2] <= sync[1], "waited before dispatching the step"
+        assert chunk[2] <= sync[1], "waited before sending the next chunk"
         assert sync[2] <= readback[1]
-    # LONG's four non-final chunks all rode with the rider's steps.
-    assert hidden == 4
+    # LONG's chunks after the first all went out behind the rider's steps,
+    # the last one too; its first and the rider's own in the admit phase.
+    assert ahead == 4 and sent.count("ahead") == 4
+    assert sent.count("in_turn") == 2 and len(sent) == chunks
+    # Four chunks were waited for behind a step: LONG's first behind the
+    # step of its pass, the next three behind the next pass's ...
     assert waits.count("step") == 4
     # ... its last chunk and the insert, and the rider's own chunk and
     # insert, are read at once: nothing was queued behind them.
     assert waits.count("none") == 4
+    walls = sorted((t0, t0 + wall) for _k, t0, wall in ticks)
+    for (_s0, e0), (s1, _e1) in zip(walls, walls[1:]):
+        assert e0 <= s1 + 1e-9, (walls, "walls overlap")
+    for s, e in walls:
+        (i,) = [i for i, (a, b) in enumerate(passes) if a <= e <= b]
+        assert s >= passes[max(i - 1, 0)][0], "a wall over three passes"
+    assert sum(e - s for s, e in walls) <= passes[-1][1] - passes[0][0]
     # The same ticks the parent journals for these requests: one a chunk,
     # one an insert, one a step; one read-back a step.
     kinds = [k for k, _t0, _w in ticks]
@@ -302,6 +322,103 @@ def test_the_wait_for_a_chunk_is_taken_behind_the_steps_dispatch(
     assert set(kinds) == {"prefill", "decode"}
     assert watchers["recorder"].ticks_recorded - ticks_before == len(kinds)
     assert sum(1 for e in log if e[0] == "engine.decode_readback") == steps
+
+
+def _family(name):
+    """A tiny configuration of a family behind the ``causal_lm`` handle
+    beside llama (the ``tiny`` fixture): (params, cfg, engine kwargs)."""
+    from tpumlops.models import gdn_moe, mla_moe
+
+    mod, cfg = {
+        "mla-moe": (mla_moe, mla_moe.MlaMoeConfig.tiny()),
+        "gdn-moe": (gdn_moe, gdn_moe.GdnMoeConfig.tiny()),
+    }[name]
+    return mod.init(jax.random.key(0), cfg, jnp.float32), cfg, {"family": mod}
+
+
+ORDERS = [(mode, "llama") for mode in MODES] + [
+    ("chunked", "mla-moe"), ("chunked", "gdn-moe"),
+]
+
+
+@pytest.mark.parametrize("mode,family", ORDERS)
+def test_sending_chunks_ahead_does_not_change_the_tokens(tiny, mode, family):
+    """Request for request the tokens are those of the parent's order of
+    dispatches (the same engine with nothing sent ahead), in every mode
+    and for every family; the chunked path does send chunks ahead."""
+    params, cfg, kw = _family(family) if family != "llama" else (*tiny, {})
+    sent = []
+    eng = GenerationEngine(
+        params, cfg, max_slots=4, dtype=jnp.float32,
+        on_prefill_dispatch=sent.append, **MODES[mode][0], **kw,
+    )
+    eng.start(warmup=False)
+
+    def serve():
+        rider, doc = _rider_then_doc(eng, doc_new=6)
+        rest = [eng.submit(p, 12) for p in PROMPTS]
+        return [list(map(int, f.result(timeout=300)))
+                for f in (rider, doc, *rest)]
+
+    try:
+        send_ahead, eng._send_chunk_ahead = eng._send_chunk_ahead, lambda: None
+        parent_order = serve()
+        assert "ahead" not in sent
+        eng._send_chunk_ahead = send_ahead
+        served = serve()
+        assert not eng._open_ticks and not eng._pending
+    finally:
+        eng.shutdown()
+    assert served == parent_order
+    assert [len(out) for out in served] == [40, 6] + [12] * len(PROMPTS)
+    # Packed and unified admissions never take the single-admission path;
+    # without ``prefill_chunk`` there is no chunk to send.
+    assert ("ahead" in sent) == (mode == "chunked")
+
+
+class _CountsReadEarly:
+    """A chunk's on-device counts that remember being converted while the
+    chunk's tick (the one that waits on ``logits``) was still open."""
+
+    def __init__(self, eng, logits, counts, early):
+        self.eng, self.logits, self.counts, self.early = (
+            eng, logits, counts, early)
+
+    def __array__(self, *args, **kwargs):
+        if any(t.wait_on is self.logits for t in self.eng._open_ticks):
+            self.early.append(self)
+        return np.asarray(self.counts)
+
+
+def test_a_steps_read_back_converts_no_count_of_an_open_chunk():
+    """With ``on_moe`` set, the counts of a chunk queued behind the step
+    stay pending until its tick is closed: the step's read-back never
+    waits a whole chunk, and every count still arrives."""
+    params, cfg, kw = _family("mla-moe")
+    calls, early, sent = [], [], []
+    eng = GenerationEngine(
+        params, cfg, max_slots=4, dtype=jnp.float32, prefill_chunk=8,
+        on_moe=lambda program, *_rest: calls.append(program),
+        on_prefill_dispatch=sent.append, **kw,
+    )
+    eng.start(warmup=False)
+    chunk_program = eng._prefill_one_chunk
+
+    def guarded(*args):
+        logits, *rest, counts = chunk_program(*args)
+        return (logits, *rest, _CountsReadEarly(eng, logits, counts, early))
+
+    try:
+        eng._prefill_one_chunk = guarded
+        rider, doc = _rider_then_doc(eng)
+        doc.result(timeout=300)
+        rider.result(timeout=300)
+        steps = eng.dispatches_total["decode"]
+    finally:
+        eng.shutdown()
+    assert sent.count("ahead") == 4 and not early
+    assert calls.count("prefill") == len(sent) == 6
+    assert calls.count("decode") == steps
 
 
 @pytest.mark.parametrize("mode", list(MODES))
@@ -328,34 +445,199 @@ class _Wedged:
         raise RuntimeError("injected device error")
 
 
-def test_a_chunk_failing_at_its_deferred_wait_fails_its_own_admission(tiny):
-    eng = _engine(tiny, prefill_chunk=8)
+def _nth_call(eng, n, then):
+    """Wrap the chunk program: its ``n``-th call from now returns
+    ``then(outputs)``."""
+    chunk_program, seen = eng._prefill_one_chunk, []
+
+    def program(*args):
+        out = chunk_program(*args)
+        seen.append(1)
+        return then(out) if len(seen) == n else out
+
+    eng._prefill_one_chunk = program
+
+
+# Which of LONG's chunks fails at its deferred wait: the first is sent in
+# the admit phase and waited for behind its pass's step, the second goes
+# out behind that step and is waited for behind the next pass's.
+@pytest.mark.parametrize("nth,when", [(1, "in_turn"), (2, "ahead")])
+def test_a_chunk_failing_at_its_deferred_wait_fails_its_own_admission(
+    tiny, nth, when
+):
+    sent = []
+    eng = _engine(tiny, prefill_chunk=8, on_prefill_dispatch=sent.append)
     try:
         (reference,) = _serve(eng, [PROMPTS[1]], new=6)
         rider, started = _first_token_then(eng, PROMPTS[0], 40)
         assert started.wait(timeout=120)
-        chunk_program = eng._prefill_one_chunk
-
-        def failing_once(*args):
-            eng._prefill_one_chunk = chunk_program
-            _logits, *rest = chunk_program(*args)
-            return (_Wedged(), *rest)
-
-        eng._prefill_one_chunk = failing_once
+        del sent[:]
+        _nth_call(eng, nth, lambda out: (_Wedged(), *out[1:]))
         doc = eng.submit(LONG, 4)
         with pytest.raises(RuntimeError, match="injected device error"):
             doc.result(timeout=300)
-        # The step was already queued behind the chunk, so the failure
+        # A step was already queued behind the chunk, so the failure
         # surfaced inside ``_step``: the admission owns it all the same,
         # and the slots go the way of any lost device state.
         with pytest.raises(RuntimeError, match="generation step failed"):
             rider.result(timeout=300)
+        assert sent[nth - 1] == when
         assert eng._poison_counts == {eng._fingerprint(np.asarray(LONG)): 1}
         (again,) = _serve(eng, [PROMPTS[1]], new=6)
         assert not eng._pending and not eng._open_ticks
     finally:
         eng.shutdown()
     assert list(again) == list(reference)
+
+
+def test_a_step_failing_with_a_chunk_in_flight_spares_the_admission(tiny):
+    """The chunk sent ahead lives in the scratch, not in the slots' cache:
+    when the step after it fails, its tick is still journaled and the
+    admission goes on to the tokens it serves alone."""
+    sent = []
+    eng = _engine(tiny, prefill_chunk=8, on_prefill_dispatch=sent.append)
+    try:
+        (reference,) = _serve(eng, [LONG], new=4)
+        rider, started = _first_token_then(eng, PROMPTS[0], 40)
+        assert started.wait(timeout=120)
+        ticks = _spy_ticks(eng)
+        step_program = eng._decode_greedy
+
+        def failing_once(*args):
+            if "ahead" not in sent:
+                return step_program(*args)
+            eng._decode_greedy = step_program
+            raise RuntimeError("injected step error")
+
+        eng._decode_greedy = failing_once
+        del sent[:]
+        doc = eng.submit(LONG, 4)
+        with pytest.raises(RuntimeError, match="generation step failed"):
+            rider.result(timeout=300)
+        served = doc.result(timeout=300)
+        assert not eng._pending and not eng._open_ticks
+    finally:
+        eng.shutdown()
+    assert list(served) == list(reference)
+    assert len(sent) == 5 and sent[:2] == ["in_turn", "ahead"]
+    assert [k for k, _t0, _w in ticks].count("prefill") == 5 + 1
+
+
+def test_a_request_cancelled_with_its_chunk_in_flight_leaves_nothing_open(tiny):
+    sent = []
+    eng = _engine(tiny, prefill_chunk=8, on_prefill_dispatch=sent.append)
+    try:
+        (reference,) = _serve(eng, [PROMPTS[1]], new=6)
+        rider, started = _first_token_then(eng, PROMPTS[0], 40)
+        assert started.wait(timeout=120)
+        del sent[:]
+        cancelled, docs = [], queue.Queue()
+        _nth_call(
+            eng, 2,
+            lambda out: cancelled.append(docs.get(timeout=60).cancel()) or out,
+        )
+        doc = eng.submit(LONG, 4)
+        docs.put(doc)
+        assert len(rider.result(timeout=300)) == 40
+        assert cancelled == [True] and doc.cancelled()
+        (again,) = _serve(eng, [PROMPTS[1]], new=6)
+        # The admission ran to its end on the scratch and gave its slot
+        # back at its first token; the next one starts a fresh scratch.
+        assert sent[:5] == ["in_turn"] + ["ahead"] * 4
+        assert not eng._pending and not eng._open_ticks
+        assert all(s is None for s in eng._slots)
+    finally:
+        eng.shutdown()
+    assert list(again) == list(reference)
+
+
+def test_a_control_op_finds_no_open_tick_under_it(tiny):
+    seen, ops = [], []
+
+    def sent(when):
+        # From the engine thread, with the chunk sent ahead in flight.
+        if when == "ahead" and not ops:
+            ops.append(eng.run_control(
+                lambda: seen.append(len(eng._open_ticks))))
+
+    eng = _engine(tiny, prefill_chunk=8, on_prefill_dispatch=sent)
+    try:
+        rider, doc = _rider_then_doc(eng)
+        doc.result(timeout=300)
+        rider.result(timeout=300)
+        ops[0].result(timeout=60)
+    finally:
+        eng.shutdown()
+    assert seen == [0]
+
+
+def test_a_write_back_and_a_preemption_find_no_open_tick_under_them(tiny):
+    """With the prefix cache on, a chunk that owes a write-back is read
+    at once and is never sent ahead (a padded tail owes none); an eviction
+    reads the slots' cache with no tick open either."""
+    from tpumlops.server.prefix_cache import PrefixCacheConfig
+
+    sent, open_at = [], {"write-back": [], "evict": []}
+    eng = _engine(
+        tiny, prefill_chunk=8, preemption=True, slo_class="batch",
+        prefix_cache=PrefixCacheConfig(
+            enabled=True, budget_bytes=2**24, chunk_tokens=8),
+        on_prefill_dispatch=sent.append,
+    )
+    insert, evict = eng._prefix_cache.insert_chunk, eng._evict_slot
+
+    def insert_chunk(*args):
+        open_at["write-back"].append(len(eng._open_ticks))
+        return insert(*args)
+
+    def evict_slot(idx):
+        open_at["evict"].append(len(eng._open_ticks))
+        return evict(idx)
+
+    eng._prefix_cache.insert_chunk, eng._evict_slot = insert_chunk, evict_slot
+    try:
+        rider, doc = _rider_then_doc(eng, doc=LONG[:27])  # 3 chunks + a tail
+        doc.result(timeout=300)
+        rider.result(timeout=300)
+        # The rider's chunk is a padded tail; LONG's three full chunks are
+        # written back, its tail goes behind a step.
+        assert sent == ["in_turn"] * 4 + ["ahead"]
+        assert open_at["write-back"] == [0, 0, 0]
+        # Four batch streams hold every slot; an interactive request
+        # evicts one.
+        streams = [_first_token_then(eng, [7 + i, 9, 11], 30) for i in range(4)]
+        assert all(started.wait(timeout=120) for _f, started in streams)
+        urgent = eng.submit(PROMPTS[3], 4, slo_class="interactive")
+        assert len(urgent.result(timeout=300)) == 4
+        for fut, _started in streams:
+            assert len(fut.result(timeout=300)) == 30
+        assert open_at["evict"] == [0]
+    finally:
+        eng.shutdown()
+
+
+def test_shutdown_with_a_chunk_in_flight_returns(tiny):
+    from tpumlops.server.generation import EngineShutdown
+
+    in_flight, stopping = threading.Event(), threading.Event()
+
+    def sent(when):  # the engine thread holds here until the stop is set
+        if when == "ahead":
+            in_flight.set()
+            assert stopping.wait(timeout=120)
+
+    eng = _engine(tiny, prefill_chunk=8, on_prefill_dispatch=sent)
+    try:
+        rider, doc = _rider_then_doc(eng)
+        assert in_flight.wait(timeout=120)
+        eng._stop.set()
+    finally:
+        stopping.set()
+        eng.shutdown()
+    assert not eng._thread.is_alive()
+    assert not eng._open_ticks and not eng._pending
+    with pytest.raises(EngineShutdown):
+        doc.result(timeout=60)
 
 
 def test_prefill_tokens_exclude_cached_prefix_tokens(tiny):

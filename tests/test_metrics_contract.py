@@ -86,6 +86,10 @@ EXPECTED_SERVER = {
     # Non-decode ticks by whether a decode dispatch was queued behind the
     # program when the engine thread waited for it ("step" | "none").
     "tpumlops_prefill_waits": ("counter", _IDENT + ("queued_behind",)),
+    # Chunk programs of the single-admission path by where the engine
+    # dispatched them ("ahead": right behind the pass's decode step |
+    # "in_turn": in the admit phase).
+    "tpumlops_prefill_dispatch": ("counter", _IDENT + ("when",)),
     # Key blocks of the capacity a prefill chunk of the latent-attention
     # family multiplied ("walked") and did not reach ("skipped").
     "tpumlops_prefill_key_blocks": ("counter", _IDENT + ("kind",)),

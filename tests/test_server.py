@@ -427,7 +427,7 @@ def test_loader_sniffs_forest_flavor(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def llm_server(tmp_path_factory):
+def llm_artifact(tmp_path_factory):
     import jax
 
     from tpumlops.models import llama
@@ -449,16 +449,32 @@ def llm_server(tmp_path_factory):
             "max_seq": cfg.max_seq,
         },
     )
+    return art
+
+
+def _llm_server(art, **tpu):
     config = ServerConfig(
         model_name="llm",
         model_uri=str(art),
         predictor_name="v1",
         deployment_name="llm",
         namespace="models",
-        tpu=TpuSpec.from_spec({"meshShape": {"tp": 1}, "maxBatchSize": 4}),
+        tpu=TpuSpec.from_spec(
+            {"meshShape": {"tp": 1}, "maxBatchSize": 4, **tpu}),
     )
-    server = build_server(config)
-    handle = serve(server)
+    return serve(build_server(config))
+
+
+@pytest.fixture(scope="module")
+def llm_server(llm_artifact):
+    handle = _llm_server(llm_artifact)
+    yield handle
+    handle.stop()
+
+
+@pytest.fixture(scope="module")
+def chunked_llm_server(llm_artifact):
+    handle = _llm_server(llm_artifact, prefillChunk=8)
     yield handle
     handle.stop()
 
@@ -707,6 +723,48 @@ def test_prefill_waits_count_one_a_non_decode_tick(llm_server):
     assert resp.status_code == 200
     w1, p1 = scrape()
     assert w1 - w0 == p1 - p0 >= 1
+
+
+def test_prefill_dispatch_counts_one_a_chunk_program(chunked_llm_server):
+    """``tpumlops_prefill_dispatch_total{when}``: ``ahead`` + ``in_turn``
+    is the chunk programs dispatched (the prefill ticks less one insert a
+    request); a request's first chunk is always sent in its turn."""
+    base = chunked_llm_server.base
+
+    def scrape():
+        text = httpx.get(base + "/metrics", timeout=10).text
+        assert "# TYPE tpumlops_prefill_dispatch_total counter" in text
+        sent = {"ahead": 0.0, "in_turn": 0.0}
+        for ln in text.splitlines():
+            if ln.startswith("tpumlops_prefill_dispatch_total{"):
+                labels, value = ln.rsplit(" ", 1)
+                (when,) = [w for w in sent if f'when="{w}"' in labels]
+                assert all(f'{name}="' in labels for name in (
+                    "deployment_name", "predictor_name", "namespace"))
+                sent[when] += float(value)
+        ticks = sum(
+            float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+            if ln.startswith("tpumlops_engine_dispatches_total{")
+            and 'op="prefill"' in ln)
+        return sent, ticks
+
+    sent0, ticks0 = scrape()
+    url = base + "/v2/models/llm/generate"
+    with httpx.stream(
+        "POST", url, timeout=120,
+        json={"prompt_ids": [5, 9, 2, 7], "max_new_tokens": 40, "stream": True},
+    ) as rider:
+        lines = rider.iter_lines()
+        assert next(ln for ln in lines if ln.startswith("data: "))
+        doc = httpx.post(  # four chunks of 8 beside the rider's steps
+            url, timeout=120,
+            json={"prompt_ids": list(range(3, 33)), "max_new_tokens": 3})
+        assert doc.status_code == 200
+        assert sum(ln.startswith("data: ") for ln in lines) == 40
+    sent1, ticks1 = scrape()
+    ahead, in_turn = (sent1[w] - sent0[w] for w in ("ahead", "in_turn"))
+    assert ahead + in_turn == (ticks1 - ticks0) - 2 == 1 + 4
+    assert in_turn >= 2
 
 
 def test_generate_streaming_rejects_multi_prompt(llm_server):
