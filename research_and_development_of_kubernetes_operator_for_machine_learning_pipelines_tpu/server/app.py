@@ -991,7 +991,11 @@ class TpuInferenceServer:
         the ``engine.*`` spans (host TraceMe events, still recorded) say
         what it was left on to show.  ``start_trace`` / ``stop_trace``
         run in an executor: ``stop_trace`` serializes the capture for
-        seconds, and the event loop keeps writing SSE meanwhile."""
+        seconds, and the event loop keeps writing SSE meanwhile.  The
+        answer carries ``spans_at``: the ``/debug/spans`` payload (span
+        table and starvation account) as the capture began and as it
+        ended, so ``scripts/capture_report.py`` can set the account's
+        deltas beside the device's own gaps over the same seconds."""
         import math
         import tempfile
 
@@ -1016,8 +1020,14 @@ class TpuInferenceServer:
                     {"error": "a profile capture is already running"}, status=409
                 )
             loop = asyncio.get_running_loop()
+            spans_at: dict = {}
+
+            def start() -> None:
+                jax.profiler.start_trace(out_dir, profiler_options=options)
+                spans_at["start"] = self._spans_payload()
 
             def stop() -> float:
+                spans_at["stop"] = self._spans_payload()
                 t0 = time.perf_counter()
                 with contextlib.suppress(Exception):
                     # raises "no session" when start_trace itself failed
@@ -1026,12 +1036,7 @@ class TpuInferenceServer:
 
             try:
                 try:
-                    await loop.run_in_executor(
-                        None,
-                        lambda: jax.profiler.start_trace(
-                            out_dir, profiler_options=options
-                        ),
-                    )
+                    await loop.run_in_executor(None, start)
                     await asyncio.sleep(duration)
                 finally:
                     stop_s = await loop.run_in_executor(None, stop)
@@ -1044,6 +1049,7 @@ class TpuInferenceServer:
                     "duration_s": duration,
                     "stop_trace_s": round(stop_s, 3),
                     "evicted": evicted,
+                    "spans_at": spans_at,
                 }
             )
         except (ValueError, TypeError, json.JSONDecodeError) as e:
@@ -1138,8 +1144,17 @@ class TpuInferenceServer:
     async def handle_debug_spans(self, request: web.Request) -> web.Response:
         """The server's tracer (``utils/tracing.py``): per span name the
         count, total, self time, mean and max — the engine loop's
-        ``engine.*`` phases."""
-        return web.json_response({"spans": self.metrics.tracer.as_dict()})
+        ``engine.*`` phases — and the engine's starvation account: when
+        the chip had nothing to run, by what it was then given and by
+        what the host was doing meanwhile."""
+        return web.json_response(self._spans_payload())
+
+    def _spans_payload(self) -> dict:
+        tracer = self.metrics.tracer
+        return {
+            "spans": tracer.as_dict(),
+            "device_starved": tracer.account("device_starved").as_dict(),
+        }
 
     async def handle_live(self, request: web.Request) -> web.Response:
         # Live through loading AND draining: kubelet must not kill a pod
@@ -1937,7 +1952,6 @@ def make_gen_engine(
         unified_step=config.tpu.unified_step,
         on_dispatch=metrics.inc_dispatch if metrics else None,
         on_prefill_tokens=metrics.inc_prefill_tokens if metrics else None,
-        on_prefill_wait=metrics.inc_prefill_wait if metrics else None,
         on_prefill_dispatch=metrics.inc_prefill_dispatch if metrics else None,
         on_key_blocks=metrics.inc_prefill_key_blocks if metrics else None,
         family=family,

@@ -429,8 +429,8 @@ class GenerationEngine:
         tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
         family=None,  # the causal-LM family's module (None: models.llama)
         on_moe: Callable[[str, dict, int, int], None] | None = None,
-        on_prefill_wait: Callable[[str], None] | None = None,  # "step"|"none"
-        on_prefill_dispatch: Callable[[str], None] | None = None,  # "ahead"|"in_turn"
+        # "ahead"|"in_turn": one a chunk program of the single-admission path
+        on_prefill_dispatch: Callable[[str], None] | None = None,
         on_key_blocks: Callable[[int, int], None] | None = None,  # walked, skipped
     ):
         import jax
@@ -660,7 +660,7 @@ class GenerationEngine:
         # time nor lend it its own.  No option arms or disarms this:
         # watching the engine (recorder, telemetry) does not change the
         # order of its dispatches.
-        self._on_prefill_wait = on_prefill_wait
+        self._unseen = 0  # tick programs dispatched and not yet seen to end
         self._on_prefill_dispatch = on_prefill_dispatch
         self._on_prefix_l2 = on_prefix_l2
         if prefix_enabled:
@@ -768,13 +768,13 @@ class GenerationEngine:
         self._super_width = sw
         self._on_dispatch = on_dispatch
         self._on_prefill_tokens = on_prefill_tokens
-        # Host-time spans of the scheduler loop (``engine.*``: one root
-        # per pass of ``_loop`` and nine phases under it).  Always on;
-        # the profiler sink puts them on the capture's clock.
+        # Host-time spans of the scheduler loop (``engine.*``: a root a pass
+        # of ``_loop``, nine phases under it) and the starvation account.
         from ..utils.tracing import Tracer
 
         self.tracer = tracer if tracer is not None else Tracer(profiler=True)
         self._span = self.tracer.span
+        self._starved = self.tracer.account("device_starved")
         # Scheduler-loop watchdog (server/watchdog.py): None — the
         # default — keeps the loop byte-for-byte (every beat below is
         # guarded).  Leader-side only, like the recorder: followers
@@ -2662,9 +2662,9 @@ class GenerationEngine:
         self._beat("admit")
         with span("engine.prefill_dispatch"):
             first = self._dispatch_admit(
-                ids, slot_idx, L, slot_key,
-                req.temperature, req.top_k, req.top_p,
+                ids, slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
             )
+            self._dispatched("prefill")
         if not self._in_warmup:
             self.prefill_forwards += 1
             self._note_prefill_tokens(L)
@@ -2697,11 +2697,10 @@ class GenerationEngine:
         self, kind: str, t0: float, wait_on, owners=(),
         noted: int | None = None, **fields
     ) -> None:
-        """Register a prefill-side program just dispatched.  Nothing
-        waits here: :meth:`_close_ticks` journals it (``fields`` are
-        :meth:`_record_tick`'s) at the next blocking read.  ``noted`` is
-        the length ``_moe_pending`` had before the dispatch: what the
-        program noted since is the tick's until it closes."""
+        """Register a prefill-side program just dispatched.  Nothing waits here:
+        :meth:`_close_ticks` journals it (``fields`` are :meth:`_record_tick`'s) at
+        the next blocking read.  ``noted`` is the length ``_moe_pending`` had before
+        the dispatch: what the program noted since is the tick's until it closes."""
         counts = [] if noted is None else self._moe_pending[noted:]
         if counts:
             del self._moe_pending[noted:]
@@ -2715,15 +2714,14 @@ class GenerationEngine:
         """A blocking point: wait for the open ticks in device order and
         journal each with the wall the host saw for it alone.
 
-        ``behind`` is the heartbeat kind of a decode-side dispatch that
-        is already queued behind them (the chip goes straight on to it);
-        None where nothing is.  ``first`` closes only that many, the
-        oldest: a step leaves the chunk it sent ahead open.  Every wait
-        is ``engine.prefill_sync`` (the benchmark's ``loop_host_ms``
-        takes that span as time blocked on the device, wherever the loop
-        takes it).  A device error raises :class:`_TickFailed`: it is
-        the admission's, not the step's, and the ticks behind it go with
-        it."""
+        ``behind`` is the heartbeat kind of a decode-side dispatch already
+        queued behind them, None where nothing is.  ``first`` closes only
+        that many, the oldest: a step leaves the chunk it sent ahead open.
+        Every wait is ``engine.prefill_sync`` (the benchmark's
+        ``loop_host_ms`` takes that span as time blocked on the device,
+        wherever the loop takes it).  A device error raises
+        :class:`_TickFailed`: it is the admission's, not the step's, and
+        the ticks behind it go with it."""
         if not self._open_ticks:
             return
         import jax
@@ -2742,22 +2740,25 @@ class GenerationEngine:
             self._moe_pending.extend(tick.counts)
             start, wall = self._tick_done(tick.t0)
             with span("engine.journal"):
-                if self._on_prefill_wait is not None:
-                    self._on_prefill_wait("step" if behind else "none")
                 self._record_tick(tick.kind, start, wall, **tick.fields)
         if behind:
             self._beat(behind)
 
     def _tick_done(self, t0: float) -> tuple[float, float]:
-        """Stamp a completion the engine thread has just seen; returns
-        the tick's (start, wall).  The device runs programs in dispatch
-        order, so a program dispatched at ``t0`` cannot have started
-        before the one ahead of it was seen to end: walls taken in
-        completion order never overlap, and a pass's walls sum to no
-        more than the pass."""
+        """Stamp a completion the engine thread has just seen; returns the tick's
+        (start, wall).  The device runs programs in dispatch order, so a program
+        dispatched at ``t0`` cannot have started before the one ahead of it was
+        seen to end: walls taken in completion order never overlap, and a pass's
+        walls sum to no more than the pass.  Was it the last program out, the chip
+        has nothing left that the host knows of: an interval of the starvation
+        account opens, and :meth:`_dispatched` closes it."""
         now = time.perf_counter()
         start = max(t0, self._done_at)
         self._done_at = now
+        if self._unseen:  # 0 through a warm-up sweep: nothing is counted
+            self._unseen -= 1
+            if not self._unseen:
+                self._starved.open()
         return start, now - start
 
     def _record_tick(
@@ -2766,10 +2767,9 @@ class GenerationEngine:
         spec_accepted: int = 0, cost=None, steps: int = 0,
         roles: dict | None = None,
     ) -> None:
-        """Journal one engine device dispatch (tick-kind metric + flight
-        recorder + the dispatches-by-op counter).  Callers skip warmup
-        themselves; every sink is optional and the default costs one
-        dict update + branch per tick.
+        """Journal one engine device dispatch (tick-kind metric + flight recorder
+        + the dispatches-by-op counter).  Callers skip warmup themselves; every
+        sink is optional and the default costs one dict update + branch per tick.
 
         ``cost`` is the tick's analytic ``(flops, hbm_bytes)`` (device
         telemetry only, None otherwise): joined with the wall into MFU /
@@ -3403,6 +3403,7 @@ class GenerationEngine:
         ts = time.perf_counter()
         with span("engine.prefill_dispatch"):
             self._dispatch_sp_prefill(ids, L)
+            self._dispatched("sp-prefill")
         if not self._in_warmup:
             self.prefill_forwards += 1
             self._note_prefill_tokens(L)
@@ -3423,6 +3424,7 @@ class GenerationEngine:
                 slot_idx, L, slot_key, req.temperature, req.top_k, req.top_p,
                 last_idx=0,
             )
+            self._dispatched("insert")
         if not self._in_warmup:
             self._open_tick(
                 "prefill", t0, first, (req,),
@@ -3526,16 +3528,14 @@ class GenerationEngine:
         return self._prefill_batch
 
     def _parked_batch(self, bucket: int) -> tuple:
-        """A fully PARKED packed-call argument set — (ids, slots,
-        offsets, last_pos, final_lens, key_data, temps, tks, tps) where
-        every row writes nothing (offset == capacity drops), finalizes
-        nothing (last_pos == -1), and carries neutral sampling params.
-        The warmup bucket sweep dispatches it as-is; :meth:`_packed_tick`
-        overwrites rows ``[0, n)`` with the real admissions — ONE
-        construction site, so the warmed shapes can never drift from the
-        live call's.  Pad slots are pairwise distinct (and their parked
-        positions start at capacity, so equality with a REAL row's
-        reserved slot cannot collide index tuples — see
+        """A fully PARKED packed-call argument set — (ids, slots, offsets, last_pos,
+        final_lens, key_data, temps, tks, tps) where every row writes nothing (offset ==
+        capacity drops), finalizes nothing (last_pos == -1), and carries neutral
+        sampling params. The warmup bucket sweep dispatches it as-is;
+        :meth:`_packed_tick` overwrites rows ``[0, n)`` with the real admissions — ONE
+        construction site, so the warmed shapes can never drift from the live call's.
+        Pad slots are pairwise distinct (and their parked positions start at capacity,
+        so equality with a REAL row's reserved slot cannot collide index tuples — see
         llama._commit_chunk_at's unique-indices contract)."""
         C = self._prefill_chunk_size
         return (
@@ -3553,12 +3553,11 @@ class GenerationEngine:
         )
 
     def _packed_tick(self) -> None:
-        """Advance up to ``prefill_batch`` in-flight admissions by one
-        chunk each — ONE batched device call (plus one seed op per
-        admission entering with a radix-cached prefix).  The token-budget
-        knob caps the chunks packed per tick, Sarathi-style: decode ticks
-        interleave every tick regardless, so bounding prefill work per
-        tick bounds the decode-cadence jitter long prompts can inject."""
+        """Advance up to ``prefill_batch`` in-flight admissions by one chunk each — ONE
+        batched device call (plus one seed op per admission entering with a radix-cached
+        prefix).  The token-budget knob caps the chunks packed per tick, Sarathi-style:
+        decode ticks interleave every tick regardless, so bounding prefill work per tick
+        bounds the decode-cadence jitter long prompts can inject."""
         self._beat("packed-prefill")
         C = self._prefill_chunk_size
         max_chunks = self._prefill_batch
@@ -3619,9 +3618,8 @@ class GenerationEngine:
                 1 for prog in chunk_progs
                 if prog.next_idx == len(prog.chunks) - 1
             )
-            # The compiled program computes every row of the B_p bucket
-            # (parked pad rows included); the mean attended span is over
-            # the REAL chunks' offsets.
+            # The compiled program computes every row of the B_p bucket (parked
+            # pad rows included); the mean attended span is over the REAL chunks'.
             attended = (
                 sum(float(offsets[i]) for i in range(n)) / n + C / 2
             )
@@ -3675,6 +3673,7 @@ class GenerationEngine:
             self._dispatch_seed_slot(
                 prog.cached_kv, prog.slot, prog.cached_tokens
             )
+            self._dispatched("seed")
         prog.seeded = True
         prog.cached_kv = []
         self.prefix_hits += 1
@@ -3792,6 +3791,7 @@ class GenerationEngine:
             jnp.asarray(r_tks),
             jnp.asarray(r_tps),
         )
+        self._dispatched("packed-prefill")
         with self._span("engine.prefill_sync"):
             return np.asarray(firsts)
 
@@ -3855,13 +3855,12 @@ class GenerationEngine:
         return jax.random.key(int(req.seed))
 
     def _chunk_tick(self) -> None:
-        """Advance the in-flight chunked admission by ONE device op (a
-        prefix-cache seed or one prefill chunk), unless its next chunk
-        went out behind the last pass's step already
-        (:meth:`_send_chunk_ahead`); once every chunk is dispatched,
-        install the sequence into its slot.  Single-admission mode only
-        (the batch-1 scratch cache serializes admissions); packed mode
-        advances through :meth:`_packed_tick`."""
+        """Advance the in-flight chunked admission by ONE device op (a prefix-cache seed
+        or one prefill chunk), unless its next chunk went out behind the last pass's
+        step already (:meth:`_send_chunk_ahead`); once every chunk is dispatched,
+        install the sequence into its slot.  Single-admission mode only (the batch-1
+        scratch cache serializes admissions); packed mode advances through
+        :meth:`_packed_tick`."""
         assert self._pending
         span = self._span
         self._beat("prefill")
@@ -3874,6 +3873,7 @@ class GenerationEngine:
             ts = time.perf_counter()
             with span("engine.prefill_dispatch"):
                 self._dispatch_seed(prog.cached_kv, prog.cached_tokens)
+                self._dispatched("seed")
             prog.seeded = True
             prog.cached_kv = []  # host copies handed off; free the refs
             self.prefix_hits += 1
@@ -3912,6 +3912,7 @@ class GenerationEngine:
             self._dispatch_chunk(
                 ids, fresh=prog.next_idx == 0 and not prog.seeded
             )
+            self._dispatched("chunk")
         if not self._in_warmup:
             self.prefill_chunks_dispatched += 1
             self.prefill_forwards += 1
@@ -3951,6 +3952,7 @@ class GenerationEngine:
                 last_idx=(L - 1) - prog.cached_tokens
                 - C * (len(prog.chunks) - 1),
             )
+            self._dispatched("insert")
         if not self._in_warmup:
             self._open_tick(
                 "prefill", t0, first, (req,),
@@ -3981,11 +3983,10 @@ class GenerationEngine:
     def _record_token(
         self, slot_idx: int, token: int, t: float | None = None
     ) -> None:
-        """Credit one emitted token to a slot.  ``t`` overrides the
-        token's wall timestamp (fused multi-step harvests reconstruct
-        per-token instants across the tick wall — K tokens landing on
-        one perf_counter() read would zero every ITL observation and
-        stack the Perfetto token instants on one point)."""
+        """Credit one emitted token to a slot.  ``t`` overrides the token's wall
+        timestamp (fused multi-step harvests reconstruct per-token instants across the
+        tick wall — K tokens landing on one perf_counter() read would zero every ITL
+        observation and stack the Perfetto token instants on one point)."""
         slot = self._slots[slot_idx]
         assert slot is not None
         if slot.future.cancelled():
@@ -4052,14 +4053,13 @@ class GenerationEngine:
     def _step(self) -> None:
         """One batched decode tick over every occupied slot.
 
-        With speculation enabled and every occupied slot greedy, the tick
-        tries a draft+verify (multi-token) pass first; a tick with no
-        drafts anywhere — or any sampling slot — runs the original
-        single-token step unchanged.
+        With speculation enabled and every occupied slot greedy, the tick tries a
+        draft+verify (multi-token) pass first; a tick with no drafts anywhere — or any
+        sampling slot — runs the original single-token step unchanged.
 
-        The unified engine routes EVERY tick through the super-step
-        assembler instead: one dispatch carries the tick's decode,
-        verify, and packed-prefill work together."""
+        The unified engine routes EVERY tick through the super-step assembler instead:
+        one dispatch carries the tick's decode, verify, and packed-prefill work
+        together."""
         if self._unified:
             self._super_tick()
             return
@@ -4119,6 +4119,7 @@ class GenerationEngine:
         self._beat("decode")
         with span("engine.decode_dispatch"):
             self._dispatch_step(active_np, window, sampling)
+            self._dispatched("decode")
         # The step is queued behind the pass's chunk, and the admission's
         # next chunk goes behind the step: now wait for what went before
         # the step, then for the step, in the order the device runs them.
@@ -4186,12 +4187,11 @@ class GenerationEngine:
         bookkeeping is exact through tick N-1 when tick N+1 is
         dispatched.  Only two decisions need host state — whether to
         keep the burst going, and the attention window — and both use
-        conservative bounds (a row can advance at most K per tick), so
-        a mid-scan EOS costs at most one trailing all-inactive dispatch,
+        conservative bounds (a row can advance at most K per tick), so a
+        mid-scan EOS costs at most one trailing all-inactive dispatch,
         never a wrong result.  The burst exits with every harvest
         drained: the scheduler never leaves ``_step`` holding un-synced
-        tokens, so admission and shutdown paths see exact slot truth.
-        """
+        tokens, so admission and shutdown paths see exact slot truth."""
         K = self._decode_steps
         B = self.max_slots
         span = self._span
@@ -4236,6 +4236,7 @@ class GenerationEngine:
                     eos_ids if start else None,
                     window, sampling,
                 )
+                self._dispatched("multistep")
             with span("engine.decode_assemble"):
                 for i in range(B):
                     emit = min(int(rem_hi[i]), K)
@@ -4274,14 +4275,13 @@ class GenerationEngine:
     def _harvest_fused(self, tok_block_dev, valid_dev, t0, window) -> None:
         """Block on one fused tick's outputs and credit its tokens.
 
-        ``valid[i]`` counts the scan steps row ``i`` was active for —
-        token columns at/after it are frozen copies the latch never
-        emitted (and whose K/V was never committed: the in-scan active
-        gate parks those writes, so no host-side truncation is needed).
-        Per-token timestamps are reconstructed by spacing the row's
-        valid tokens across the tick wall (clamped monotone against the
-        row's previous token): K tokens on one instant would zero every
-        ITL observation and stack the Perfetto instants."""
+        ``valid[i]`` counts the scan steps row ``i`` was active for — token columns
+        at/after it are frozen copies the latch never emitted (and whose K/V was never
+        committed: the in-scan active gate parks those writes, so no host-side
+        truncation is needed). Per-token timestamps are reconstructed by spacing the
+        row's valid tokens across the tick wall (clamped monotone against the row's
+        previous token): K tokens on one instant would zero every ITL observation and
+        stack the Perfetto instants."""
         self._close_ticks(behind="multistep")
         with self._span("engine.decode_readback"):
             toks = np.asarray(tok_block_dev)  # the deferred device sync
@@ -4743,7 +4743,7 @@ class GenerationEngine:
             self._decode_steps,
             bool(sampling),
         )
-        self._close_ticks(behind="superstep")
+        self._queued_behind("superstep")
         with self._span("engine.decode_readback"):
             return (
                 np.asarray(tok_block), np.asarray(valid), np.asarray(greedy),
@@ -4898,7 +4898,7 @@ class GenerationEngine:
             jnp.asarray(draft_len),
             int(window),
         )
-        self._close_ticks(behind="verify")
+        self._queued_behind("verify")
         with self._span("engine.decode_readback"):
             return np.asarray(greedy), np.asarray(accepted)
 
@@ -4964,6 +4964,35 @@ class GenerationEngine:
                 window,
             )
         self._note_experts("decode", int(np.sum(active_np)), len(active_np), aux)
+
+    # -- the starvation account ------------------------------------------------
+    # When the chip had nothing to run, what it was waiting to be given and
+    # what the host was doing meanwhile: ``tracer.account("device_starved")``.
+    # An interval opens where the engine thread sees the last tick program it
+    # dispatched end (``_tick_done``) and closes where the call that hands the
+    # device the next one returns, under that program's kind (a dispatch onto
+    # an idle chip is the host's time too).  It is the host's view: a
+    # completion is seen when the thread blocks on it or next looks, so the
+    # account can only under-count.  Programs that are no ticks (the scratch's
+    # zero-fills, an admission's eager scalars) end no interval: they are the
+    # host work it charges.  Always on; a warm-up sweep counts nothing.
+
+    def _dispatched(self, kind: str) -> None:
+        """The device has just been handed a tick program: one more is
+        out, and the starved interval, if one is open, ends before it.
+        Waiting for traffic is no part of it; what no phase covered is
+        the root's."""
+        if self._in_warmup:
+            return
+        if self._starved.mark is not None:
+            self._starved.close(kind, "engine.iteration", ("engine.wait_work",))
+        self._unseen += 1
+
+    def _queued_behind(self, kind: str) -> None:
+        """A decode-side program whose read-back follows at once (its
+        dispatch span holds both) is out: wait for what went before it."""
+        self._dispatched(kind)
+        self._close_ticks(behind=kind)
 
     def _send_chunk_ahead(self) -> None:
         """Dispatch the in-flight admission's next chunk right behind the
@@ -5242,6 +5271,10 @@ class GenerationEngine:
             self._close_ticks()
         except _TickFailed as failed:
             self._drop_admission(failed.owners, failed)
+        # What was out is lost with the device state: nothing is unseen,
+        # and no interval runs from a completion that never came.
+        self._unseen = 0
+        self._starved.drop()
         for i, slot in enumerate(self._slots):
             if slot is not None and not slot.future.done():
                 self._abort_trace(slot.trace, "error")

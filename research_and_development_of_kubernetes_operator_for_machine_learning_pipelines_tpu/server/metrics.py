@@ -39,9 +39,10 @@ _LATENCY_BUCKETS = (
 
 
 class _SpanCollector:
-    """``tpumlops_span_*``: the tracer's per-name stats rendered when
-    ``/metrics`` is scraped, so a span costs the hot path no prometheus
-    call."""
+    """``tpumlops_span_*`` and ``tpumlops_device_starved_*``: the
+    tracer's per-name stats and the engine's starvation account
+    (``tracer.account("device_starved")``) rendered when ``/metrics`` is
+    scraped, so neither costs the hot path a prometheus call."""
 
     def __init__(self, tracer: Tracer, identity: dict[str, str]):
         self._tracer = tracer
@@ -68,7 +69,37 @@ class _SpanCollector:
             seconds.add_metric(values, s.total_s)
             self_seconds.add_metric(values, s.self_s)
             count.add_metric(values, s.count)
-        return [seconds, self_seconds, count]
+        return [seconds, self_seconds, count, *self._starved()]
+
+    def _starved(self):
+        ident = list(self._identity.values())
+        seconds = CounterMetricFamily(
+            "tpumlops_device_starved_seconds",
+            "Engine-thread time between seeing the last dispatched tick "
+            "program end and handing the device the next, by the kind of "
+            "program dispatched at the interval's end; time waiting for "
+            "traffic (engine.wait_work) is in no interval",
+            labels=[*self._identity, "before"],
+        )
+        intervals = CounterMetricFamily(
+            "tpumlops_device_starved_intervals",
+            "Such intervals closed, by the kind of program dispatched at "
+            "their end",
+            labels=[*self._identity, "before"],
+        )
+        by_span = CounterMetricFamily(
+            "tpumlops_device_starved_by_span_seconds",
+            "The same seconds by the engine.* span whose self time covered "
+            "them: what the host was doing while the chip had nothing",
+            labels=[*self._identity, "span"],
+        )
+        account = self._tracer.account("device_starved")
+        for before, (s, n) in sorted(account.by_label.copy().items()):
+            seconds.add_metric([*ident, before], s)
+            intervals.add_metric([*ident, before], n)
+        for name, s in sorted(account.by_span.copy().items()):
+            by_span.add_metric([*ident, name], s)
+        return [seconds, intervals, by_span]
 
 
 class ServerMetrics:
@@ -168,13 +199,6 @@ class ServerMetrics:
             buckets=(1, 2, 4, 8, 16, 32, 64),
             registry=self.registry,
         )
-        self.decode_step_seconds = Histogram(
-            "tpumlops_decode_step_seconds",
-            "Wall time of one batched decode step",
-            ident_labels,
-            buckets=_LATENCY_BUCKETS,
-            registry=self.registry,
-        )
         # Prefix KV cache (server/prefix_cache.py): the promotion gate's
         # operator can watch hit rate / cached-token volume per predictor
         # to judge whether a canary inherits the production prefix mix.
@@ -184,19 +208,6 @@ class ServerMetrics:
             "wrote; cached-prefix tokens count in "
             "tpumlops_prefix_cache_cached_tokens instead",
             ident_labels,
-            registry=self.registry,
-        )
-        # How often the engine thread's wait for a prefill-side program
-        # was hidden behind work for the chip: step / (step + none) is
-        # the share of such waits taken with a decode step already
-        # queued behind the program.
-        self.prefill_waits = Counter(
-            "tpumlops_prefill_waits_total",
-            "Non-decode engine ticks (seed, chunk, insert, sp-prefill, "
-            "packed chunks) by whether a decode dispatch was already "
-            "queued behind the program when the engine thread waited "
-            "for it",
-            ident_labels + ["queued_behind"],
             registry=self.registry,
         )
         # How often an admission's chunk went out behind the pass's
@@ -416,7 +427,7 @@ class ServerMetrics:
         )
         # Per-request latency decomposition (with ttft_seconds): ITL is
         # the steady-state token cadence a streaming client feels —
-        # decode_step_seconds measures the device tick, ITL measures the
+        # tick_seconds{kind="decode"} measures the device tick, ITL the
         # request (a tick serves many slots; a slot skips ticks while
         # its admission peer prefills).
         self.itl_seconds = Histogram(
@@ -708,10 +719,10 @@ class ServerMetrics:
         admitting: int = 0,
     ):
         # active_slots == 0 is the engine's idle heartbeat: refresh the
-        # occupancy gauges but keep the per-tick histograms tick-only.
+        # occupancy gauges but keep the batch-size histogram tick-only
+        # (the step's wall is tpumlops_tick_seconds{kind="decode"}).
         if active_slots > 0:
             self.decode_batch.labels(**self.identity).observe(active_slots)
-            self.decode_step_seconds.labels(**self.identity).observe(seconds)
         self.engine_active_slots.labels(**self.identity).set(active_slots)
         self.engine_queue_depth.labels(**self.identity).set(queue_depth)
         self.engine_admitting.labels(**self.identity).set(admitting)
@@ -776,11 +787,6 @@ class ServerMetrics:
 
     def inc_prefill_tokens(self, n: int):
         self.prefill_tokens.labels(**self.identity).inc(n)
-
-    def inc_prefill_wait(self, queued_behind: str):
-        self.prefill_waits.labels(
-            **self.identity, queued_behind=queued_behind
-        ).inc()
 
     def inc_prefill_dispatch(self, when: str):
         self.prefill_dispatch.labels(**self.identity, when=when).inc()

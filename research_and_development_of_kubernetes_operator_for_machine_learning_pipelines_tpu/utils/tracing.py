@@ -14,6 +14,13 @@ always on; there is no switch.  Three sinks read the one primitive:
   ``jax.profiler.TraceAnnotation`` while a capture is running, so the
   span lands on its thread's line of the ``/host:CPU`` plane of the same
   ``.xplane.pb`` as the device's ops, on the profiler's clock.
+
+An :class:`IntervalAccount` (``tracer.account(name)``) keeps, for
+intervals one thread opens and closes, their seconds and count by the
+label each was closed under and the same seconds by the span whose SELF
+time covered them.  The generation engine's ``device_starved`` account
+is the one user: open where the chip was seen to run out of programs,
+closed where it is handed the next.
 """
 
 from __future__ import annotations
@@ -97,6 +104,103 @@ class _Span:
         return False
 
 
+def _open_self(state: _ThreadState, now: float) -> list[tuple[str, float]]:
+    """(name, self time so far) of every span open on ``state``'s thread."""
+    out = []
+    node, child_t0 = state.top, now
+    while node is not None:
+        # What an open child has covered is not in ``_child_s`` yet.
+        out.append((node._name, child_t0 - node._t0 - node._child_s))
+        node, child_t0 = node._parent, node._t0
+    return out
+
+
+class IntervalAccount:
+    """Intervals of ONE thread (the only writer, so no lock), each closed
+    under a label: ``by_label`` holds (seconds, intervals), ``by_span``
+    the same seconds by the span whose self time covered them.  A reader
+    on another thread copies a dict and never sees a torn record; between
+    the two dicts it may see one interval half booked."""
+
+    __slots__ = ("_tracer", "_state", "mark", "by_label", "by_span")
+
+    def __init__(self, tracer: Tracer):
+        self._tracer = tracer
+        self._state: _ThreadState | None = None  # the writer's, at its first open
+        self.mark: tuple | None = None  # None: no interval is open
+        self.by_label: dict[str, tuple[float, int]] = {}
+        self.by_span: dict[str, float] = {}
+
+    def open(self) -> None:
+        """An interval begins now: mark the thread's self-time account
+        (the closed spans' records, which are replaced whole on every
+        exit, and what every open span has so far)."""
+        state = self._state
+        if state is None:
+            state = self._state = self._tracer._thread_state()
+        now = perf_counter()
+        self.mark = (now, state.stats.copy(), _open_self(state, now))
+
+    def drop(self) -> None:
+        """Forget an open interval (what it waited for was lost)."""
+        self.mark = None
+
+    def close(self, label: str, rest: str, leave_out: tuple = ()) -> None:
+        """Close the open interval, if any, under ``label``, and book its
+        seconds by span: a span that closed since the mark gives the self
+        time it closed with less what it had at the mark, a span still
+        open what it has so far.  Time no span covered goes to ``rest``;
+        the spans named in ``leave_out`` are no part of the interval, and
+        their time comes off its length.  One pass, no table in between:
+        this runs on the engine's hot path."""
+        mark = self.mark
+        if mark is None:
+            return
+        self.mark = None
+        t0, stats0, open0 = mark
+        state = self._state
+        now = perf_counter()
+        by_span = self.by_span
+        covered = left_out = 0.0
+        for name, d in _open_self(state, now):
+            covered += d
+            if name in leave_out:
+                left_out += d
+            else:
+                by_span[name] = by_span.get(name, 0.0) + d
+        for name, rec in state.stats.items():
+            was = stats0.get(name)
+            if rec is not was:
+                d = rec[2] - (was[2] if was else 0.0)
+                covered += d
+                if name in leave_out:
+                    left_out += d
+                else:
+                    by_span[name] = by_span.get(name, 0.0) + d
+        for name, d in open0:
+            covered -= d
+            if name in leave_out:
+                left_out -= d
+            else:
+                by_span[name] = by_span.get(name, 0.0) - d
+        length = now - t0
+        by_span[rest] = by_span.get(rest, 0.0) + length - covered
+        seconds, count = self.by_label.get(label, (0.0, 0))
+        self.by_label[label] = (seconds + length - left_out, count + 1)
+
+    def as_dict(self) -> dict:
+        """JSON-ready totals (the ``/debug/spans`` shape)."""
+        return {
+            "by_label": {
+                label: {"seconds": round(s, 6), "intervals": n}
+                for label, (s, n) in sorted(self.by_label.copy().items())
+            },
+            "by_span_s": {
+                name: round(s, 6) for name, s in sorted(self.by_span.copy().items())
+            },
+        }
+
+
 class Tracer:
     def __init__(self, profiler: bool = False):
         self._annotation = None
@@ -105,16 +209,33 @@ class Tracer:
 
             self._annotation = jax.profiler.TraceAnnotation
         self._local = threading.local()
-        self._lock = threading.Lock()  # guards _threads and _retired
+        self._lock = threading.Lock()  # guards _threads, _retired, _accounts
         self._threads: list[_ThreadState] = []
         self._retired: dict[str, tuple] = {}
+        self._accounts: dict[str, IntervalAccount] = {}
+
+    def _thread_state(self) -> _ThreadState:
+        try:
+            return self._local.state
+        except AttributeError:
+            return self._register_thread()
 
     def span(self, name: str) -> _Span:
-        try:
+        try:  # the hot path: ``_thread_state`` inlined
             state = self._local.state
         except AttributeError:
             state = self._register_thread()
         return _Span(state, name, self._annotation)
+
+    def account(self, name: str) -> IntervalAccount:
+        """The interval account of this name, made on first asking: its
+        writer and its readers (``/metrics``, ``/debug/spans``) meet
+        here as a span's do."""
+        with self._lock:
+            account = self._accounts.get(name)
+            if account is None:
+                account = self._accounts[name] = IntervalAccount(self)
+        return account
 
     def _register_thread(self) -> _ThreadState:
         state = self._local.state = _ThreadState()

@@ -301,7 +301,10 @@ def test_metrics_exposition_unchanged_when_disabled():
     off = ServerMetrics("d", "p", "n")
     assert off.device_hbm_bytes is None
     text = off.exposition().decode()
-    assert "tpumlops_device" not in text
+    # (``tpumlops_device_starved_*`` is the always-on starvation account:
+    # it shares the prefix, not the switch.)
+    for family in ("tpumlops_device_hbm", "tpumlops_device_mfu"):
+        assert family not in text
     assert "tpumlops_compile_" not in text
 
     on = ServerMetrics("d", "p", "n", device_telemetry=True)

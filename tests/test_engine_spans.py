@@ -221,6 +221,11 @@ class _LoggingTracer(Tracer):
         return _LoggedSpan(super().span(name), name, self.log)
 
 
+def _intervals(eng) -> dict:
+    """Starved intervals closed so far, by what was dispatched at their end."""
+    return {k: n for k, (_s, n) in eng._starved.by_label.copy().items()}
+
+
 def _spy_ticks(eng) -> list:
     """Every journaled tick as (kind, start, wall), on perf_counter."""
     ticks, record = [], eng._record_tick
@@ -258,16 +263,22 @@ def test_the_next_chunk_is_dispatched_right_behind_the_steps_dispatch(
     ahead is waited for behind the next pass's step.  The journaled walls
     are taken in completion order, so they do not overlap, and each ends
     in a pass and starts no earlier than the pass before that."""
-    tracer, waits, sent = _LoggingTracer(), [], []
+    tracer, sent, starved = _LoggingTracer(), [], []
     watchers = _watchers(cpu_peaks)
+
+    def chunk_sent(when):  # on the engine thread, behind the dispatch's stamp
+        sent.append(when)
+        starved.append(_intervals(eng).get("chunk", 0))
+
     eng = _engine(
-        tiny, prefill_chunk=8, tracer=tracer, on_prefill_wait=waits.append,
-        on_prefill_dispatch=sent.append, **watchers,
+        tiny, prefill_chunk=8, tracer=tracer,
+        on_prefill_dispatch=chunk_sent, **watchers,
     )
     try:
         _serve(eng, [LONG[:20], PROMPTS[0]], new=8)  # compiles the programs
         ticks = _spy_ticks(eng)
-        del tracer.log[:], waits[:], sent[:]
+        del tracer.log[:], sent[:], starved[:]
+        chunk_intervals = _intervals(eng).get("chunk", 0)
         ticks_before = watchers["recorder"].ticks_recorded
         steps_before = eng.dispatches_total["decode"]
         chunks_before = eng.prefill_chunks_dispatched
@@ -301,12 +312,12 @@ def test_the_next_chunk_is_dispatched_right_behind_the_steps_dispatch(
     # the last one too; its first and the rider's own in the admit phase.
     assert ahead == 4 and sent.count("ahead") == 4
     assert sent.count("in_turn") == 2 and len(sent) == chunks
-    # Four chunks were waited for behind a step: LONG's first behind the
-    # step of its pass, the next three behind the next pass's ...
-    assert waits.count("step") == 4
-    # ... its last chunk and the insert, and the rider's own chunk and
-    # insert, are read at once: nothing was queued behind them.
-    assert waits.count("none") == 4
+    # The starvation account says the same from inside: a chunk sent ahead
+    # goes out behind a step the host has not seen to end, so its dispatch
+    # closes no interval; only a chunk sent in turn can have met an idle chip.
+    closed = [n - m for m, n in zip([chunk_intervals] + starved, starved)]
+    assert [c for c, when in zip(closed, sent) if when == "ahead"] == [0] * 4
+    assert all(c in (0, 1) for c in closed)
     walls = sorted((t0, t0 + wall) for _k, t0, wall in ticks)
     for (_s0, e0), (s1, _e1) in zip(walls, walls[1:]):
         assert e0 <= s1 + 1e-9, (walls, "walls overlap")
@@ -322,6 +333,182 @@ def test_the_next_chunk_is_dispatched_right_behind_the_steps_dispatch(
     assert set(kinds) == {"prefill", "decode"}
     assert watchers["recorder"].ticks_recorded - ticks_before == len(kinds)
     assert sum(1 for e in log if e[0] == "engine.decode_readback") == steps
+
+
+def _starved(eng) -> tuple[dict, dict]:
+    """The starvation account as it stands: ({before: (seconds,
+    intervals)}, {span: seconds})."""
+    return eng._starved.by_label.copy(), eng._starved.by_span.copy()
+
+
+def _assert_account_adds_up(eng, since=None):
+    """Seconds by ``before`` == seconds by span, no interval holds time
+    spent waiting for traffic, and all of it lies inside the loop's busy
+    time (root less ``engine.wait_work``)."""
+    by_before, by_span = _starved(eng)
+    st = eng.tracer.stats()
+    total = sum(s for s, _n in by_before.values())
+    assert total == pytest.approx(sum(by_span.values()), rel=1e-9, abs=1e-9)
+    assert "engine.wait_work" not in by_span
+    assert set(by_span) <= ({ROOT} | PHASES)
+    assert all(s > -1e-9 for s in by_span.values()), by_span
+    assert total <= st[ROOT].total_s - st["engine.wait_work"].total_s + 1e-6
+    return total
+
+
+def test_a_step_onto_an_idle_chip_closes_one_interval_before_decode(tiny):
+    """A lone stream, no chunks: every step but the first is dispatched
+    after the engine thread saw the step before it end, so each closes
+    exactly one interval under ``before="decode"``, as long as the spans
+    between that read-back and the dispatch's return, and no longer."""
+    tracer = _LoggingTracer()
+    eng = _engine(tiny, tracer=tracer)
+    try:
+        _serve(eng, [PROMPTS[0]], new=4)  # compiles the programs
+        n0, steps0 = _intervals(eng), eng.dispatches_total["decode"]
+        s0 = _starved(eng)[0].get("decode", (0.0, 0))[0]
+        del tracer.log[:]
+        _serve(eng, [PROMPTS[0]], new=10)
+        steps = eng.dispatches_total["decode"] - steps0
+        n1 = _intervals(eng)
+        seconds = _starved(eng)[0]["decode"][0] - s0
+        log = list(tracer.log)
+        _assert_account_adds_up(eng)
+        by_span = _starved(eng)[1]
+    finally:
+        eng.shutdown()
+    # The fused admission's prefill met a chip the last request had left
+    # idle (one interval, the wait for traffic taken out of it); its first
+    # token is read before the first step goes out, so that step too.
+    assert steps == 9
+    assert n1["decode"] - n0["decode"] == steps
+    assert n1["prefill"] - n0.get("prefill", 0) == 1
+    assert set(n1) == {"prefill", "decode"}
+    readbacks = [e for e in log if e[0] == "engine.decode_readback"]
+    dispatches = [e for e in log if e[0] == "engine.decode_dispatch"]
+    admits = [e for e in log if e[0] == "engine.prefill_dispatch"]
+    assert len(readbacks) == len(dispatches) == steps and len(admits) == 1
+    # Step k+1's interval runs from inside step k's read-back span to inside
+    # its own dispatch span (the first step's from the admission's).
+    ends_before = [admits[0]] + readbacks[:-1]
+    at_least = sum(d[1] - r[2] for r, d in zip(ends_before, dispatches))
+    at_most = sum(d[2] - r[1] for r, d in zip(ends_before, dispatches))
+    assert at_least <= seconds <= at_most
+    # What the host was doing: the phases between a read-back and a dispatch.
+    assert {"engine.decode_dispatch", "engine.decode_assemble", "engine.emit",
+            "engine.journal", "engine.admit"} <= set(by_span)
+
+
+def test_time_waiting_for_traffic_is_in_no_interval(tiny):
+    """Between two requests the loop sits in ``engine.wait_work``; the
+    interval the second one's first dispatch closes is the host's work on
+    either side of that wait, not the wait."""
+    eng = _engine(tiny, prefill_chunk=8)
+    try:
+        _serve(eng, [PROMPTS[0]], new=4)
+        before = _assert_account_adds_up(eng)
+        waited0 = eng.tracer.stats()["engine.wait_work"].total_s
+        time.sleep(0.4)
+        n0 = _intervals(eng).get("chunk", 0)
+        _serve(eng, [PROMPTS[0]], new=1)
+        waited = eng.tracer.stats()["engine.wait_work"].total_s - waited0
+        after = _assert_account_adds_up(eng)
+        n1 = _intervals(eng)["chunk"]
+    finally:
+        eng.shutdown()
+    assert n1 - n0 == 1  # the first chunk met an idle chip
+    assert waited > 0.3
+    assert after - before < waited / 2  # the wait is not in it
+
+
+STARVED = {  # mode -> (engine kwargs, labels its dispatches may close under,
+    # labels they must)
+    "plain": ({}, {"prefill", "decode"}, {"prefill", "decode"}),
+    "chunked": (MODES["chunked"][0], {"chunk", "insert", "decode"},
+                {"chunk", "decode"}),
+    "packed": (MODES["packed"][0], {"packed-prefill", "decode"},
+               {"packed-prefill", "decode"}),
+    "unified": (MODES["unified"][0], {"superstep"}, {"superstep"}),
+    "fused": (MODES["fused"][0], {"prefill", "decode", "multistep"},
+              {"prefill", "multistep"}),
+    "verify": ("speculative", {"prefill", "decode", "verify"},
+               {"prefill", "verify"}),
+}
+
+
+@pytest.mark.parametrize("mode", list(STARVED))
+def test_every_tick_path_opens_and_closes_the_account(tiny, mode):
+    """Whatever program a path hands the device, its dispatch ends the
+    open interval under its own kind, and its completion, once nothing
+    else is out, begins the next: after any traffic nothing is unseen, an
+    interval is open, and the two tables add up to the same seconds."""
+    kw, may, must = STARVED[mode]
+    if kw == "speculative":
+        from tpumlops.server.speculative import SpeculativeConfig
+
+        kw = dict(speculative=SpeculativeConfig(
+            enabled=True, draft_tokens=2, ngram_min=1, ngram_max=4))
+    eng = _engine(tiny, **kw)
+    if mode == "verify":
+        eng._propose = lambda slot, budget: [5, 7][:budget]
+    try:
+        _serve(eng)
+        assert _starved(eng)[0], "nothing counted"
+        for _ in range(2):
+            _serve(eng)
+            assert eng._unseen == 0 and not eng._open_ticks
+            assert eng._starved.mark is not None
+            _assert_account_adds_up(eng)
+        labels = set(_intervals(eng))
+        ticks = {k for k, n in eng.dispatches_total.items() if n}
+    finally:
+        eng.shutdown()
+    assert must <= labels <= may, labels
+    # The labels are the tick kinds, ``prefill`` told apart where known.
+    told_apart = {"chunk": "prefill", "insert": "prefill"}
+    assert {told_apart.get(k, k) for k in labels} <= ticks
+
+
+def test_the_warm_up_sweep_counts_nothing(tiny):
+    """A warm-up sweep dispatches and reads back every program; none of
+    it is a tick, and none of it is starvation."""
+    params, cfg = tiny
+    eng = GenerationEngine(
+        params, cfg, max_slots=2, dtype=jnp.float32, prefill_chunk=16,
+    )
+    try:
+        eng.start(warmup=True)
+        assert eng._starved.by_label == {} and eng._starved.by_span == {}
+        assert eng._unseen == 0 and eng._starved.mark is None
+        _serve(eng, [PROMPTS[0]], new=3)
+        assert set(_intervals(eng)) == {"decode"}
+    finally:
+        eng.shutdown()
+
+
+def test_a_lost_step_leaves_nothing_unseen(tiny):
+    """A step that fails is never seen to end: the account forgets what
+    was out with the device state, and counts on from the next dispatch."""
+    eng = _engine(tiny, prefill_chunk=8)
+    try:
+        _serve(eng, [PROMPTS[0]], new=3)
+        step_program, calls = eng._decode_greedy, []
+
+        def failing_once(*args):
+            calls.append(1)
+            if len(calls) != 3:
+                return step_program(*args)
+            raise RuntimeError("injected step error")
+
+        eng._decode_greedy = failing_once
+        with pytest.raises(RuntimeError, match="generation step failed"):
+            _serve(eng, [PROMPTS[0]], new=12)
+        assert eng._unseen == 0 and eng._starved.mark is None
+        _serve(eng, [PROMPTS[0]], new=3)
+        assert eng._unseen == 0
+        _assert_account_adds_up(eng)
+    finally:
+        eng.shutdown()
 
 
 def _family(name):
@@ -708,6 +895,97 @@ def test_profiler_capture_holds_engine_spans_on_the_engine_line(tiny, tmp_path):
         if e.name == "engine.decode_readback":
             assert any(s <= e.start_ns and e.start_ns + e.duration_ns <= t
                        for s, t in passes)
+
+
+@pytest.fixture(scope="module")
+def capture_report():
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parent.parent / "scripts" / "capture_report.py"
+    spec = importlib.util.spec_from_file_location("capture_report", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+US = 1e3  # nanoseconds
+
+
+def test_gap_table_names_a_gap_by_the_program_that_follows(capture_report):
+    """`scripts/capture_report.py::gap_table` on plain lists, by hand: a
+    chunk, a step queued 20 us behind it (no gap), 400 us idle in front of
+    the next step, an insert that overlaps a step's tail, 2000 us in front
+    of the scratch's zero-fill and 100 us in front of the chunk behind it."""
+    modules = [
+        ("jit__prefill_one_chunk(1)", 0 * US, 1000 * US),
+        ("jit__decode_greedy(2)", 1020 * US, 1500 * US),   # 20 us: under the floor
+        ("jit__decode_greedy(2)", 1900 * US, 2400 * US),   # 400 us idle before it
+        ("jit__insert_only(3)", 2300 * US, 2500 * US),     # overlaps: no gap
+        ("jit__cache_buffers(4)", 4500 * US, 4600 * US),   # 2000 us idle
+        ("jit__prefill_one_chunk(1)", 4700 * US, 5700 * US),  # 100 us idle
+    ]
+    spans = [
+        ("engine.iteration", 0 * US, 2450 * US),
+        ("engine.decode_readback", 1000 * US, 1600 * US),
+        ("engine.emit", 1600 * US, 1700 * US),
+        ("engine.decode_dispatch", 1850 * US, 1950 * US),
+        ("engine.iteration", 2500 * US, 5800 * US),
+        ("engine.admit", 2600 * US, 4800 * US),
+        ("engine.prefill_dispatch", 4000 * US, 4750 * US),
+    ]
+    t = capture_report.gap_table(modules, spans)
+    assert t["window_s"] == pytest.approx(5700e-6)
+    assert t["gaps"] == 3 and t["gap_s"] == pytest.approx(2500e-6)
+    assert {k: (pytest.approx(v[0]), v[1]) for k, v in t["by_program"].items()} == {
+        "jit__cache_buffers": (pytest.approx(2000e-6), 1),
+        "jit__decode_greedy": (pytest.approx(400e-6), 1),
+        "jit__prefill_one_chunk": (pytest.approx(100e-6), 1),
+    }
+    assert list(t["by_program"]) == [
+        "jit__cache_buffers", "jit__decode_greedy", "jit__prefill_one_chunk"]
+    # 1500-1900: readback 100, emit 100, the root's own 150, dispatch 50;
+    # 2500-4500: the root's own 100, admit 1400, prefill_dispatch 500;
+    # 4600-4700: prefill_dispatch.
+    assert t["by_span"] == {
+        "engine.admit": pytest.approx(1400e-6),
+        "engine.prefill_dispatch": pytest.approx(600e-6),
+        "engine.iteration": pytest.approx(250e-6),
+        "engine.decode_readback": pytest.approx(100e-6),
+        "engine.emit": pytest.approx(100e-6),
+        "engine.decode_dispatch": pytest.approx(50e-6),
+    }
+    assert sum(t["by_span"].values()) == pytest.approx(t["gap_s"])
+    # No host line in the capture: every gap is under no span.
+    bare = capture_report.gap_table(modules, [])
+    assert bare["by_span"] == {"(no span)": pytest.approx(2500e-6)}
+    assert bare["by_program"] == t["by_program"]
+    assert capture_report.gap_table([], spans)["gaps"] == 0
+
+
+def test_account_delta_reads_the_two_payloads_of_a_profile_answer(capture_report):
+    def at(iteration, wait, decode, chunk, emit):
+        return {
+            "spans": {"engine.iteration": {"total_s": iteration},
+                      "engine.wait_work": {"total_s": wait}},
+            "device_starved": {
+                "by_label": {"decode": {"seconds": decode[0], "intervals": decode[1]},
+                             "chunk": {"seconds": chunk[0], "intervals": chunk[1]}},
+                "by_span_s": {"engine.emit": emit, "engine.admit": 1.0},
+            },
+        }
+
+    d = capture_report.account_delta({
+        "start": at(10.0, 4.0, (0.5, 100), (0.2, 7), 0.3),
+        "stop": at(12.5, 4.5, (0.75, 200), (0.2, 7), 0.55),
+    })
+    assert d["busy_s"] == pytest.approx(2.0)
+    assert d["starved_s"] == pytest.approx(0.25)
+    assert d["by_before"] == {"decode": [pytest.approx(0.25), 100]}  # no new chunk gap
+    assert d["by_span"] == {"engine.emit": pytest.approx(0.25)}
+    # A server from before the account answers without it.
+    assert capture_report.account_delta({"start": {"spans": {}}, "stop": {"spans": {}}}) is None
+    assert capture_report.account_delta({}) is None
 
 
 def test_operator_imports_no_jax():

@@ -40,7 +40,6 @@ EXPECTED_SERVER = {
     "tpumlops_batch_size": ("histogram", _IDENT),
     "tpumlops_compilations": ("counter", _IDENT),
     "tpumlops_decode_batch_size": ("histogram", _IDENT),
-    "tpumlops_decode_step_seconds": ("histogram", _IDENT),
     "tpumlops_engine_active_slots": ("gauge", _IDENT),
     "tpumlops_engine_admitting": ("gauge", _IDENT),
     "tpumlops_engine_queue_depth": ("gauge", _IDENT),
@@ -83,9 +82,6 @@ EXPECTED_SERVER = {
     # Real prompt tokens prefilled (cached-prefix tokens excluded);
     # exported as tpumlops_prefill_tokens_total.
     "tpumlops_prefill_tokens": ("counter", _IDENT),
-    # Non-decode ticks by whether a decode dispatch was queued behind the
-    # program when the engine thread waited for it ("step" | "none").
-    "tpumlops_prefill_waits": ("counter", _IDENT + ("queued_behind",)),
     # Chunk programs of the single-admission path by where the engine
     # dispatched them ("ahead": right behind the pass's decode step |
     # "in_turn": in the admit phase).
@@ -120,6 +116,13 @@ EXPECTED_SERVER = {
     "tpumlops_span_seconds": ("counter", _IDENT + ("span",)),
     "tpumlops_span_self_seconds": ("counter", _IDENT + ("span",)),
     "tpumlops_spans": ("counter", _IDENT + ("span",)),
+    # The engine's starvation account (tracer.account("device_starved")),
+    # rendered by the same collector: seconds and intervals the chip had
+    # nothing to run, by the tick program dispatched at their end, and the
+    # same seconds by the engine.* span whose self time covered them.
+    "tpumlops_device_starved_seconds": ("counter", _IDENT + ("before",)),
+    "tpumlops_device_starved_intervals": ("counter", _IDENT + ("before",)),
+    "tpumlops_device_starved_by_span_seconds": ("counter", _IDENT + ("span",)),
     "tpumlops_prefix_cache_cached_tokens": ("counter", _IDENT),
     "tpumlops_prefix_cache_evictions": ("counter", _IDENT),
     "tpumlops_prefix_cache_hits": ("counter", _IDENT),
@@ -235,7 +238,10 @@ def test_device_telemetry_families_absent_from_disabled_exposition():
         deployment_name="d", predictor_name="p", namespace="n"
     )
     text = metrics.exposition().decode()
-    assert "tpumlops_device_" not in text
+    # (``tpumlops_device_starved_*`` is the always-on starvation account,
+    # not device telemetry: it shares a prefix, not a switch.)
+    for family in set(EXPECTED_SERVER_DEVICE) - set(EXPECTED_SERVER):
+        assert family not in text
     assert "tpumlops_compile_" not in text
 
 

@@ -561,7 +561,8 @@ def test_generate_endpoint_validation_and_metrics(llm_server):
     assert "capacity" in resp.json()["error"]
     text = httpx.get(llm_server.base + "/metrics", timeout=10).text
     assert "tpumlops_generated_tokens_total" in text
-    assert "tpumlops_decode_step_seconds" in text
+    assert "# TYPE tpumlops_tick_seconds histogram" in text
+    assert "# TYPE tpumlops_device_starved_seconds_total counter" in text
 
 
 def test_generate_route_absent_for_non_llm(iris_server):
@@ -699,30 +700,49 @@ def test_streaming_observes_one_emit_lag_per_token(llm_server):
     assert p1 - p0 == 4
 
 
-def test_prefill_waits_count_one_a_non_decode_tick(llm_server):
-    """``tpumlops_prefill_waits_total{queued_behind}``: every prefill-side
-    dispatch is waited for once, behind a queued step or with nothing
-    behind it."""
-    def scrape():
-        text = httpx.get(llm_server.base + "/metrics", timeout=10).text
-        waits = [ln for ln in text.splitlines()
-                 if ln.startswith("tpumlops_prefill_waits_total{")]
-        assert all('queued_behind="step"' in ln or 'queued_behind="none"' in ln
-                   for ln in waits)
-        prefills = sum(
-            float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
-            if ln.startswith("tpumlops_engine_dispatches_total{")
-            and 'op="decode"' not in ln)
-        return sum(float(ln.rsplit(" ", 1)[1]) for ln in waits), prefills
+def test_starvation_account_on_metrics_and_debug_spans(llm_server):
+    """``tpumlops_device_starved_*``: when the chip had nothing to run, by
+    what it was then given (``before``) and by what the engine thread was
+    doing (``span``); the two ``seconds`` families hold the same total, and
+    ``GET /debug/spans`` shows the same account beside the span table."""
+    def family(text, name, label):
+        out = {}
+        for ln in text.splitlines():
+            if ln.startswith(name + "{"):
+                key = ln.split(label + '="', 1)[1].split('"', 1)[0]
+                out[key] = float(ln.rsplit(" ", 1)[1])
+        return out
 
-    w0, p0 = scrape()
-    resp = httpx.post(
-        llm_server.base + "/v2/models/llm/generate",
-        json={"prompt_ids": [5, 9, 2, 7, 1], "max_new_tokens": 3}, timeout=60,
-    )
-    assert resp.status_code == 200
-    w1, p1 = scrape()
-    assert w1 - w0 == p1 - p0 >= 1
+    for _ in range(2):  # the second request's first dispatch meets an idle chip
+        resp = httpx.post(
+            llm_server.base + "/v2/models/llm/generate",
+            json={"prompt_ids": [5, 9, 2, 7, 1], "max_new_tokens": 4}, timeout=60,
+        )
+        assert resp.status_code == 200
+    text = httpx.get(llm_server.base + "/metrics", timeout=10).text
+    seconds = family(text, "tpumlops_device_starved_seconds_total", "before")
+    intervals = family(text, "tpumlops_device_starved_intervals_total", "before")
+    by_span = family(text, "tpumlops_device_starved_by_span_seconds_total", "span")
+    ticks = family(text, "tpumlops_tick_seconds_count", "kind")
+    assert set(seconds) == set(intervals) and "decode" in seconds
+    # ``before`` is a tick kind, ``prefill`` told apart where the site knows.
+    assert {{"chunk": "prefill", "insert": "prefill"}.get(k, k)
+            for k in seconds} <= set(ticks)
+    assert all(n >= 1 and n == int(n) for n in intervals.values())
+    assert intervals["decode"] <= ticks["decode"]
+    assert "engine.wait_work" not in by_span
+    assert all(k.startswith("engine.") for k in by_span)
+    assert sum(seconds.values()) == pytest.approx(sum(by_span.values()), rel=1e-6)
+    assert 'deployment_name="' in text.split(
+        "tpumlops_device_starved_seconds_total{", 1)[1].split("}", 1)[0]
+    body = httpx.get(llm_server.base + "/debug/spans", timeout=10).json()
+    assert "engine.iteration" in body["spans"]
+    account = body["device_starved"]
+    assert set(account) == {"by_label", "by_span_s"}
+    assert set(account["by_label"]) >= set(seconds)
+    assert all(set(v) == {"seconds", "intervals"}
+               for v in account["by_label"].values())
+    assert set(account["by_span_s"]) >= set(by_span)
 
 
 def test_prefill_dispatch_counts_one_a_chunk_program(chunked_llm_server):
@@ -968,6 +988,11 @@ def test_debug_profile_endpoint(iris_server, tmp_path, monkeypatch):
     # paths are server-chosen (unauthenticated endpoint: no client dirs)
     assert out["trace_dir"].startswith(str(tmp_path / "tpumlops-profile") + os.sep)
     assert out["stop_trace_s"] >= 0.0
+    # The span table and the starvation account as the capture began and
+    # ended: what scripts/capture_report.py sets beside the device's gaps.
+    assert set(out["spans_at"]) == {"start", "stop"}
+    assert all(set(at) == {"spans", "device_starved"}
+               for at in out["spans_at"].values())
     found = []
     for _root, _dirs, files in os.walk(out["trace_dir"]):
         found += files
