@@ -1953,6 +1953,7 @@ def make_gen_engine(
         on_dispatch=metrics.inc_dispatch if metrics else None,
         on_prefill_tokens=metrics.inc_prefill_tokens if metrics else None,
         on_prefill_dispatch=metrics.inc_prefill_dispatch if metrics else None,
+        on_decode_dispatch=metrics.inc_decode_dispatch if metrics else None,
         on_key_blocks=metrics.inc_prefill_key_blocks if metrics else None,
         family=family,
         on_moe=metrics.inc_moe if metrics else None,

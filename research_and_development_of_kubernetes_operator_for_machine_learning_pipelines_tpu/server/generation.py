@@ -429,8 +429,9 @@ class GenerationEngine:
         tracer=None,  # utils.tracing.Tracer | None (the server shares its own)
         family=None,  # the causal-LM family's module (None: models.llama)
         on_moe: Callable[[str, dict, int, int], None] | None = None,
-        # "ahead"|"in_turn": one a chunk program of the single-admission path
+        # "ahead"|"in_turn": one a chunk program of the single-admission path,
         on_prefill_dispatch: Callable[[str], None] | None = None,
+        on_decode_dispatch: Callable[[str], None] | None = None,  # one a step
         on_key_blocks: Callable[[int, int], None] | None = None,  # walked, skipped
     ):
         import jax
@@ -647,21 +648,20 @@ class GenerationEngine:
         # — the default — wraps nothing and computes nothing per tick.
         self._telemetry = telemetry
         # JAX dispatch is async: a prefill-side call returns before the
-        # device finishes.  The engine thread does not wait for it there:
-        # it registers an open tick (``_open_tick``) and takes the wait at
-        # the pass's next blocking read (``_close_ticks``), behind the
-        # decode step it has dispatched meanwhile, so the chip never
-        # idles while the host assembles that step.  An admission's next
-        # chunk goes out right behind that step (``_send_chunk_ahead``)
-        # and is waited for behind the NEXT step, so the chip does not
-        # idle through the read-back, the emission and the next admit
-        # phase either.  The tick's wall runs to the stamp of its wait,
-        # in completion order, and does not absorb the step's device
-        # time nor lend it its own.  No option arms or disarms this:
-        # watching the engine (recorder, telemetry) does not change the
-        # order of its dispatches.
+        # device finishes.  The engine thread waits for it at the pass's
+        # next blocking read (``_close_ticks``), behind the decode step it
+        # dispatched meanwhile.  Each pass leaves one program queued behind
+        # its read-back: the admission's next chunk (``_send_chunk_ahead``)
+        # or else the next decode step (``_ahead``, dispatched from the
+        # device-resident outputs of the step before it and read back
+        # behind the step after it), so the chip does not idle through the
+        # read-back, the emission and the next admit phase.  A tick's wall
+        # runs to the stamp of its wait, in completion order, and does not
+        # absorb another's device time.  No option arms or disarms this:
+        # watching the engine (recorder, telemetry) does not change it.
         self._unseen = 0  # tick programs dispatched and not yet seen to end
         self._on_prefill_dispatch = on_prefill_dispatch
+        self._on_decode_dispatch = on_decode_dispatch
         self._on_prefix_l2 = on_prefix_l2
         if prefix_enabled:
             from .prefix_cache import RadixPrefixCache
@@ -1550,10 +1550,10 @@ class GenerationEngine:
         self._ms_remaining = None
         self._ms_eos = None
         self._moe_pending = []  # device scalars of the programs just lost
-        # Prefill-side programs dispatched and not waited for yet (their
-        # arrays are lost with the rest), and the stamp of the last
-        # completion the engine thread saw.
+        # Prefill-side programs dispatched and not waited for yet, the step
+        # in flight (lost with the rest), the last completion seen.
         self._open_ticks: list[_OpenTick] = []
+        self._ahead: _StepOut | None = None
         self._done_at = 0.0
 
     def _put_seq(self, buf):
@@ -2711,17 +2711,17 @@ class GenerationEngine:
     def _close_ticks(
         self, behind: str | None = None, first: int | None = None
     ) -> None:
-        """A blocking point: wait for the open ticks in device order and
-        journal each with the wall the host saw for it alone.
-
+        """A blocking point: read back the step in flight, the oldest program
+        out, then wait for the open ticks in device order and journal each
+        with the wall the host saw for it alone.
         ``behind`` is the heartbeat kind of a decode-side dispatch already
         queued behind them, None where nothing is.  ``first`` closes only
         that many, the oldest: a step leaves the chunk it sent ahead open.
         Every wait is ``engine.prefill_sync`` (the benchmark's
         ``loop_host_ms`` takes that span as time blocked on the device,
-        wherever the loop takes it).  A device error raises
-        :class:`_TickFailed`: it is the admission's, not the step's, and
-        the ticks behind it go with it."""
+        wherever the loop takes it).  A tick's device error raises
+        :class:`_TickFailed`, the step in flight's :class:`_StepFailed`."""
+        self._read_ahead()
         if not self._open_ticks:
             return
         import jax
@@ -2873,18 +2873,18 @@ class GenerationEngine:
         if aux and self._on_moe is not None and not self._in_warmup:
             self._moe_pending.append((program, tokens, rows, aux[0]))
 
-    def _read_experts(self) -> None:
-        """Hand the pending counts to ``on_moe(program, counts, routed,
-        row_tile)``: the family's ``COUNTS`` by name, every (token,
-        expert) pair of the call's real tokens, the grouped matmuls' row
-        tile.  Called where the loop has just read a program's result
-        back: a step's counts are that program's own, and a chunk's come
-        here only when its tick is closed (a chunk sent ahead is queued
-        BEHIND the step whose tokens were just read), so every count here
-        is already computed: no synchronisation of its own."""
-        if not self._moe_pending:
-            return
-        pending, self._moe_pending = self._moe_pending, []
+    def _read_experts(self, pending: list | None = None) -> None:
+        """Hand counts to ``on_moe(program, counts, routed, row_tile)``:
+        the family's ``COUNTS`` by name, every (token, expert) pair of the
+        call's real tokens, the grouped matmuls' row tile.  ``pending`` is
+        a step's own, at its read-back (a program dispatched behind the
+        step may have noted its own since); None takes ``_moe_pending``
+        where the loop has just read a program's result back: a chunk's
+        come here only when its tick is closed (a chunk sent ahead is
+        queued BEHIND the step whose tokens were just read), so every
+        count here is already computed: no synchronisation of its own."""
+        if pending is None:
+            pending, self._moe_pending = self._moe_pending, []
         for program, tokens, rows, counts in pending:
             self._on_moe(
                 program,
@@ -4051,42 +4051,39 @@ class GenerationEngine:
             self._recorder.complete(slot.trace)
 
     def _step(self) -> None:
-        """One batched decode tick over every occupied slot.
+        """One batched decode tick.
 
-        With speculation enabled and every occupied slot greedy, the tick tries a
-        draft+verify (multi-token) pass first; a tick with no drafts anywhere — or any
-        sampling slot — runs the original single-token step unchanged.
+        The plain step goes out behind the step still in flight, over the
+        rows :meth:`_step_rows` picks, from the device-resident tokens,
+        lengths and cache that step left; the one in flight is then read
+        back behind it (:meth:`_close_ticks`), and the new one stays out
+        across the end of the pass unless a chunk went out behind it.
 
-        The unified engine routes EVERY tick through the super-step assembler instead:
-        one dispatch carries the tick's decode, verify, and packed-prefill work
-        together."""
+        A draft+verify pass (speculation on, every slot greedy), a fused
+        multi-step burst (a tick that owes nothing else) and the unified
+        super-step start from exact slot truth: the step in flight is read
+        back first."""
+        if self._unified or self._spec is not None or self._fused:
+            self._read_ahead()
         if self._unified:
             self._super_tick()
             return
         span = self._span
         with span("engine.decode_assemble"):
-            active_np = np.array([s is not None for s in self._slots])
+            # The step's rows, attention window and token rule.
+            active_np, window, sampling = self._step_rows()
             drafts = None
-            if active_np.any():
-                # Attention window: smallest bucket covering every active
-                # row's next write position (prompt + tokens emitted so
-                # far).
-                needed = max(
-                    s.prompt_len + len(s.generated)
-                    for s in self._slots
-                    if s is not None
-                )
-                window = decode_window_bucket(needed, self.capacity)
-                sampling = any(
-                    s is not None and s.sampling for s in self._slots
-                )
-                if (
-                    self._spec is not None
-                    and not sampling
-                    and not self._in_warmup
-                ):
-                    drafts = self._collect_drafts()
+            if (
+                active_np.any()
+                and self._spec is not None
+                and not sampling
+                and not self._in_warmup
+            ):
+                drafts = self._collect_drafts()
         if not active_np.any():
+            if self._ahead is not None:
+                self._read_ahead()  # the last step out: no row needs more
+                return
             # Still report occupancy: without this the gauges freeze at
             # their last busy values and an idle server reads as loaded.
             # (observe_decode_step skips its histograms at 0 active.)
@@ -4115,32 +4112,35 @@ class GenerationEngine:
             # mid-prefill whose chunk cadence a fused tick would stall.
             self._step_fused(active_np, sampling)
             return
+        # The step's on-device counts are the ones noted from here on:
+        # they travel with it to its own read-back.
+        noted = len(self._moe_pending)
         t0 = time.perf_counter()
         self._beat("decode")
         with span("engine.decode_dispatch"):
             self._dispatch_step(active_np, window, sampling)
             self._dispatched("decode")
-        # The step is queued behind the pass's chunk, and the admission's
-        # next chunk goes behind the step: now wait for what went before
-        # the step, then for the step, in the order the device runs them.
+            step = self._step_out(t0, active_np, window, noted)
+        # The admission's next chunk goes behind the step.  Then wait in the
+        # device's order: the step in flight, what went before this one, and
+        # this one only where a chunk is behind it (one program stays out).
         before = len(self._open_ticks)
         self._send_chunk_ahead()
         self._close_ticks(behind="decode", first=before)
-        with span("engine.decode_readback"):
-            toks = np.asarray(self._tokens)[:, 0]
-            done = self._tick_done(t0)
-            self._read_experts()
-        with span("engine.journal"):
-            self._note_tick(
-                active_np, *done, tokens=int(active_np.sum()),
-                cost=self._cost_decode(window),
-            )
-        with span("engine.emit"):
-            for i, was_active in enumerate(active_np):
-                if was_active and self._slots[i] is not None:
-                    self._record_token(i, int(toks[i]))
-                    if not self._in_warmup:
-                        self.decode_tokens += 1
+        if self._open_ticks or self._in_warmup:
+            self._read_step(step)  # the warm-up sweep sends none ahead
+        else:
+            self._ahead = step
+
+    def _read_ahead(self) -> None:
+        """Read back the step in flight, if one is (a failure is its own)."""
+        step, self._ahead = self._ahead, None
+        try:
+            if step is not None:
+                self._read_step(step)
+        except Exception as exc:  # and what went behind it: lost, unblamed
+            self._open_ticks = []
+            raise _StepFailed("the decode step in flight failed") from exc
 
     def _note_tick(
         self, active_np, t0: float, wall: float, kind: str = "decode",
@@ -4965,6 +4965,77 @@ class GenerationEngine:
             )
         self._note_experts("decode", int(np.sum(active_np)), len(active_np), aux)
 
+    # -- the step in flight ----------------------------------------------------
+    # A plain step goes out from the outputs of the one before it, which stay
+    # on the device (tokens and lengths are not donated, K/V are), so the host
+    # can dispatch step n+1 before it reads step n back: it reads step n from
+    # the token array it held before that dispatch.  Host slot truth lags one
+    # step meanwhile; each slot's known budget says which rows step n+1 needs.
+
+    def _step_rows(self) -> tuple:
+        """The rows of the step about to go out, its attention window and
+        its token rule.  A row is in while its known budget exceeds the
+        steps still un-read for it (one where the step in flight was
+        computed for this very slot): a row that step finishes by length
+        gets no step past it, one it finishes on EOS gets one whose token
+        nobody reads.  The window is the bucket of the furthest next write
+        position of the rows in, the step in flight counted."""
+        out = self._ahead.slots if self._ahead else (None,) * self.max_slots
+        active = np.zeros((self.max_slots,), bool)
+        needed, sampling = 0, False
+        for i, slot in enumerate(self._slots):
+            if slot is None:
+                continue
+            unread = int(out[i] is slot)
+            if slot.remaining <= unread:
+                continue
+            active[i] = True
+            needed = max(needed, slot.prompt_len + len(slot.generated) + unread)
+            sampling = sampling or slot.sampling
+        return active, decode_window_bucket(needed, self.capacity), sampling
+
+    def _step_out(self, t0: float, active_np, window: int, noted: int):
+        """The step just dispatched, as its read-back will need it: its own
+        token array, the slot each row was computed for and the counts it
+        noted (moved off ``_moe_pending``: converting them before the step
+        is read back would wait for it)."""
+        if not self._in_warmup and self._on_decode_dispatch is not None:
+            self._on_decode_dispatch(
+                "in_turn" if self._ahead is None else "ahead"
+            )
+        counts = self._moe_pending[noted:]
+        del self._moe_pending[noted:]
+        slots = tuple(
+            s if on else None for s, on in zip(self._slots, active_np)
+        )
+        return _StepOut(t0, self._tokens, slots, window, counts)
+
+    def _read_step(self, step: _StepOut) -> None:
+        """Read a step back, journal the rows it emits, emit them.  A row's
+        token goes to the slot it was computed for and to no other: a slot
+        that finished meanwhile may hold a new admission already, whose
+        insert reset the row behind this step on the device."""
+        span = self._span
+        self._beat("decode")
+        with span("engine.decode_readback"):
+            toks = np.asarray(step.tokens)[:, 0]
+            done = self._tick_done(step.t0)
+            self._read_experts(step.counts)
+        with span("engine.journal"):
+            live = np.array([
+                s is not None and s is self._slots[i]
+                for i, s in enumerate(step.slots)
+            ])
+            self._note_tick(
+                live, *done, tokens=int(live.sum()),
+                cost=self._cost_decode(step.window),
+            )
+        with span("engine.emit"):
+            for i in np.flatnonzero(live):
+                self._record_token(int(i), int(toks[i]))
+                if not self._in_warmup:
+                    self.decode_tokens += 1
+
     # -- the starvation account ------------------------------------------------
     # When the chip had nothing to run, what it was waiting to be given and
     # what the host was doing meanwhile: ``tracer.account("device_starved")``.
@@ -5025,14 +5096,18 @@ class GenerationEngine:
         prog.ahead = True
 
     def _settle_ticks(self) -> None:
-        """Close the open ticks where the admit phase is about to read
-        device state (a control op, an eviction), outside ``_loop``'s
-        handler: a wait that fails there is its admission's all the
-        same."""
+        """Read back the step in flight and close the open ticks where the
+        admit phase is about to read device state (a control op, an
+        eviction, a packed chunk) or to wait for traffic, outside
+        ``_loop``'s handler: a wait that fails there is its admission's,
+        or the step's, all the same."""
         try:
             self._close_ticks()
         except _TickFailed as failed:
             self._admission_failed(failed.owners, failed)
+        except Exception:
+            _log.exception("decode step failed")
+            self._fail_all_and_recover()
 
     def _loop(self) -> None:
         span = self._span
@@ -5052,11 +5127,13 @@ class GenerationEngine:
                 try:
                     self._step()
                     # A pass that read nothing back (no slot active)
-                    # waits for its chunk here.  Only a chunk sent ahead
-                    # stays open across the end of its pass, and closes
-                    # behind the next step's dispatch: at most a step
-                    # and two chunks are ever queued un-waited.
-                    if not (self._pending and self._pending[0].ahead):
+                    # waits for its chunk here.  One program stays out
+                    # across the end of a pass, and is waited for behind
+                    # the next step's dispatch: a chunk sent ahead, else
+                    # the step in flight.
+                    if self._ahead is None and not (
+                        self._pending and self._pending[0].ahead
+                    ):
                         self._close_ticks()
                 except _TickFailed as failed:
                     self._admission_failed(failed.owners, failed)
@@ -5064,15 +5141,16 @@ class GenerationEngine:
                     _log.exception("decode step failed")
                     self._fail_all_and_recover()
         try:
-            self._close_ticks()  # shutdown with a chunk in flight
-        except _TickFailed:
-            _log.exception("a prefill-side program failed at shutdown")
+            self._close_ticks()  # shutdown with a step or a chunk in flight
+        except Exception:
+            _log.exception("a program in flight failed at shutdown")
 
     def _dequeue_or_wait(self, block: bool):
         """:meth:`_dequeue`; blocking (no slot active, nothing pending)
         is waiting for traffic, not host cost: ``engine.wait_work``."""
         if not block:
             return self._dequeue(False, self._idle_poll_s)
+        self._settle_ticks()  # nothing stays out across a wait for traffic
         with self._span("engine.wait_work"):
             return self._dequeue(True, self._idle_poll_s)
 
@@ -5224,6 +5302,9 @@ class GenerationEngine:
             # prefill rows); a failure there runs _loop's recovery,
             # which fails pending packed admissions too.
             return True
+        # A packed call reads its first tokens back at once: the step in
+        # flight is read first, so each wall stays its own program's.
+        self._settle_ticks()
         try:
             self._packed_tick()
         except Exception as exc:
@@ -5235,7 +5316,10 @@ class GenerationEngine:
         dispatch or at the wait for it, wherever the loop took that wait
         (a :class:`_TickFailed` names the tick's own requests): count the
         crash against their prompts, fail their futures with the device
-        error, drop their progress and recover the device state."""
+        error, drop their progress and recover the device state.  Where the
+        step in flight failed at the admission's blocking point
+        (:class:`_StepFailed`), the requests are lost with the slots and
+        their prompts are not blamed: decode crashes are not attributed."""
         self._drop_admission(reqs, exc)
         self._open_ticks = []  # that admission's: lost with the device state
         self._fail_all_and_recover()
@@ -5243,12 +5327,16 @@ class GenerationEngine:
     def _drop_admission(self, reqs, exc: Exception) -> None:
         """:meth:`_admission_failed` less the recovery of the device
         state (which may be what found the failure)."""
-        if isinstance(exc, _TickFailed):
-            reqs, exc = exc.owners or reqs, exc.__cause__
-        _log.error(
-            "admission failed: a prefill-side program raised", exc_info=exc
-        )
-        self._note_admission_crash(reqs)
+        if isinstance(exc, _StepFailed):
+            exc = exc.__cause__
+            _log.error("decode step failed under an admission", exc_info=exc)
+        else:
+            if isinstance(exc, _TickFailed):
+                reqs, exc = exc.owners or reqs, exc.__cause__
+            _log.error(
+                "admission failed: a prefill-side program raised", exc_info=exc
+            )
+            self._note_admission_crash(reqs)
         failed = {id(req) for req in reqs}
         self._pending = [p for p in self._pending if id(p.req) not in failed]
         self._seq_state = None  # the scratch is that admission's
@@ -5266,7 +5354,8 @@ class GenerationEngine:
         buffers restore service for subsequent requests.  A chunk sent
         ahead of the failure is waited for first: journaled if it ran (the
         scratch is not the slots'), its admission failed with the rest if
-        it did not."""
+        it did not.  The step in flight is lost with the slots it serves."""
+        self._ahead = None
         try:
             self._close_ticks()
         except _TickFailed as failed:
@@ -5320,3 +5409,24 @@ class GenerationEngine:
             self._reset_device_state()
         except Exception:
             _log.exception("device state reallocation failed")
+
+
+@dataclass(eq=False)
+class _StepOut:
+    """A plain decode step dispatched and not read back yet
+    (``GenerationEngine._ahead`` while it stays out across a pass)."""
+
+    t0: float  # perf_counter before its dispatch
+    # Its own output tokens: the step after it reads them on the device,
+    # and does not donate them.
+    tokens: object
+    slots: tuple  # the _Slot each row was computed for, None where inactive
+    window: int
+    counts: list  # the ``_moe_pending`` entries it noted, until its read-back
+
+
+class _StepFailed(RuntimeError):
+    """The read-back of the decode step in flight raised (``__cause__``
+    holds the error), wherever the pass took it: at a blocking point of an
+    admission too.  The failure is the step's, and no prompt is blamed
+    (``GenerationEngine._drop_admission``)."""

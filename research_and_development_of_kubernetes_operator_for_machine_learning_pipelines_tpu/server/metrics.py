@@ -222,6 +222,17 @@ class ServerMetrics:
             ident_labels + ["when"],
             registry=self.registry,
         )
+        # How often a plain decode step went out behind the one before it,
+        # before that one was read back: ahead / (ahead + in_turn) is the
+        # share of steps the chip did not idle in front of.
+        self.decode_dispatch = Counter(
+            "tpumlops_decode_dispatch_total",
+            "Plain decode step programs by where the engine dispatched "
+            "them: ahead (behind the step still in flight, before its "
+            "read-back) or in_turn (with no step in flight)",
+            ident_labels + ["when"],
+            registry=self.registry,
+        )
         self.prefill_key_blocks = Counter(
             "tpumlops_prefill_key_blocks_total",
             "Key blocks of the cache's capacity, summed over the "
@@ -790,6 +801,9 @@ class ServerMetrics:
 
     def inc_prefill_dispatch(self, when: str):
         self.prefill_dispatch.labels(**self.identity, when=when).inc()
+
+    def inc_decode_dispatch(self, when: str):
+        self.decode_dispatch.labels(**self.identity, when=when).inc()
 
     def inc_prefill_key_blocks(self, walked: int, skipped: int):
         self.prefill_key_blocks.labels(**self.identity, kind="walked").inc(walked)

@@ -305,9 +305,14 @@ def test_the_next_chunk_is_dispatched_right_behind_the_steps_dispatch(
         ahead += 1
         (chunk,) = behind
         (sync,) = opened("engine.prefill_sync")
-        (readback,) = opened("engine.decode_readback")
-        assert chunk[2] <= sync[1], "waited before sending the next chunk"
-        assert sync[2] <= readback[1]
+        # The step still in flight (where the pass found one) is read back
+        # before the wait for the chunk sent in turn, and the pass's own
+        # step last: a chunk behind it, it stays out no longer.
+        readbacks = opened("engine.decode_readback")
+        assert 1 <= len(readbacks) <= 2
+        assert chunk[2] <= min(sync[1], readbacks[0][1]), (
+            "waited before sending the next chunk")
+        assert sync[2] <= readbacks[-1][1]
     # LONG's chunks after the first all went out behind the rider's steps,
     # the last one too; its first and the rider's own in the admit phase.
     assert ahead == 4 and sent.count("ahead") == 4
@@ -357,17 +362,19 @@ def _assert_account_adds_up(eng, since=None):
 
 
 def test_a_step_onto_an_idle_chip_closes_one_interval_before_decode(tiny):
-    """A lone stream, no chunks: every step but the first is dispatched
-    after the engine thread saw the step before it end, so each closes
-    exactly one interval under ``before="decode"``, as long as the spans
-    between that read-back and the dispatch's return, and no longer."""
-    tracer = _LoggingTracer()
-    eng = _engine(tiny, tracer=tracer)
+    """A lone stream, no chunks: its first step goes out onto a chip the
+    engine thread has seen idle (its first token was just read), so it
+    closes one interval under ``before="decode"``, as long as the spans
+    between that read and the dispatch's return, and no longer.  Every
+    later step is dispatched behind the one still in flight, before that
+    one is read back: it closes none, and goes out ``ahead``."""
+    tracer, sent = _LoggingTracer(), []
+    eng = _engine(tiny, tracer=tracer, on_decode_dispatch=sent.append)
     try:
         _serve(eng, [PROMPTS[0]], new=4)  # compiles the programs
         n0, steps0 = _intervals(eng), eng.dispatches_total["decode"]
         s0 = _starved(eng)[0].get("decode", (0.0, 0))[0]
-        del tracer.log[:]
+        del tracer.log[:], sent[:]
         _serve(eng, [PROMPTS[0]], new=10)
         steps = eng.dispatches_total["decode"] - steps0
         n1 = _intervals(eng)
@@ -375,28 +382,42 @@ def test_a_step_onto_an_idle_chip_closes_one_interval_before_decode(tiny):
         log = list(tracer.log)
         _assert_account_adds_up(eng)
         by_span = _starved(eng)[1]
+        assert eng._ahead is None and eng._unseen == 0
     finally:
         eng.shutdown()
     # The fused admission's prefill met a chip the last request had left
     # idle (one interval, the wait for traffic taken out of it); its first
-    # token is read before the first step goes out, so that step too.
+    # token is read before the first step goes out, so that step closes
+    # one too, and no step after it does.
     assert steps == 9
-    assert n1["decode"] - n0["decode"] == steps
+    assert n1["decode"] - n0["decode"] == 1
     assert n1["prefill"] - n0.get("prefill", 0) == 1
     assert set(n1) == {"prefill", "decode"}
+    assert sent == ["in_turn"] + ["ahead"] * (steps - 1)
+    assert sent.count("ahead") / len(sent) == (steps - 1) / steps
     readbacks = [e for e in log if e[0] == "engine.decode_readback"]
     dispatches = [e for e in log if e[0] == "engine.decode_dispatch"]
     admits = [e for e in log if e[0] == "engine.prefill_dispatch"]
     assert len(readbacks) == len(dispatches) == steps and len(admits) == 1
-    # Step k+1's interval runs from inside step k's read-back span to inside
-    # its own dispatch span (the first step's from the admission's).
-    ends_before = [admits[0]] + readbacks[:-1]
-    at_least = sum(d[1] - r[2] for r, d in zip(ends_before, dispatches))
-    at_most = sum(d[2] - r[1] for r, d in zip(ends_before, dispatches))
-    assert at_least <= seconds <= at_most
-    # What the host was doing: the phases between a read-back and a dispatch.
-    assert {"engine.decode_dispatch", "engine.decode_assemble", "engine.emit",
-            "engine.journal", "engine.admit"} <= set(by_span)
+    # Step k+1 goes out before step k is read back; the last is read back
+    # in a pass that dispatches nothing (its row has no budget past it).
+    for k in range(steps - 1):
+        assert dispatches[k + 1][2] <= readbacks[k][1]
+    assert readbacks[-1][1] >= dispatches[-1][2]
+    # The one interval runs from where the first token was seen (behind
+    # the wait for it, before that tick's journal) to inside the first
+    # step's dispatch.
+    sync = [e for e in log if e[0] == "engine.prefill_sync"][0]
+    journal = min(
+        (e for e in log if e[0] == "engine.journal" and e[1] >= sync[2]),
+        key=lambda e: e[1],
+    )
+    assert dispatches[0][1] - journal[1] <= seconds
+    assert seconds <= dispatches[0][2] - sync[2]
+    # What the host was doing: the first token's emission, the assembly
+    # and the dispatch itself.
+    assert {"engine.decode_dispatch", "engine.decode_assemble",
+            "engine.emit"} <= set(by_span)
 
 
 def test_time_waiting_for_traffic_is_in_no_interval(tiny):

@@ -86,6 +86,9 @@ EXPECTED_SERVER = {
     # dispatched them ("ahead": right behind the pass's decode step |
     # "in_turn": in the admit phase).
     "tpumlops_prefill_dispatch": ("counter", _IDENT + ("when",)),
+    # Plain decode steps by where the engine dispatched them ("ahead":
+    # behind the step still in flight | "in_turn": with none in flight).
+    "tpumlops_decode_dispatch": ("counter", _IDENT + ("when",)),
     # Key blocks of the capacity a prefill chunk of the latent-attention
     # family multiplied ("walked") and did not reach ("skipped").
     "tpumlops_prefill_key_blocks": ("counter", _IDENT + ("kind",)),

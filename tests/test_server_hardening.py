@@ -252,7 +252,12 @@ def _saturate(eng):
     caller can wait the fixture clean."""
     slot_futs = []
     for _ in range(2):
-        slot_futs.append(eng.submit([5, 9, 2, 7], 56))
+        # Each token of an occupant holds the scheduler a little (its
+        # callback runs on that thread), so the occupants outlast the
+        # caller's request: finishing first would admit the queued one
+        # and empty the backlog the budget bounds.
+        slot_futs.append(eng.submit(
+            [5, 9, 2, 7], 56, on_token=lambda _t: time.sleep(0.05)))
         deadline = time.monotonic() + 60
         while eng._queue.qsize() > 0 and time.monotonic() < deadline:
             time.sleep(0.01)  # admitted into a slot
