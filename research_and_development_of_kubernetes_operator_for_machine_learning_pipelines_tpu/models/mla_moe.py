@@ -323,10 +323,7 @@ class MlaMoeConfig:
 
     @property
     def ring_rows(self) -> int:
-        """Rows of a sliding layer's ring: the window, rounded up to the
-        layout's tile of positions (128; 8 under that)."""
-        tile = 128 if self.sliding_window >= 128 else 8
-        return -(-self.sliding_window // tile) * tile
+        return _ring_rows(self.sliding_window)
 
     def view(self, kind: str) -> "MlaMoeConfig":
         """This config with ``kind``'s attention widths under the plain
@@ -362,6 +359,35 @@ def _kind_view(cfg: MlaMoeConfig, kind: str) -> MlaMoeConfig:
         rope_theta=cfg.swa_rope_theta,
         index_n_heads=0,
     )
+
+
+def _ring_rows(window: int) -> int:
+    """Rows of a sliding layer's ring: the window, rounded up to the
+    layout's tile of positions (128; 8 under that)."""
+    tile = 128 if window >= 128 else 8
+    return -(-window // tile) * tile
+
+
+def _ring_slots(valid, positions, ring: int):
+    """Where a chunk's rows land on a ring, [B, S]: a row's last ``ring``
+    real tokens at their position mod ring; padding, and a token that
+    ``ring`` later real ones of this chunk would overwrite, are dropped
+    (index = ring).  Counted in real tokens, not slots: a padded bucket may
+    be longer than the ring."""
+    real = valid.astype(jnp.int32)
+    later = real.sum(-1, keepdims=True) - jnp.cumsum(real, axis=-1)
+    fresh = valid & (later < ring)
+    return jnp.where(fresh, positions[None, :] % ring, ring)
+
+
+def _ring_bias(lengths, ring: int, window: int):
+    """A step's additive mask [B, 1, ring] over a ring: row j holds the
+    last position before the row's own that is j mod ring; it is attended
+    while inside the window."""
+    last = lengths[:, None] - 1
+    held = last - (last - jnp.arange(ring)[None, :]) % ring  # [B, ring]
+    in_window = (held >= 0) & (lengths[:, None] - held < window)
+    return jnp.where(in_window, 0.0, -1e9).astype(jnp.float32)[:, None]
 
 
 def _layer_plan(cfg) -> list:
@@ -1200,15 +1226,7 @@ def forward(
     v = {name: list(bufs) for name, bufs in cache.v.items()}
     window, ring = cfg.sliding_window, cfg.ring_rows
     if cfg.sliding_layers:
-        # A row's last ``ring`` real tokens land at their position mod
-        # ring; padding, and a token that ``ring`` later real ones of this
-        # chunk would overwrite, are dropped (index = ring).  Counted in
-        # real tokens, not slots: a padded bucket may be longer than the
-        # ring.
-        real = valid.astype(jnp.int32)
-        later = real.sum(-1, keepdims=True) - jnp.cumsum(real, axis=-1)
-        fresh = valid & (later < ring)
-        ring_at = jnp.where(fresh, positions[None, :] % ring, ring)  # [B, S]
+        ring_at = _ring_slots(valid, positions, ring)
     counts, dsa = jnp.zeros((3,), jnp.int32), jnp.zeros((2,), jnp.int32)
     for (kind, i), lp in zip(_layer_plan(cfg), params["layers"]):
         kc = cfg.view(kind)
@@ -1401,13 +1419,7 @@ def decode_ragged(
     before = jnp.arange(window)[None, :] < lengths[:, None]  # [B, W]
     mask_bias = jnp.where(before, 0.0, -1e9).astype(jnp.float32)[:, None]
     if cfg.sliding_layers:
-        # Ring row j holds the last position before the row's own that is
-        # j mod ring; it is attended while inside the window.
-        ring = cfg.ring_rows
-        last = lengths[:, None] - 1
-        held = last - (last - jnp.arange(ring)[None, :]) % ring  # [B, ring]
-        in_window = (held >= 0) & (lengths[:, None] - held < cfg.sliding_window)
-        ring_bias = jnp.where(in_window, 0.0, -1e9).astype(jnp.float32)[:, None]
+        ring_bias = _ring_bias(lengths, cfg.ring_rows, cfg.sliding_window)
 
     news = {name: [] for name in (*cache.k, *cache.v)}
     counts, dsa = jnp.zeros((3,), jnp.int32), jnp.zeros((2,), jnp.int32)

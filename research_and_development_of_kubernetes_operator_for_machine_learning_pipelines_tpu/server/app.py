@@ -1955,6 +1955,7 @@ def make_gen_engine(
         on_prefill_dispatch=metrics.inc_prefill_dispatch if metrics else None,
         on_decode_dispatch=metrics.inc_decode_dispatch if metrics else None,
         on_key_blocks=metrics.inc_prefill_key_blocks if metrics else None,
+        on_attn_pairs=metrics.inc_attn_pairs if metrics else None,
         family=family,
         on_moe=metrics.inc_moe if metrics else None,
         tracer=metrics.tracer if metrics else None,

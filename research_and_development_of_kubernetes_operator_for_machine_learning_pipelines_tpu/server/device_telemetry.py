@@ -343,6 +343,13 @@ def build_hbm_ledger(
         state = family.state_row_bytes(cfg, dtype_bytes) * int(max_slots)
         ledger.components["cache_state"] = state
         ledger.components["kv_cache"] -= state
+    if hasattr(family, "ring_row_bytes"):
+        # A sliding-window layer's ring is rows a slot, not rows a
+        # position: its own line too.
+        ring = family.ring_row_bytes(cfg, dtype_bytes) * int(max_slots)
+        if ring:
+            ledger.components["cache_ring"] = ring
+            ledger.components["kv_cache"] -= ring
     ledger.components["sampling_state"] = sampling_state_bytes(max_slots)
     if prefix_cache_budget_bytes:
         ledger.host_components["prefix_cache_budget"] = int(

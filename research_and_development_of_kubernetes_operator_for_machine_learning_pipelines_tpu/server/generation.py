@@ -433,6 +433,8 @@ class GenerationEngine:
         on_prefill_dispatch: Callable[[str], None] | None = None,
         on_decode_dispatch: Callable[[str], None] | None = None,  # one a step
         on_key_blocks: Callable[[int, int], None] | None = None,  # walked, skipped
+        # program, {kind: (pairs computed, pairs attended)}: one a chunk, one a step
+        on_attn_pairs: Callable[[str, dict], None] | None = None,
     ):
         import jax
         import jax.numpy as jnp
@@ -476,6 +478,11 @@ class GenerationEngine:
         # (``prefill_key_blocks``): host arithmetic at the dispatch.
         self._key_blocks = getattr(lm, "prefill_key_blocks", None)
         self._on_key_blocks = on_key_blocks
+        # A family that says which (query, key) pairs its attention cores
+        # multiply and how many of them its masks keep (``attn_pairs``):
+        # host arithmetic at a chunk's and a step's dispatch.
+        self._attn_pairs = getattr(lm, "attn_pairs", None)
+        self._on_attn_pairs = on_attn_pairs if self._attn_pairs else None
         self._moe_pending: list = []
         self._params = params
         self._cfg = cfg
@@ -3921,6 +3928,9 @@ class GenerationEngine:
                 self._on_prefill_dispatch(when)
             if self._key_blocks is not None and self._on_key_blocks is not None:
                 self._on_key_blocks(*self._key_blocks(self._cfg, offset, C))
+            if self._on_attn_pairs is not None:
+                self._on_attn_pairs("prefill", self._attn_pairs(
+                    self._cfg, "prefill", [offset], [self._chunk_tokens([prog])], C))
             # The chunk's logits: the next chunk donates the scratch, not
             # these.
             self._open_tick(
@@ -5003,6 +5013,16 @@ class GenerationEngine:
             self._on_decode_dispatch(
                 "in_turn" if self._ahead is None else "ahead"
             )
+        if not self._in_warmup and self._on_attn_pairs is not None:
+            # A row's query sits at the position behind its known tokens,
+            # one further where the step in flight was computed for it.
+            out = self._ahead.slots if self._ahead else (None,) * self.max_slots
+            first = [
+                s.prompt_len + len(s.generated) - 1 + int(out[i] is s)
+                for i, s in enumerate(self._slots) if active_np[i]
+            ]
+            self._on_attn_pairs("decode", self._attn_pairs(
+                self._cfg, "decode", first, [1] * len(first), window))
         counts = self._moe_pending[noted:]
         del self._moe_pending[noted:]
         slots = tuple(

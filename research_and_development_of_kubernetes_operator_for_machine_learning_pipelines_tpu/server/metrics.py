@@ -243,6 +243,25 @@ class ServerMetrics:
             ident_labels + ["kind"],
             registry=self.registry,
         )
+        # What the attention cores of a family that says so multiply
+        # against what its causal and window masks keep, by program and
+        # layer kind: attended / computed is the share of the products
+        # that count.
+        self.attn_pairs_computed = Counter(
+            "tpumlops_attn_pairs_computed_total",
+            "(query, key) pairs the attention cores multiplied for real "
+            "query rows, a pair a layer summed over the layers of the kind; "
+            "host arithmetic from the call's shapes, trip count and "
+            "lengths at its dispatch",
+            ident_labels + ["program", "kind"],
+            registry=self.registry,
+        )
+        self.attn_pairs_attended = Counter(
+            "tpumlops_attn_pairs_attended_total",
+            "Of those pairs, the ones inside the causal and window masks",
+            ident_labels + ["program", "kind"],
+            registry=self.registry,
+        )
         # Routed-expert traffic of a sparse-expert family, by program
         # (prefill | decode): assignments / activations is the mean
         # number of tokens an expert that was read got to work on;
@@ -808,6 +827,14 @@ class ServerMetrics:
     def inc_prefill_key_blocks(self, walked: int, skipped: int):
         self.prefill_key_blocks.labels(**self.identity, kind="walked").inc(walked)
         self.prefill_key_blocks.labels(**self.identity, kind="skipped").inc(skipped)
+
+    def inc_attn_pairs(self, program: str, pairs: dict):
+        """``pairs``: ``{kind: (computed, attended)}`` of one call."""
+        short = {"full_attention": "full", "sliding_attention": "sliding"}
+        for kind, (computed, attended) in pairs.items():
+            labels = dict(self.identity, program=program, kind=short.get(kind, kind))
+            self.attn_pairs_computed.labels(**labels).inc(computed)
+            self.attn_pairs_attended.labels(**labels).inc(attended)
 
     def inc_moe(self, program: str, counts: dict, routed: int, row_tile: int):
         """One call of a routed family's ``program``: ``counts`` is what

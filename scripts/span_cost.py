@@ -13,6 +13,11 @@ closed, split by span, at the next dispatch.  A saturated pass, whose step
 queues behind a chunk, opens no interval
 (`account_us_per_pass_no_interval`: the stamps and their checks).
 
+And what the attention-pairs counters cost a decode step of 8 active rows
+(`attn_pairs_us_per_step.<configuration>`): the engine's query positions,
+`gdn_moe.attn_pairs` and `ServerMetrics.inc_attn_pairs`, at the two GQA
+configurations of the benchmark as the program runs them.
+
     python scripts/span_cost.py [--capture]
 """
 
@@ -108,6 +113,51 @@ def pass_cost_us(every_pass: bool, n: int = 20_000) -> float:
     return best[True] - best[False]
 
 
+def attn_pairs_us_per_step(cfg, n: int = 20_000) -> float:
+    """Best of five of what `GenerationEngine._step_out` adds a decode
+    step for a family with `attn_pairs`: 8 active rows, one step in
+    flight, the widest window bucket."""
+    from types import SimpleNamespace
+
+    from tpumlops.models import gdn_moe
+    from tpumlops.server.metrics import ServerMetrics
+
+    metrics = ServerMetrics("d", "p", "ns")
+    slots = [SimpleNamespace(prompt_len=4096 + 512 * i, generated=list(range(20 + i)))
+             for i in range(8)]
+    active_np, out, window = [True] * 8, tuple(slots), cfg.max_seq
+    rounds = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            first = [
+                s.prompt_len + len(s.generated) - 1 + int(out[i] is s)
+                for i, s in enumerate(slots) if active_np[i]
+            ]
+            metrics.inc_attn_pairs("decode", gdn_moe.attn_pairs(
+                cfg, "decode", first, [1] * len(first), window))
+        rounds.append((time.perf_counter() - t0) / n * 1e6)
+    return min(rounds)
+
+
+def gqa_configs() -> dict:
+    """The benchmark's two GQA configurations as the program runs them."""
+    import json
+
+    from tpumlops.models import gdn_moe
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+    sys.path.append(str(bench))
+    from references import laguna_decoder
+
+    model = json.loads((bench / "configs" / "laguna-s-2.1-bf16.json").read_text())["model"]
+    return {
+        "qwen3-next-80b-a3b-bf16": gdn_moe.GdnMoeConfig(
+            vocab_size=37984, num_layers=8, n_local_experts=128, max_seq=8704),
+        "laguna-s-2.1-bf16": gdn_moe.GdnMoeConfig(**laguna_decoder.geometry(model)),
+    }
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--capture", action="store_true")
@@ -127,6 +177,8 @@ def main() -> int:
                 jax.profiler.stop_trace()
     out["account_us_per_pass"] = pass_cost_us(every_pass=True)
     out["account_us_per_pass_no_interval"] = pass_cost_us(every_pass=False)
+    for name, cfg in gqa_configs().items():
+        out[f"attn_pairs_us_per_step.{name}"] = attn_pairs_us_per_step(cfg)
     print(json.dumps(out))
     return 0
 

@@ -92,6 +92,11 @@ EXPECTED_SERVER = {
     # Key blocks of the capacity a prefill chunk of the latent-attention
     # family multiplied ("walked") and did not reach ("skipped").
     "tpumlops_prefill_key_blocks": ("counter", _IDENT + ("kind",)),
+    # (query, key) pairs the attention cores multiplied and those inside
+    # the causal and window masks, by program and layer kind (full |
+    # sliding), for a family that says so.
+    "tpumlops_attn_pairs_computed": ("counter", _IDENT + ("program", "kind")),
+    "tpumlops_attn_pairs_attended": ("counter", _IDENT + ("program", "kind")),
     # Engine on_token stamp -> the SSE event's write returning.
     "tpumlops_emit_lag_seconds": ("histogram", _IDENT),
     # Routed-expert traffic of a sparse-expert family by program (prefill

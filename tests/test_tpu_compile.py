@@ -815,3 +815,103 @@ def test_state_programs_leave_rows_and_state_in_place(one_chip, program):
                   "layer.attn_core", "layer.attn_gate", "layer.attn_out",
                   "layer.moe_router", "layer.moe_experts", "layer.moe_shared"):
         assert scope in text, scope
+
+
+def _laguna_cfg():
+    """``benchmarks/configs/laguna-s-2.1-bf16.json`` as the program runs
+    it: 8 of 48 layers (full + dense, sliding x 3, full, sliding x 3), 32
+    of 256 experts held, 12544 of 100352 vocabulary rows, 8704 positions;
+    every width as published."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from tpumlops.models import gdn_moe
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+    if str(bench) not in sys.path:
+        sys.path.append(str(bench))
+    from references import laguna_decoder
+
+    model = json.loads((bench / "configs" / "laguna-s-2.1-bf16.json").read_text())["model"]
+    return gdn_moe.GdnMoeConfig(**laguna_decoder.geometry(model))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_window_programs_leave_rows_and_ring_in_place(one_chip, program):
+    """The window configuration at the benchmark's published widths (8
+    slots x 8704 positions, chunk 512): the decode step at its widest
+    window and the prefill chunk fit the chip beside 5.69 GB of bf16
+    weights; every donated buffer (the two full layers' K and V rows, the
+    six sliding layers' rings of 512 rows) aliases and none is copied or
+    relaid whole; the chunk holds no ``[heads, chunk, capacity]`` float32
+    scores; the expert matmuls are the kernel at 32 groups in the seven
+    expert layers."""
+    from tpumlops.models import gdn_moe
+
+    cfg = _laguna_cfg()
+    assert cfg.kinds == (gdn_moe.FULL,) + (gdn_moe.SLIDING,) * 3 + (
+        gdn_moe.FULL,) + (gdn_moe.SLIDING,) * 3
+    params = _on(one_chip, jax.eval_shape(
+        lambda: gdn_moe.init(jax.random.key(0), cfg, jnp.bfloat16)))
+    if program == "decode":
+        cache = _on(one_chip, jax.eval_shape(
+            lambda: gdn_moe.RaggedKVCache.create(cfg, 8)))
+
+        def fn(params, toks, k, v, lengths, active):
+            logits, c, counts = gdn_moe.decode_ragged(
+                params, toks, gdn_moe.RaggedKVCache(k, v, lengths), cfg,
+                active=active, window=8704)
+            return jnp.argmax(logits[:, -1], -1), c.k, c.v, c.lengths, counts
+
+        args = (params, _sds(one_chip, (8, 1), jnp.int32), cache.k, cache.v,
+                cache.lengths, _sds(one_chip, (8,), jnp.bool_))
+    else:
+        seq = _on(one_chip, jax.eval_shape(lambda: gdn_moe.KVCache.create(cfg, 1)))
+
+        def fn(params, ids, sk, sv, slen):
+            logits, s, counts = gdn_moe.forward(
+                params, ids, gdn_moe.KVCache(sk, sv, slen), cfg)
+            return logits[0], s.k, s.v, s.length, counts
+
+        args = (params, _sds(one_chip, (1, 512), jnp.int32), seq.k, seq.v,
+                seq.length)
+    compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    leaves = sum(math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert leaves == 2_843_053_056  # 5,686,106,112 bytes, 33.1 % of 16 GiB
+    assert gdn_moe.param_counts(cfg)[1] == leaves - (8 * 2 * 3072 + 3072)  # the norms
+    # A slot: two full layers' K and V of 1024 numbers a position, and six
+    # rings of 512 rows of the same.
+    assert gdn_moe.ring_row_bytes(cfg) == 6 * 512 * 2 * 1024 * 2
+    assert gdn_moe.kv_row_bytes(cfg) == 2 * 8704 * 2 * 1024 * 2 + 6 * 512 * 2 * 1024 * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 0.8 * HBM
+    buffers = jax.tree.leaves(args[2:4])
+    assert len(buffers) == 2 * 2 + 6 * 2  # a full layer: key, value; a sliding one: its ring's
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in buffers)
+    assert mem.alias_size_in_bytes >= cache_bytes, "a buffer's donation not credited"
+    assert mem.temp_size_in_bytes < 1.5 * 2**30, mem.temp_size_in_bytes
+    # The slots' rows and rings; in the chunk the scratch's rows (its ring
+    # of [1, 512, 1024] has the element count of the chunk's own K or V
+    # rows, which the projections lay out as they like).
+    whole = {math.prod(a.shape) for a in buffers
+             if program == "decode" or a.shape[1] > cfg.ring_rows}
+    for name, dims, opcode in _array_instructions(compiled.as_text()):
+        relayout = opcode in ("copy", "copy-start", "transpose") or (
+            opcode == "fusion" and ("copy" in name or "transpose" in name))
+        assert not (relayout and math.prod(dims) in whole), (
+            f"{name}: a whole-buffer {opcode} of {dims}")
+        assert not (opcode != "parameter" and 8704 in dims
+                    and math.prod(dims) >= 48 * 512 * 8704), (
+            f"{name}: {dims} is a capacity-wide score tensor")
+    text = compiled.as_text()
+    calls = _kernel_calls(text, "grouped_matmul")
+    assert len(calls) == 21  # three a layer, seven expert layers
+    assert all("layer.moe_experts" in l for l in calls)
+    assert "ragged-dot" not in text
+    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    for scope in ("ring_commit", "kv_commit", "rope", "layer.attn_qkv",
+                  "layer.attn_core", "layer.attn_gate", "layer.attn_out",
+                  "layer.mlp", "layer.moe_router", "layer.moe_experts",
+                  "layer.moe_shared"):
+        assert scope in text, scope
