@@ -76,10 +76,15 @@ Design decisions:
   and sum (``_gqa_blocks``; the trip count follows what is written), a
   decode step a strict window plus the position in flight, rows
   committed after the loop: ``mla_moe.py``'s contracts at GQA's shapes.
-  A sliding layer's chunk attends the ring's ``sliding_window - 1`` rows
-  before it and its own rows, masked by absolute positions, and the
-  ring takes the chunk's rows when the layer has read it; its step reads
-  the whole ring.
+  A sliding layer's chunk attends the ring's ``ring_rows`` rows before
+  it, in position order, and its own rows, masked by absolute positions
+  (the ring's oldest row is outside every query's window: a ring of 512
+  and a chunk of 512 make two whole key blocks), and the ring takes the
+  chunk's rows when the layer has read it; its step reads the whole
+  ring.  On the TPU both kinds' prefill core is one Pallas kernel
+  (``ops/gqa_prefill_attention.py``: a key block's scores stay in VMEM,
+  the rows read as they lie), the einsum body off it and where no tiling
+  fits.
 - The expert layer is ``mla_moe.moe_ffn``, shared with that family: one
   grouped matmul, one share arithmetic (``n_local_experts`` from
   ``local_expert_start`` of a router over all ``n_routed_experts``), one
@@ -112,11 +117,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..ops.gqa_prefill_attention import gqa_prefill_attention
 from .common import rms_norm
 from .llama import _attended_window, _embed, _qmatmul
 from .mla_moe import (
     _commit_row,
     _key_block,
+    _key_tile,
     _layer_plan,
     _layer_rows,
     _ring_bias,
@@ -512,7 +519,7 @@ def attn_pairs(cfg: GdnMoeConfig, program: str, first: list, count: list,
     head of it).  Row ``r`` brings ``count[r]`` real queries from
     position ``first[r]``.  ``program`` "prefill": one call of ``width``
     query slots; a full layer walks the key blocks of its capacity that
-    hold a written position, a sliding one the ring's ``window - 1`` rows
+    hold a written position, a sliding one the ring's ``ring_rows`` rows
     before the chunk and the chunk's own.  "decode": ``width`` is the
     step's window bucket; a full layer reads it and the position in
     flight for every row, a sliding one its whole ring and the position
@@ -529,7 +536,7 @@ def attn_pairs(cfg: GdnMoeConfig, program: str, first: list, count: list,
             if program == "decode":
                 keys = (width if kind == FULL else cfg.ring_rows) + 1
             elif kind == SLIDING:
-                keys = cfg.sliding_window - 1 + width
+                keys = cfg.ring_rows + width
             else:
                 kb = _key_block(cfg.max_seq)
                 keys = cfg.max_seq if kb == cfg.max_seq else -(-(f + width) // kb) * kb
@@ -877,38 +884,42 @@ def _attn_qkv(xn, lp, cos, sin, cfg):
                 k.reshape(b, s, nkv * d), v)
 
 
-def _gqa_blocks(q, keys, values, positions, written, key_pos=None, window=0):
-    """Causal attention of ``S`` queries ``q`` [B,S,KV,R,D] at
-    ``positions`` [S] over the first ``written`` of the ``T`` cached
-    positions (a traced scalar: no query sees a later one), ``keys`` /
-    ``values`` [B,T,KV*D] as cached, a key block at a time with a
-    running maximum and sum (one block, a plain softmax, up to
-    ``mla_moe.ONE_PASS`` positions).  With ``key_pos`` [T] the keys are
-    not the cache's positions in order but those (a ring's rows and a
-    chunk's, negative where none was written), and a query sees a key
-    inside the last ``window`` positions up to its own.  Returns ctx
-    [B,S,NH*D]."""
+def _gqa_blocks(q, keys, values, start, written, key_start=0, window=0):
+    """Causal attention of ``S`` queries ``q`` [B,S,KV,R,D] at positions
+    ``start ..`` over the first ``written`` of ``T`` keys ``keys`` /
+    ``values`` [B,T,KV*D] as cached, at positions ``key_start ..`` (ints
+    or traced scalars; no query sees a key past ``written``).  A full
+    layer's keys are its cache rows (``key_start`` 0); a sliding layer's
+    are its ring's ``ring_rows`` rows in position order, the positions
+    before the chunk (negative where none was written; the oldest is
+    outside every query's window), then the chunk's own, and with a
+    ``window`` a query sees a key at a position ``>= 0`` inside the last
+    ``window`` positions up to its own.  On the TPU the fused core of
+    ``ops/gqa_prefill_attention.py`` at the shapes its tiles take; else,
+    and off it, the einsum body here: a key block at a time with a running
+    maximum and sum (one block, a plain softmax, up to
+    ``mla_moe.ONE_PASS`` positions).  Returns ctx [B,S,NH*D]."""
     b, s, nkv, r, d = q.shape
     dt = q.dtype
     t = keys.shape[1]
     kb = _key_block(t)
     scale = 1.0 / math.sqrt(d)
 
-    def scores_of(j):
-        lo = j * kb
-        k = _layer_rows(keys, lo, kb).astype(dt).reshape(b, kb, nkv, d)
-        v = _layer_rows(values, lo, kb).astype(dt).reshape(b, kb, nkv, d)
-        sc = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-        if key_pos is None:
-            see = (lo + jnp.arange(kb))[None, :] <= positions[:, None]  # [S, kb]
-        else:
-            kp = lax.dynamic_slice_in_dim(key_pos, lo, kb)[None, :]
-            qp = positions[:, None]
-            see = (kp >= 0) & (kp <= qp) & (qp - kp < window)
-        return sc, see, v
+    def einsums(q, keys, values, start, written, key_start):
+        qp = (start + jnp.arange(s))[:, None]
 
-    with jax.named_scope("layer.attn_core"):
+        def scores_of(j):
+            lo = j * kb
+            k = _layer_rows(keys, lo, kb).astype(dt).reshape(b, kb, nkv, d)
+            v = _layer_rows(values, lo, kb).astype(dt).reshape(b, kb, nkv, d)
+            sc = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+            kp = (key_start + lo + jnp.arange(kb))[None, :]
+            see = kp <= qp  # [S, kb]
+            if window:
+                see &= (kp >= 0) & (qp - kp < window)
+            return sc, see, v
+
         if kb == t:
             sc, see, v = scores_of(0)
             probs = jax.nn.softmax(jnp.where(see, sc, -1e9), axis=-1).astype(dt)
@@ -934,6 +945,11 @@ def _gqa_blocks(q, keys, values, positions, written, key_pos=None, window=0):
              jnp.zeros((b, nkv, r, s, d), jnp.float32)))
         ctx = acc / jnp.maximum(total, 1e-30)[..., None]
         return ctx.transpose(0, 3, 1, 2, 4).reshape(b, s, nkv * r * d).astype(dt)
+
+    with jax.named_scope("layer.attn_core"):
+        return gqa_prefill_attention(
+            q, keys, values, start, written, key_start, window=window,
+            key_block=_key_tile(t), scale=scale, fallback=einsums)
 
 
 def _gqa_step(q, k_new, v_new, ck, cv, mask_bias):
@@ -1160,9 +1176,9 @@ def forward(
     ``cache.length``; ids < 0 are padding behind a row's real tokens
     (embedded as id 0, not routed, folded into no state, written to no
     ring).  A full layer writes its rows, then attends the blocks of its
-    cache written so far; a sliding layer attends its ring's last ``window
-    - 1`` rows and the chunk's own, then its ring takes the chunk's rows
-    in one drop-scatter; a linear layer reads its convolution tail and its
+    cache written so far; a sliding layer attends its ring's ``ring_rows``
+    rows and the chunk's own, then its ring takes the chunk's rows in one
+    drop-scatter; a linear layer reads its convolution tail and its
     state, runs the chunked rule, and writes both back.
     Returns ``(logits [B,S,vocab] float32, cache, counts)`` (``counts``
     int32 ``[len(COUNTS)]``, summed over layers)."""
@@ -1188,10 +1204,10 @@ def forward(
     window, ring = cfg.sliding_window, cfg.ring_rows
     if cfg.sliding_layers:
         ring_at = _ring_slots(valid, positions, ring)
-        # The ring's rows of the window - 1 positions before the chunk,
-        # then the chunk's own.
-        before = start - (window - 1) + jnp.arange(window - 1)
-        key_pos = jnp.concatenate([before, positions])
+        # The ring's slots of the ``ring`` positions before the chunk, in
+        # position order (a ring of 512 rows and a chunk of 512: two key
+        # blocks of 512).
+        before = (start - ring + jnp.arange(ring)) % ring
     for (kind, i), lp in zip(_layer_plan(cfg), params["layers"]):
         kc = cfg.view(kind)
         xn = _norm(x, lp["attn_norm"], cfg)
@@ -1200,15 +1216,15 @@ def forward(
             with jax.named_scope("kv_commit"):
                 k["key"][i] = put(k["key"][i], k_new)
                 v["value"][i] = put(v["value"][i], v_new)
-            ctx = _gqa_blocks(q, k["key"][i], v["value"][i], positions, start + s)
+            ctx = _gqa_blocks(q, k["key"][i], v["value"][i], start, start + s)
             x = _attn_out(x, ctx, gate, xn, lp, kc)
         elif kind == SLIDING:
             q, gate, k_new, v_new = _attn_qkv(xn, lp, *ropes[kind], kc)
-            take = lambda buf: jnp.take(buf, before % ring, axis=1).astype(k_new.dtype)
+            take = lambda buf: jnp.take(buf, before, axis=1).astype(k_new.dtype)
             ctx = _gqa_blocks(
                 q, jnp.concatenate([take(k["ring_key"][i]), k_new], axis=1),
                 jnp.concatenate([take(v["ring_value"][i]), v_new], axis=1),
-                positions, window - 1 + s, key_pos=key_pos, window=window)
+                start, ring + s, key_start=start - ring, window=window)
             rows = jnp.arange(b)[:, None]
             with jax.named_scope("ring_commit"):
                 k["ring_key"][i] = k["ring_key"][i].at[rows, ring_at].set(

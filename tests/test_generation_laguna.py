@@ -66,7 +66,7 @@ def reference_logits(params, row):
 
 @pytest.mark.parametrize("prefill_chunk", [8, None])
 def test_engine_tokens_equal_the_reference_as_requests_join_and_leave(
-        params, prefill_chunk):
+        params, prefill_chunk, monkeypatch):
     """Four requests on two slots (they queue, join and leave, a slot sits
     idle while the other decodes), prompts of 13-50 (one to four rings of
     16) by chunks of 8 that straddle the window of 12, and by the engine's
@@ -74,7 +74,9 @@ def test_engine_tokens_equal_the_reference_as_requests_join_and_leave(
     so a slot's ring wraps while it decodes.  Greedy tokens equal the
     reference's own greedy continuation, which they can only do if the
     rings hold what the window says; the pairs the engine hands on are
-    the arithmetic's for every chunk and step it dispatched."""
+    the arithmetic's for every chunk and step it dispatched, and a sliding
+    chunk's computed pairs are the keys its core was handed (the ring's
+    16 rows and the chunk's 8) a real query."""
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, CFG.vocab_size, n).astype(np.int32)
                for n in (13, 37, 21, 50)]
@@ -94,6 +96,14 @@ def test_engine_tokens_equal_the_reference_as_requests_join_and_leave(
             assert top2[1] - top2[0] > 4 * ATOL, "a near-tie: pick another seed"
             assert int(np.argmax(at)) == tok, (len(prompt), i)
 
+    cores = []  # (queries, keys, window) of every prefill core traced
+    core = gdn_moe.gqa_prefill_attention
+
+    def seen_core(q, keys, *a, window, **kw):
+        cores.append((q.shape[1], keys.shape[1], window))
+        return core(q, keys, *a, window=window, **kw)
+
+    monkeypatch.setattr(gdn_moe, "gqa_prefill_attention", seen_core)
     pairs = []
     engine = GenerationEngine(
         params, CFG, max_slots=2, dtype=jnp.float32, family=gdn_moe,
@@ -126,6 +136,8 @@ def test_engine_tokens_equal_the_reference_as_requests_join_and_leave(
             len(p) * (len(p) + 1) // 2 for p in prompts)
         assert sum(p[S][1] for p in chunks) == 2 * sum(
             sum(min(t + 1, 12) for t in range(len(p))) for p in prompts)
+        assert {k for s, k, w in cores if w and s == 8} == {16 + 8}
+        assert sum(p[S][0] for p in chunks) == 2 * (16 + 8) * sum(map(len, prompts))
     else:
         assert chunks == []  # the whole-prompt call is no chunk
 

@@ -49,7 +49,7 @@ ATOL = 3e-5
 def small_key_blocks(monkeypatch):
     """Blocks of 16 keys: the capacity of 256 is sixteen of them, so
     prefill takes the blocked path the published size takes (a sliding
-    layer's ring and chunk, 11 + 16 keys, too)."""
+    layer's ring and chunk, 16 + 16 keys and more, too)."""
     monkeypatch.setattr(mla_moe, "KEY_BLOCK", 16)
     monkeypatch.setattr(mla_moe, "ONE_PASS", 16)
 
@@ -228,6 +228,37 @@ def test_chunked_prefill_then_ragged_decode_equals_the_full_forward(
         assert not np.asarray(buf[0]).any() and not np.asarray(buf[2]).any()
 
 
+def test_chunked_prefill_through_the_fused_core_equals_the_reference(
+        params, toks, want, monkeypatch):
+    """The TPU's prefill core (``ops/gqa_prefill_attention.py``, interpret
+    mode) in every attention layer of a prompt of 47 in chunks of 16: a
+    sliding call takes the ring's 16 rows in position order and the
+    chunk's 16, two whole key blocks as the published 512 and 512 make
+    (the oldest row outside every window), a full one the blocks written
+    so far; every logit is the float32 reference's."""
+    from tpumlops.ops import gqa_prefill_attention as ga
+
+    calls = []
+
+    def core(q, keys, values, *a, window, **kw):
+        calls.append((keys.shape[1], window))
+        return ga.gqa_prefill_attention(q, keys, values, *a, window=window,
+                                        interpret=True, **kw)
+
+    monkeypatch.setattr(gdn_moe, "gqa_prefill_attention", core)
+    prompt, chunk = 47, 16
+    seq = gdn_moe.KVCache.create(CFG, 1, jnp.float32)
+    for at in range(0, prompt, chunk):
+        ids = np.full((1, chunk), gdn_moe.PAD_ID, np.int32)
+        n = min(chunk, prompt - at)
+        ids[0, :n] = toks[0, at:at + n]
+        logits, seq, _ = gdn_moe.forward(params, jnp.asarray(ids), seq, CFG, jnp.float32)
+        np.testing.assert_allclose(
+            np.asarray(logits[0, :n]), want[0, at:at + n], atol=ATOL)
+    assert calls == 3 * (2 * ([(256, 0)] + 3 * [(32, 12)]))  # F S S S F S S S a chunk
+    assert gdn_moe.attn_pairs(CFG, "prefill", [0], [16], 16)[S][0] == 6 * 16 * 32
+
+
 def test_padding_leaves_the_ring_alone(params, toks):
     """A chunk's padding rows take no ring row: the ring after a prompt
     of 13 in a padded chunk of 32 is the ring after the same 13 unpadded,
@@ -369,16 +400,15 @@ def test_each_gate_against_its_reference(gate):
     xn = gdn_moe._norm(x, lp["attn_norm"], cfg)
     q, g, k, v = gdn_moe._attn_qkv(xn, lp, cos, sin, c)
     if kind == S:
-        key_pos = jnp.concatenate([-(cfg.sliding_window - 1) + jnp.arange(cfg.sliding_window - 1),
-                                   positions])
-        pad = jnp.zeros((2, cfg.sliding_window - 1, k.shape[-1]))
+        # An empty ring (positions -ring .. -1) in front of the chunk.
+        pad = jnp.zeros((2, cfg.ring_rows, k.shape[-1]))
         ctx = gdn_moe._gqa_blocks(q, jnp.concatenate([pad, k], 1), jnp.concatenate([pad, v], 1),
-                                  positions, cfg.sliding_window - 1 + seq,
-                                  key_pos=key_pos, window=cfg.sliding_window)
+                                  0, cfg.ring_rows + seq, key_start=-cfg.ring_rows,
+                                  window=cfg.sliding_window)
     else:
         cache = jnp.zeros((2, cfg.max_seq, k.shape[-1]))
         ctx = gdn_moe._gqa_blocks(q, cache.at[:, :seq].set(k), cache.at[:, :seq].set(v),
-                                  positions, seq)
+                                  0, seq)
     got = gdn_moe._attn_out(x, ctx, g, xn, lp, c)
     with jax.default_matmul_precision("highest"):
         built = ref.build(geometry(cfg), seq)
@@ -436,15 +466,15 @@ def test_the_pairs_the_cores_multiply_and_the_masks_keep():
     queries and what the causal and window masks keep, by kind, a pair a
     layer."""
     # A chunk of 8 slots, 5 real, from position 20: a full layer walks
-    # ceil(28 / 16) = 2 blocks of 16 keys; a sliding one 11 ring rows and
-    # the 8 slots.  Attended: positions 20-24 see 21-25 keys / 12.
+    # ceil(28 / 16) = 2 blocks of 16 keys; a sliding one the ring's 16
+    # rows and the 8 slots.  Attended: positions 20-24 see 21-25 keys / 12.
     pairs = gdn_moe.attn_pairs(CFG, "prefill", [20], [5], 8)
     # (the capacity 256 is no more than ONE_PASS = 16 blocks of 16 here:
     # blocks of 16, as the fixture makes them)
     assert pairs == {F: (2 * 5 * 32, 2 * (21 + 22 + 23 + 24 + 25)),
-                     S: (6 * 5 * 19, 6 * 5 * 12)}
+                     S: (6 * 5 * 24, 6 * 5 * 12)}
     # From position 0 the window has not begun to bite.
-    assert gdn_moe.attn_pairs(CFG, "prefill", [0], [8], 8)[S] == (6 * 8 * 19, 6 * 36)
+    assert gdn_moe.attn_pairs(CFG, "prefill", [0], [8], 8)[S] == (6 * 8 * 24, 6 * 36)
     # Straddling it: positions 8-15 see 9, 10, 11, 12, 12, 12, 12, 12.
     assert gdn_moe.attn_pairs(CFG, "prefill", [8], [8], 8)[S][1] == 6 * (9 + 10 + 11 + 5 * 12)
     # A step of two live rows at positions 3 and 40 in a window of 64: the

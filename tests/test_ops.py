@@ -307,3 +307,140 @@ def test_prefill_attention_off_the_tpu_is_the_einsum_body():
     assert pa.tiles_for(512, 1024, 64, 192, 128, 1024, 128, 512, 2).nope == 256
     # 2048 queries: four tiles of 512.
     assert pa.tiles_for(2048, 2048, key_block=512, **geo).queries == 512
+
+
+# --- GQA prefill attention: the fused core against the einsum body it replaces ---
+
+from tpumlops.models import gdn_moe  # noqa: E402
+from tpumlops.ops import gqa_prefill_attention as ga  # noqa: E402
+
+GQA_CORE = {
+    # name: (kind, KV heads, query heads a KV head, head width, queries,
+    #        capacity (full) or ring rows (sliding), key block, start,
+    #        window, batch rows)
+    "full_one_block_written": ("full", 2, 6, 128, 32, 128, 32, 0, 0, 1),
+    "full_two_blocks_written": ("full", 2, 6, 128, 32, 128, 32, 32, 0, 1),
+    "full_last_block_partly_written_group_of_8_width_256": (
+        "full", 2, 8, 256, 32, 160, 32, 80, 0, 1),
+    "full_padded_query_rows_two_batch_rows": ("full", 2, 6, 128, 32, 128, 32, 40, 0, 2),
+    "sliding_ring_empty": ("sliding", 2, 9, 128, 32, 32, 32, 0, 32, 1),
+    "sliding_ring_partly_filled": ("sliding", 2, 9, 128, 32, 32, 32, 16, 32, 1),
+    "sliding_ring_wrapped_window_under_the_ring": ("sliding", 2, 9, 128, 32, 32, 32, 200, 28, 1),
+}
+
+
+def _gqa_case(case):
+    """The operands of one case, bf16 (the kernel's and the oracle's
+    matmuls multiply the same numbers), with the keys no query sees
+    poisoned for the kernel: NaN in the blocks behind ``written`` (never
+    walked: a NaN would come out of ``0 * NaN``), 1e4 in the unseen keys
+    of the walked blocks (a masked key's probability is an exact 0, so any
+    leak would be huge); the oracle's copy has zeros there."""
+    kind, nkv, r, d, s, rows, kb, start, window, b = GQA_CORE[case]
+    rng = np.random.default_rng(sorted(GQA_CORE).index(case))
+    t = rows + s if kind == "sliding" else rows
+    q = rng.standard_normal((b, s, nkv, r, d))
+    keys = rng.standard_normal((b, t, nkv * d))
+    values = rng.standard_normal((b, t, nkv * d))
+    qpos = start + np.arange(s)
+    if kind == "sliding":
+        # The ring's rows in position order, then the chunk's own.
+        key_start, written = start - rows, t
+        kpos = key_start + np.arange(t)
+        seen = ((kpos[None] >= 0) & (kpos[None] <= qpos[:, None])
+                & (qpos[:, None] - kpos[None] < window)).any(0)
+    else:
+        key_start, written = 0, start + s
+        kpos = np.arange(t)
+        seen = kpos <= qpos[-1]
+    walked = -(-written // kb) * kb
+    clean = [keys.copy(), values.copy()]
+    bad = [keys.copy(), values.copy()]
+    for c, x in zip(clean, bad):
+        c[:, ~seen] = 0.0
+        x[:, ~seen] = 1e4
+        x[:, walked:] = np.nan
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)
+    return (bf(q), [bf(x) for x in clean], [bf(x) for x in bad],
+            dict(start=start, written=written, key_start=key_start, window=window, kb=kb))
+
+
+def _gqa_run(monkeypatch, q, keys, values, at, **kw):
+    """``gdn_moe._gqa_blocks`` on one case's operands, its core the public
+    op with ``kw`` (``interpret=True``: the kernel; else, off the TPU, the
+    einsum body), ``written`` traced."""
+    monkeypatch.setattr(gdn_moe, "gqa_prefill_attention",
+                        functools.partial(ga.gqa_prefill_attention, **kw))
+    return np.asarray(gdn_moe._gqa_blocks(
+        q, keys, values, jnp.int32(at["start"]), jnp.int32(at["written"]),
+        key_start=jnp.int32(at["key_start"]), window=at["window"],
+    ).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("case", sorted(GQA_CORE))
+def test_gqa_prefill_attention_kernel_equals_the_einsum_body(case, monkeypatch):
+    """The GQA core (interpret mode) against ``gdn_moe._gqa_blocks``'s
+    einsum body in its blocked form on the same operands, both layer
+    kinds: a full layer over one, two and a last partly written key block
+    of a traced ``written``; a sliding layer over a ring that is empty,
+    partly filled and wrapped, then the chunk, under the window mask;
+    groups of 6, 8 and 9 query heads, widths 128 and 256; query rows at
+    positions behind a prompt's end, two batch rows."""
+    q, clean, bad, at = _gqa_case(case)
+    kb = at["kb"]
+    monkeypatch.setattr(mla_moe, "KEY_BLOCK", kb)
+    monkeypatch.setattr(mla_moe, "ONE_PASS", kb)
+    tiles = []
+    fused = ga._fused
+
+    def seen_fused(*a, **kw):
+        tiles.append(kw["tiles"])
+        return fused(*a, **kw)
+
+    monkeypatch.setattr(ga, "_fused", seen_fused)
+    want = _gqa_run(monkeypatch, q, *clean, at)  # off the TPU: the einsum body
+    assert not tiles
+    got = _gqa_run(monkeypatch, q, *bad, at, interpret=True)
+    b, s, nkv, r, d = q.shape
+    assert tiles == [ga.Tiles(s, kb, r, 1)]
+    assert got.shape == want.shape == (b, s, nkv * r * d)
+    assert np.isfinite(got).all()
+    # The same float32 operations in the same order on the CPU: bf16
+    # outputs equal but where a sum's order tips a rounding.
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    assert np.abs(got - want).mean() < 1e-3
+
+
+@pytest.mark.parametrize("group", [1, 3])
+def test_gqa_prefill_attention_in_head_groups_of_a_kv_head(group, monkeypatch):
+    """A KV head's query heads over several grid steps (a group of 1 or 3
+    of its 6, as the scoped VMEM makes it at wider tiles): each step reads
+    its own heads' lanes of the queries and output and the KV head's
+    lanes of the rows."""
+    q, clean, bad, at = _gqa_case("full_two_blocks_written")
+    monkeypatch.setattr(mla_moe, "KEY_BLOCK", at["kb"])
+    monkeypatch.setattr(mla_moe, "ONE_PASS", at["kb"])
+    monkeypatch.setattr(ga, "heads_per_step", lambda *a: group)
+    got = _gqa_run(monkeypatch, q, *bad, at, interpret=True)
+    want = _gqa_run(monkeypatch, q, *clean, at)
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    assert np.abs(got - want).mean() < 1e-3
+
+
+def test_gqa_prefill_attention_off_the_tpu_is_the_einsum_body():
+    """No kernel in a CPU lowering of either kind, and ``tiles_for`` has
+    no tiling for what the chip's layouts do not take (a single-token
+    call, a sliding call of 511 ring rows and a chunk, a head width under
+    the lanes); at the published geometries it has one."""
+    q, clean, _bad, at = _gqa_case("sliding_ring_partly_filled")
+    for key_start, window in ((0, 0), (at["key_start"], at["window"])):
+        f = lambda q, k, v: gdn_moe._gqa_blocks(
+            q, k, v, at["start"], at["written"], key_start=key_start, window=window)
+        assert "tpu_custom_call" not in jax.jit(f).lower(q, *clean).as_text()
+    assert ga.tiles_for(512, 8704, 6, 128, 512, 2) == ga.Tiles(512, 512, 6, 128)
+    assert ga.tiles_for(512, 1024, 9, 128, 512, 2) == ga.Tiles(512, 512, 3, 128)
+    assert ga.tiles_for(512, 8704, 8, 256, 512, 2) == ga.Tiles(512, 512, 4, 128)
+    assert ga.tiles_for(1, 8704, 6, 128, 512, 2) is None
+    assert ga.tiles_for(512, 1023, 9, 128, mla_moe._key_tile(1023), 2) is None
+    assert ga.tiles_for(512, 8704, 6, 64, 512, 2) is None
+    assert ga.tiles_for(512, 8704, 6, 128, 500, 2) is None

@@ -239,6 +239,18 @@ def _assert_no_float32_scores(hlo_text, heads, queries):
                 f"{m.group(1)}: float32 scores {dims} in HBM")
 
 
+def _assert_no_gqa_scores(hlo_text, queries=512):
+    """No float32 instruction of the program is a grouped-query core's
+    scores ``[..., queries, keys]`` (under any head, KV or batch axis, a
+    key block of 512 or more): they stay in the kernel's VMEM."""
+    for line in hlo_text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = f32\[([\d,]*)\]", line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d and int(d) != 1]
+            assert not (len(dims) >= 3 and dims[-2] == queries and dims[-1] >= 512), (
+                f"{m.group(1)}: float32 scores {dims} in HBM")
+
+
 def _assert_buffer_left_in_place(compiled, buffer_elements, cfg, windowed):
     """No ``copy``/``transpose`` of a whole ``[L, B, T, NKV, D]`` buffer of
     ``buffer_elements`` and, where the program takes a window, no
@@ -726,6 +738,56 @@ def test_prefill_attention_lowers_at_the_published_widths(one_chip, geometry):
     assert compiled.memory_analysis().temp_size_in_bytes < 100 * 2**20
 
 
+GQA_GEOMETRIES = {
+    # name: (KV heads, query heads a KV head, head width, keys, window):
+    # the three layer geometries of the GQA-and-experts family's two
+    # configurations, a 512-token chunk (a sliding layer: the ring's 512
+    # rows and the chunk's).
+    "laguna_full": (8, 6, 128, 8704, 0),
+    "laguna_sliding": (8, 9, 128, 1024, 512),
+    "qwen3_next_full": (2, 8, 256, 8704, 0),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(GQA_GEOMETRIES))
+def test_gqa_prefill_attention_lowers_at_the_published_widths(one_chip, geometry):
+    """Mosaic takes the GQA prefill core at the published head counts and
+    widths of the three layer geometries, 512 queries over key blocks of
+    512 with a traced block count, at the head group ``heads_per_step``
+    picks: inside the 16 MiB of scoped VMEM, or the compile raises.  The
+    program around it holds no float32 score tensor, and nothing is laid
+    out anew in front of the kernel or behind it."""
+    from tpumlops.models import gdn_moe
+    from tpumlops.ops import gqa_prefill_attention as ga
+
+    nkv, r, d, keys, window = GQA_GEOMETRIES[geometry]
+    tiles = ga.tiles_for(512, keys, r, d, 512, 2)
+    assert tiles is not None and tiles.queries == tiles.keys == 512
+    assert tiles.heads == {"laguna_full": 6, "laguna_sliding": 3,
+                           "qwen3_next_full": 4}[geometry]
+
+    def core(q, k, v, start):
+        q = q.reshape(1, 512, nkv, r, d)  # as the projections give it
+        if window:
+            return gdn_moe._gqa_blocks(q, k, v, start, keys, key_start=start - 512,
+                                       window=window)
+        return gdn_moe._gqa_blocks(q, k, v, start, start + 512)
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(core).lower(
+        _sds(one_chip, (1, 512, nkv * r * d), bf), _sds(one_chip, (1, keys, nkv * d), bf),
+        _sds(one_chip, (1, keys, nkv * d), bf), _sds(one_chip, (), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "gqa_prefill_attention")) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    _assert_no_gqa_scores(text)
+    for name, dims, opcode in _array_instructions(text):
+        assert not (opcode in ("copy", "transpose") and math.prod(dims) >= 512 * d), (
+            f"{name}: {opcode} of {dims}")
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def _qwen3_next_cfg():
     """``benchmarks/configs/qwen3-next-80b-a3b-bf16.json`` as the program
     runs it: 8 of 48 layers, 128 of 512 experts held, 37984 of 151936
@@ -744,9 +806,9 @@ def test_state_programs_leave_rows_and_state_in_place(one_chip, program):
     bf16 weights; every donated buffer of both kinds (the two full
     layers' K and V rows, the six linear layers' float32 state and
     convolution tail) aliases, so the state is updated in place, and none
-    is copied or relaid whole; the chunk holds no ``[heads, chunk,
-    capacity]`` float32 scores; the expert matmuls are the kernel at 128
-    groups."""
+    is copied or relaid whole; the chunk's softmax cores are the GQA
+    kernel and no float32 scores are left in the program; the expert
+    matmuls are the kernel at 128 groups."""
     from tpumlops.models import gdn_moe
 
     cfg = _qwen3_next_cfg()
@@ -809,7 +871,13 @@ def test_state_programs_leave_rows_and_state_in_place(one_chip, program):
     assert len(calls) == 24  # three a layer, eight layers
     assert all("layer.moe_experts" in l for l in calls)
     assert "ragged-dot" not in text
-    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    # The chunk's softmax core: the GQA kernel once a full layer.
+    cores = _kernel_calls(text, "gqa_prefill_attention")
+    assert len(cores) == (2 if program == "prefill_chunk" else 0)
+    assert all("layer.attn_core" in l for l in cores)
+    assert len(calls) + len(cores) == text.count('custom_call_target="tpu_custom_call"')
+    if program == "prefill_chunk":
+        _assert_no_gqa_scores(text)
     for scope in ("layer.gdn_in", "layer.gdn_conv", "layer.gdn_scan",
                   "layer.gdn_out", "state_commit", "kv_commit", "layer.attn_qkv",
                   "layer.attn_core", "layer.attn_gate", "layer.attn_out",
@@ -844,9 +912,9 @@ def test_window_programs_leave_rows_and_ring_in_place(one_chip, program):
     window and the prefill chunk fit the chip beside 5.69 GB of bf16
     weights; every donated buffer (the two full layers' K and V rows, the
     six sliding layers' rings of 512 rows) aliases and none is copied or
-    relaid whole; the chunk holds no ``[heads, chunk, capacity]`` float32
-    scores; the expert matmuls are the kernel at 32 groups in the seven
-    expert layers."""
+    relaid whole; the chunk's softmax cores of both kinds are the GQA
+    kernel and no float32 scores are left in the program; the expert
+    matmuls are the kernel at 32 groups in the seven expert layers."""
     from tpumlops.models import gdn_moe
 
     cfg = _laguna_cfg()
@@ -909,7 +977,15 @@ def test_window_programs_leave_rows_and_ring_in_place(one_chip, program):
     assert len(calls) == 21  # three a layer, seven expert layers
     assert all("layer.moe_experts" in l for l in calls)
     assert "ragged-dot" not in text
-    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    # The chunk's softmax core: the GQA kernel once an attention layer of
+    # either kind (2 full, 6 sliding over the ring's 512 rows and the
+    # chunk's).
+    cores = _kernel_calls(text, "gqa_prefill_attention")
+    assert len(cores) == (8 if program == "prefill_chunk" else 0)
+    assert all("layer.attn_core" in l for l in cores)
+    assert len(calls) + len(cores) == text.count('custom_call_target="tpu_custom_call"')
+    if program == "prefill_chunk":
+        _assert_no_gqa_scores(text)
     for scope in ("ring_commit", "kv_commit", "rope", "layer.attn_qkv",
                   "layer.attn_core", "layer.attn_gate", "layer.attn_out",
                   "layer.mlp", "layer.moe_router", "layer.moe_experts",
